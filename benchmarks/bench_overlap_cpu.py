@@ -16,8 +16,6 @@ sys.path.insert(0, str(__import__("pathlib").Path(__file__).resolve().parents[1]
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 import bench  # noqa: E402
 from bench import _make_experiment, _make_async_experiment, measure_ours  # noqa: E402
 

@@ -369,12 +369,10 @@ def main(argv=None) -> int:
                 flags + " --xla_force_host_platform_device_count=8").strip()
         os.environ["JAX_PLATFORMS"] = "cpu"
         jax.config.update("jax_platforms", "cpu")
-        from dba_mod_tpu.utils.compile_cache import enable_compile_cache
-        enable_compile_cache()
     else:
         jax.config.update("jax_default_matmul_precision", "highest")
-        from dba_mod_tpu.utils.compile_cache import enable_compile_cache
-        enable_compile_cache("/tmp/jax_cache_dba_bench")
+    from dba_mod_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     sections, summaries = [], []
     pre_note = {}

@@ -131,7 +131,9 @@ _DEFAULTS: Dict[str, Any] = {
     "synthetic_noise_std": 25.0,   # task difficulty: 25 saturates (smoke
                                    # runs); ~90 plateaus below 100% like
                                    # real data (datasets.py docstring)
-    "num_devices": 0,              # 0 = use all visible devices on the clients mesh
+    "num_devices": 0,              # clients mesh: 0 = single device (no
+                                   # mesh), -1 = all visible devices,
+                                   # n > 1 = the first n
     "run_dir": "./runs",
     "checkpoint_dir": "saved_models",  # root for resume/pretrain checkpoints
     "dynamic_steps": False,        # size each round's batch plan to the

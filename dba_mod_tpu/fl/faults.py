@@ -7,7 +7,7 @@ partial participation and byzantine payloads. This module perturbs per-round
 client *outcomes* (what the server receives), never the training computation
 itself: faults model the uplink, not the local SGD.
 
-Fault taxonomy (per client, per round; mutually exclusive, resolved in
+Fault classes (per client, per round; mutually exclusive, resolved in
 priority order host-loss > dropout > corrupt > blowup > stale):
 
   dropout — the client never reports. Its payload is zeroed and it is

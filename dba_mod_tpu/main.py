@@ -35,6 +35,8 @@ from dba_mod_tpu.config import Params
 def _train(args) -> int:
     from dba_mod_tpu.fl.experiment import Experiment
     from dba_mod_tpu.utils import run_guard
+    from dba_mod_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     params = Params.from_yaml(args.params)
     if args.epochs is not None:
         params.raw["epochs"] = args.epochs
@@ -93,6 +95,8 @@ def _train(args) -> int:
 def _pretrain(args) -> int:
     from dba_mod_tpu import checkpoint as ckpt
     from dba_mod_tpu.fl.experiment import Experiment
+    from dba_mod_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     params = Params.from_yaml(args.params)
     params.raw.update(is_poison=False, resumed_model=False,
                       save_model=False)

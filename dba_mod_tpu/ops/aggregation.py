@@ -390,8 +390,10 @@ def foolsgold_update(global_params: Any, stacked_grads: Any,
 # survivor set still sorts deterministically — an excluded client's score
 # (_EXCLUDED) always exceeds any survivor's, even the 1-survivor case whose
 # score is a sum of _FAR pair distances. Both fit comfortably in f32.
-_FAR = jnp.float32(1e30)       # pair distance to/from an excluded client
-_EXCLUDED = jnp.float32(1e35)  # score of an excluded client
+# Plain floats: a jnp scalar here would initialise the XLA backend while the
+# package is imported, before jax.distributed.initialize() can run.
+_FAR = 1e30       # pair distance to/from an excluded client
+_EXCLUDED = 1e35  # score of an excluded client
 
 
 class KrumResult(NamedTuple):

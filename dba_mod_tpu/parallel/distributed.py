@@ -71,15 +71,6 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
         return False
     if _initialized:  # idempotent: every Experiment calls this
         return jax.process_count() > 1
-    try:
-        # CPU cross-process collectives need the gloo transport; the
-        # default ("none") makes every multi-process CPU round fail with
-        # "Multiprocess computations aren't implemented on the CPU
-        # backend". Harmless on TPU (the option only affects the CPU
-        # backend); tolerated absent on jax versions that predate it.
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover — other jax
-        pass
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=(num_processes if num_processes is not None else

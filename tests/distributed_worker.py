@@ -24,7 +24,6 @@ def main():
     os.environ["JAX_NUM_PROCESSES"] = "2"
     os.environ["JAX_PROCESS_ID"] = str(process_id)
     import jax
-    jax.config.update("jax_platforms", "cpu")
     from dba_mod_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
 

@@ -28,9 +28,45 @@ def test_required_key_validation():
 
 
 def test_unknown_aggregation_rejected():
-    bad = dict(BASE, aggregation_methods="krum")
+    bad = dict(BASE, aggregation_methods="bulyan")
+    assert "bulyan" not in cfg.AGGR_ALL
     with pytest.raises(ValueError, match="aggregation"):
         cfg.Params.from_dict(bad)
+
+
+def test_importing_experiment_initialises_no_backend():
+    """jax.distributed.initialize() refuses to run once a backend exists, and
+    on a chip machine the first process to initialise one owns the chip — so
+    nothing in the package may create a device array while it is imported.
+    A fresh interpreter: this one initialised its backend in conftest."""
+    import subprocess
+    import sys
+    code = ("import dba_mod_tpu.fl.experiment, dba_mod_tpu.main\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+@pytest.mark.parametrize("env_dir", ["/some/where/else", None])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; unset, the
+    cache sits at the one fixed, git-ignored path inside the checkout."""
+    import jax
+    from pathlib import Path
+    from dba_mod_tpu.utils import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+            assert compile_cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            assert compile_cache.enable_compile_cache() == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_adversarial_index_distributed():

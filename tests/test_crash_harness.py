@@ -43,11 +43,8 @@ VOLATILE = {"time", "round_time", "dispatch_time", "finalize_time"}
 def _env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    # share the suite's persistent compile cache (tests/conftest.py /
-    # utils/compile_cache.py) so subprocess launches skip recompiles
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_dba_tests")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
+    # the children are `python -m dba_mod_tpu.main`, which enables the same
+    # persistent compile cache as tests/conftest.py (utils/compile_cache.py)
     return env
 
 

@@ -56,9 +56,6 @@ def _env(world=None):
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["TF_CPP_MIN_LOG_LEVEL"] = "3"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_dba_tests")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
     if world is not None:
         coord, n, pid = world
         env["JAX_COORDINATOR_ADDRESS"] = coord

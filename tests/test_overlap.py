@@ -144,14 +144,38 @@ def test_sync_overlap_telemetry_forces_sequential():
 
 def test_donated_round_gate_off_on_cpu_and_under_overlap():
     """round_fn donation is only sound when nobody re-reads the donated
-    buffers: never on CPU (jit aliasing is unsupported → warning spam),
-    never with the sentinel armed (rollback re-reads vars_before), never
+    buffers: never on CPU (rounds.py keeps the gate off there), never with
+    the sentinel armed (rollback re-reads vars_before), never
     under overlap (the core path owns the buffers)."""
     e = Experiment(Params.from_dict(dict(BASE, epochs=1)),
                    save_results=False)
     assert jax.default_backend() == "cpu"
     assert e.engine.round_fn_donated is None
     assert e._use_donated_round is False
+
+
+def test_donated_round_never_feeds_a_pipelined_checkpoint(tmp_path,
+                                                          monkeypatch):
+    """Found on the chip's path (PR 21): under pipeline_rounds the checkpoint
+    of round N reads RoundInFlight.vars_after after round N+1 was dispatched
+    — with the donated twin that is "Array has been deleted". XLA:CPU does
+    donate, so telling the engine it is not on the CPU reproduces it here:
+    the gate must fall back to round_fn exactly when a pipelined run saves,
+    and donate (bit-identically) when it does not."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "not-cpu")
+    cfg = dict(BASE, pipeline_rounds=True, run_dir=str(tmp_path / "runs"),
+               save_on_epochs=[2])
+    saving = Experiment(Params.from_dict(dict(cfg, save_model=True)),
+                        save_results=True)
+    assert saving.engine.round_fn_donated is not None
+    assert saving._use_donated_round is False
+    saving.run()
+    assert (saving.folder / "model_last.pt.tar.epoch_2").is_dir()
+    plain = _run(cfg)
+    assert plain._use_donated_round is True
+    assert plain.engine.round_fn_donated._cache_size() == 1
+    assert plain.engine.round_fn._cache_size() == 0
+    _assert_ab(saving, plain)
 
 
 # ----------------------------------------------------------- async engine

@@ -1,0 +1,141 @@
+"""Compile the chip's own programs for a DESCRIBED TPU v5e — no chip attached.
+
+The TPU compiler is installed here and compiles for a topology that is only
+described (`on-chip-measurement` guide §2): it refuses what the chip's
+compiler would refuse (misaligned Pallas slices, VMEM overflow, programs that
+do not fit HBM), which interpret-mode tests cannot see. Covered: the fused
+Pallas per-step update at the CIFAR and Tiny-ImageNet model shapes, and the
+CIFAR round program's donated twin — the default program of an unsharded TPU
+run, which the CPU suite otherwise never builds.
+
+A compile that passes is not a chip run; `chip_smoke.py` is.
+
+Only one process may load libtpu, so the topology is described inside a
+module-scoped fixture (never at import), everything built from it lives in
+fixtures or tests, compiles happen in this process, and all of these tests
+stay in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dba_mod_tpu import config as cfg
+from dba_mod_tpu.models import build_model
+from dba_mod_tpu.ops.fused_update import make_fused_step_update
+
+C = 10  # clients per round in every reference config
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip (the next run warns and recompiles),
+    so the cache is off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("fg_enabled", [False, True])
+@pytest.mark.parametrize("model_type", ["cifar", "tiny-imagenet-200"])
+def test_fused_update_compiles_for_v5e(one_chip, no_persistent_cache,
+                                       model_type, fg_enabled):
+    model = build_model(cfg.Params.from_dict(dict(
+        type=model_type, lr=0.1, batch_size=64, epochs=1, no_models=C,
+        number_of_total_participants=100, eta=0.1,
+        aggregation_methods="mean")))
+    one = jax.eval_shape(model.init_vars, jax.random.key(0))
+    stacked = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct((C,) + l.shape, l.dtype,
+                                       sharding=one_chip), one)
+    p, bn = stacked.params, stacked.batch_stats
+    vec = lambda dt: jax.ShapeDtypeStruct((C,), dt, sharding=one_chip)
+    fused = make_fused_step_update(0.9, 5e-4, fg_enabled, use_pallas=True)
+    compiled = jax.jit(jax.vmap(fused)).lower(
+        vec(jnp.float32), vec(jnp.bool_), p, p, p, p if fg_enabled else {},
+        bn, bn).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def cifar_engine():
+    """configs/cifar_params.yaml's engine as a TPU run builds it — fused
+    update on (`auto`), donated twin present — with only the synthetic data
+    cut (5,000/1,000 images; the dataset is closed over by the program, so
+    its size is compile payload, not program structure). RoundEngine asks
+    jax.default_backend() for both decisions; here it is told "tpu"."""
+    from pathlib import Path
+    from dba_mod_tpu.fl.experiment import Experiment
+    params = cfg.Params.from_yaml(
+        Path(__file__).resolve().parents[1] / "configs/cifar_params.yaml")
+    params.raw.update(synthetic_data=True, synthetic_train_size=5000,
+                      synthetic_test_size=1000, resumed_model=False)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        exp = Experiment(params, save_results=False)
+    assert exp.engine.fused_pallas and not exp.engine.fused_interpret
+    assert exp.engine.round_fn_donated is not None
+    return exp
+
+
+def _round_args(exp, sharding):
+    """Abstract arguments of one poisoned-run round at the static plan
+    shape, all on `sharding`."""
+    from dba_mod_tpu.fl.state import build_client_tasks
+    n = int(exp.params["no_models"])
+    names = exp.participants[:n]
+    slots = np.array([exp.client_slots[x] for x in names], np.int64)
+    tasks = build_client_tasks(exp.params, names, 1, slots, exp.epochs_max,
+                               None)
+    tasks_seq = jax.tree_util.tree_map(lambda l: np.asarray(l)[None], tasks)
+    plan = (1, n, exp.epochs_max, exp.steps_per_epoch,
+            int(exp.params["batch_size"]))
+    rng_t, rng_a = jax.random.split(jax.random.key(0))
+    args = (exp.global_vars, exp.fg_state, tasks_seq,
+            np.zeros(plan, np.int32), np.zeros(plan, bool),
+            np.arange(n, dtype=np.int32), np.zeros((n,), np.float32),
+            rng_t, rng_a)
+    return _abstract(args, sharding)
+
+
+def test_cifar_donated_round_compiles_for_v5e(one_chip, no_persistent_cache,
+                                              cifar_engine):
+    """The donated twin only: it is what an unsharded CLI run dispatches on
+    the chip, and round_fn is the same program minus the aliasing (compiling
+    both costs ~50 s more than this suite's clock can spare)."""
+    compiled = cifar_engine.engine.round_fn_donated.lower(
+        *_round_args(cifar_engine, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # donation really aliases the model/defense state into the outputs
+    assert "input_output_alias" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 14 * 2**30  # fits one 16 GB chip
