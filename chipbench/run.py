@@ -1,0 +1,395 @@
+"""chipbench: one run of one cell of BENCHMARK.json, in a process of its own.
+
+    python -m chipbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Fails at once without a TPU or with fewer devices than the cell's `chips`;
+builds the configuration's `Experiment`; seeds the traffic; compiles and warms
+the cell's one round program (the two check rounds and one whole round);
+measures the program's own sequential loop for `--seconds`; compares the check
+rounds with the plain reference; prints findings as JSON lines and, last, the
+result object of the benchmark's contract.
+
+`--rehearse` walks the same control flow on whatever backend is there at tiny
+sizes (Pallas interpreted): it prints no metric and is never `correct`.
+"""
+from __future__ import annotations
+
+import time
+
+T_PROCESS = time.perf_counter()  # before anything heavy is imported
+
+import argparse
+import importlib.util
+import json
+import math
+import shutil
+import statistics
+import sys
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+ROOT = HERE.parent
+CHECK_STEPS = (1, 3)   # real steps a client takes in the two check rounds
+WARM_ROUNDS = 1        # whole rounds through run_round before the window
+FIRST_WINDOW_EPOCH = len(CHECK_STEPS) + WARM_ROUNDS + 1
+
+
+def emit(**obj) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def load_cell(name: str, benchmark_file=None):
+    """BENCHMARK.json names the cell's configuration and traffic; their files
+    are found by those names. The harness holds no list of cells."""
+    bench = json.loads(Path(benchmark_file or ROOT / "BENCHMARK.json").read_text())
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if name not in cells:
+        raise SystemExit(f"chipbench: no workload {name!r} in BENCHMARK.json")
+    cell = cells[name]
+    entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = json.loads((ROOT / entry["file"]).read_text())
+    traffic = json.loads((HERE / "traffic" / f"{cell['traffic']}.json").read_text())
+    return bench, cell, config, traffic
+
+
+def load_readers(bench, cell_name: str):
+    """One small reader per per-layer metric, found by the metric's name."""
+    readers = []
+    for m in bench["per_layer"]:
+        if "workloads" in m and cell_name not in m["workloads"]:
+            continue
+        path = HERE / "metrics" / f"{m['name']}.py"
+        spec = importlib.util.spec_from_file_location(
+            f"chipbench_metric_{m['name'].replace('.', '_')}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        readers.append((m, mod))
+    return readers
+
+
+class CompileEvents:
+    """Compile traffic from jax.monitoring's own events
+    (chip_smoke.CacheEvents, extended by the count of backend compiles)."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.hits = self.misses = self.requests = self.backend_compiles = 0
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_event(self, event: str, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+        elif event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+
+    def _on_duration(self, event: str, _secs: float, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.backend_compiles += 1
+
+    def total(self) -> int:
+        return self.requests + self.backend_compiles
+
+    def snapshot(self) -> dict:
+        return {"cache_hits": self.hits, "cache_misses": self.misses,
+                "compile_requests": self.requests,
+                "backend_compiles": self.backend_compiles}
+
+
+def device_memory(devices) -> list:
+    return [{k: (d.memory_stats() or {}).get(k)
+             for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
+            for d in devices]
+
+
+def seeded_check_rounds(exp, config, traffic, seed, first_window_epoch, events):
+    """Weights and traffic from `seed`, then the two check rounds through the
+    window's own round program; leaves `exp` seeded afresh for the window."""
+    import jax
+    from chipbench import program
+    from chipbench.reference import resnet18 as ref
+    model = config["model"]
+    # one jitted call on the device; kept on the host from here on, so that
+    # the device's peak stays the program's
+    state0 = jax.device_get(
+        ref.init_weights(seed, model["variant"], model["num_classes"]))
+    program.seed_state(exp, seed, state0)
+    checks = []
+    for i, steps in enumerate(CHECK_STEPS):
+        # the first check round is at the epoch the traffic poisons first
+        rounds = traffic.get("poison_window_rounds") or []
+        epoch = (first_window_epoch - 1 + rounds[0]
+                 if (i == 0 and traffic["is_poison"] and rounds) else i + 1)
+        got = program.check_round(exp, epoch, steps)
+        got["state"] = program.from_program(got.pop("new_vars"), list(state0))
+        checks.append(got)
+        emit(phase="check_round", index=i, epoch=epoch, real_steps=steps,
+             seconds=got["seconds"], compile=events.snapshot())
+        program.seed_state(exp, seed, state0)
+    return state0, checks
+
+
+def judge(raw, variant, state0, population, checks, lim, precision="default",
+          every=False):
+    """Each number compared, beside its limit (`lim`; `every`: also those
+    without one). `raw`: the parameters as run, `variant`: the model variant."""
+    from chipbench import check
+    compared = []
+    for got in checks:
+        want = check.reference_round(raw, variant, state0, population, got,
+                                     precision,
+                                     eval_rows=len(population["test_labels"]))
+        for name, value in check.compare(state0, got["state"], got, want).items():
+            key = f"{name}.k{got['real_steps']}"
+            row = {"number": key, "value": value}
+            if key in lim:
+                row.update(limit=lim[key],
+                           ok=bool(math.isfinite(value) and value <= lim[key]))
+            if key in lim or every:
+                compared.append(row)
+    return compared
+
+
+def population_of(exp):
+    import numpy as np
+    data = exp.image_data
+    return {"train_images": data.train_images,
+            "train_labels": np.asarray(data.train_labels, np.int32),
+            "test_images": data.test_images,
+            "test_labels": np.asarray(data.test_labels, np.int32)}
+
+
+def end_to_end(rounds_s, failed, no_models, window_s, peak_bytes, setup_s):
+    """The end-to-end arithmetic: a rate over all the work and all the time of
+    the window, and the tail of all rounds."""
+    finished = len(rounds_s) - failed
+    return {"client_updates_per_s": (finished * no_models / window_s, "updates/s"),
+            "round_s_max": (max(rounds_s), "s"),
+            "peak_hbm_gib": (peak_bytes / 2 ** 30, "GiB"),
+            "setup_s": (setup_s, "s")}
+
+
+def run_cell(args, sabotage=None) -> dict:
+    """One run. `sabotage(exp)` is for chipbench/tests only: it breaks the
+    timed path underneath the harness. Returns the result object."""
+    import jax
+    bench, cell, config, traffic = load_cell(
+        args.workload, getattr(args, "benchmark_file", None))
+    devices = jax.devices()
+    dev = devices[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices)}
+    emit(phase="device", device=device, rehearsal=args.rehearse)
+    if not args.rehearse and dev.platform != "tpu":
+        raise SystemExit(f"chipbench: no TPU (platform {dev.platform!r})")
+    if len(devices) < cell["chips"] and not args.rehearse:
+        raise SystemExit(f"chipbench: the cell needs {cell['chips']} devices, "
+                         f"JAX sees {len(devices)}")
+    devices = devices[:cell["chips"]]
+
+    from chipbench import check, program, trace as trace_mod
+    events = CompileEvents()
+    cache_dir = program.enable_cache()
+    out_dir = HERE / "_out" / f"{args.workload}.{args.seed}.{args.trace}"
+    if out_dir.exists():
+        shutil.rmtree(out_dir)
+    out_dir.mkdir(parents=True)
+
+    # ---- set-up: build (population from the configuration's own seed)
+    cut = None
+    if args.rehearse:
+        rehearsal = json.loads((HERE / "rehearsal.json").read_text())
+        cut = {**rehearsal["cut"],
+               **rehearsal["by_type"].get(config["params"]["type"], {})}
+    first_window_epoch = FIRST_WINDOW_EPOCH
+    overrides = dict(getattr(args, "overrides", None) or {})
+    if getattr(args, "override", None):
+        if not args.rehearse:
+            raise SystemExit("chipbench: --override is for rehearsals only")
+        overrides.update(json.loads(args.override))
+    params, raw = program.make_params(config, traffic, out_dir,
+                                      first_window_epoch, cut, overrides)
+    exp, build_s = program.build_experiment(params)
+    spans = {"build": [build_s]}
+    emit(phase="build", seconds=build_s, steps_per_epoch=exp.steps_per_epoch,
+         epochs_max=exp.epochs_max, no_models=int(params["no_models"]),
+         memory=device_memory(devices), cache_dir=cache_dir)
+    if sabotage is not None:
+        sabotage(exp)
+
+    # ---- set-up: traffic from --seed; the check rounds compile and warm the
+    # window's one round program
+    state0, checks = seeded_check_rounds(exp, config, traffic, args.seed,
+                                         first_window_epoch, events)
+    # the window starts from the same weights with the running statistics a
+    # trained model would carry (those of the population's first 256 images)
+    from chipbench.reference import resnet18 as ref
+    warm_state = jax.device_get(ref.with_batch_statistics(
+        state0, exp.image_data.train_images[:256], config["model"]["variant"]))
+    program.seed_state(exp, args.seed, warm_state)
+    del warm_state
+    spans["first_round"] = [checks[0]["seconds"]]
+    spans["steady_round"] = [c["seconds"] for c in checks[1:]]
+    for epoch in range(len(CHECK_STEPS) + 1, first_window_epoch):
+        exp.run_round(epoch)
+        exp.save_model(epoch)
+    jax.block_until_ready(exp.global_vars)
+    compiles_before = events.total()
+    setup_s = time.perf_counter() - T_PROCESS
+
+    # ---- the window: the program's own sequential loop
+    rounds_s, results, failed = [], [], 0
+    spans.update(dispatch=[], device_wait=[], finalize=[])
+    counters = {"real_client_steps": 0, "executed_client_steps": 0}
+    traced = None
+    trace_rounds = int(traffic.get("trace_rounds", 2)) if args.trace else 0
+    trace_dir = out_dir / "trace"
+    t_window = time.perf_counter()
+    epoch = first_window_epoch
+    while time.perf_counter() - t_window < args.seconds:
+        if args.trace and len(rounds_s) == 0:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0  # annotations only: no hook on
+            jax.profiler.start_trace(       # every Python call of the planner
+                str(trace_dir), profiler_options=options)
+            t_trace = time.perf_counter()
+        t0 = time.perf_counter()
+        try:
+            if args.trace:
+                with jax.profiler.TraceAnnotation("chipbench/dispatch"):
+                    fl = exp.dispatch_round(epoch)
+                t1 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("chipbench/device_wait"):
+                    jax.block_until_ready((fl.payload, exp.global_vars))
+                t2 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("chipbench/finalize"):
+                    r = exp.finalize_round(fl)
+                t3 = time.perf_counter()
+                spans["dispatch"].append(t1 - t0)
+                spans["device_wait"].append(t2 - t1)
+                spans["finalize"].append(t3 - t2)
+                real, executed = program.useful_steps(fl)
+                counters["real_client_steps"] += real
+                counters["executed_client_steps"] += executed
+            else:
+                r = exp.run_round(epoch)
+            exp.save_model(epoch)
+            r["global_loss"] = exp.last_global_loss
+            if not math.isfinite(r["global_loss"]):
+                failed += 1
+            results.append(r)
+        except Exception as e:  # a round that raised is a failed round
+            emit(phase="round_failed", epoch=epoch, error=repr(e))
+            failed += 1
+        rounds_s.append(time.perf_counter() - t0)
+        if args.trace and len(rounds_s) == trace_rounds:
+            jax.block_until_ready(exp.global_vars)
+            traced = {"window_s": time.perf_counter() - t_trace,
+                      "rounds": trace_rounds}
+            jax.profiler.stop_trace()
+        epoch += 1
+    jax.block_until_ready(exp.global_vars)
+    window_s = time.perf_counter() - t_window
+    if args.trace and traced is None:  # the window was shorter than the trace
+        traced = {"window_s": time.perf_counter() - t_trace,
+                  "rounds": len(rounds_s)}
+        jax.profiler.stop_trace()
+    compiles_in_window = events.total() - compiles_before
+    memory = device_memory(devices)
+    attempted = len(rounds_s)
+    if not program.all_finite(exp.global_vars):
+        failed = max(failed, 1)
+    rows = program.recorded_rows(exp)
+    window_rows = [r for r in rows if r["epoch"] >= first_window_epoch]
+    engine = program.engine_report(exp, dev.platform == "tpu")
+    emit(phase="window", window_s=window_s, rounds_s=rounds_s,
+         global_acc=[r["global_acc"] for r in results],
+         global_loss=[r["global_loss"] for r in results],
+         backdoor_acc=[r["backdoor_acc"] for r in results],
+         recorded_rows=len(window_rows), compiles_in_window=compiles_in_window,
+         compile=events.snapshot(), engine=engine, memory=memory)
+
+    # ---- the output check, after the window: the reference follows the
+    # check rounds' feeds on the same rows of the population
+    t0 = time.perf_counter()
+    lim = check.limits(cell["config"], cell["traffic"])
+    compared = judge(raw, config["model"]["variant"], state0, population_of(exp),
+                     checks, lim)
+    check_ok = bool(compared) and all(row["ok"] for row in compared)
+    emit(phase="check", seconds=time.perf_counter() - t0, compared=compared)
+
+    conditions = {
+        "device": (dev.platform == "tpu" and device["count"] >= cell["chips"]),
+        "no_compile_in_window": compiles_in_window == 0,
+        "rows_recorded": len(window_rows) == attempted - failed
+        and [r["epoch"] for r in window_rows]
+        == list(range(first_window_epoch, first_window_epoch + len(window_rows))),
+        "no_failed_round": failed == 0 and attempted > 0,
+        "engine": engine["ok"],
+        "agrees_with_reference": check_ok,
+    }
+    emit(phase="conditions", **conditions)
+    correct = all(conditions.values()) and not args.rehearse
+
+    # ---- the numbers
+    peak = max((m["peak_bytes_in_use"] or 0) for m in memory)
+    numbers = end_to_end(rounds_s, failed, int(params["no_models"]), window_s,
+                         peak, setup_s)
+    result = {"correct": correct, "attempted": attempted, "failed": failed,
+              "metrics": {}, "device": dict(device, count=cell["chips"],
+                                            memory_peak_bytes=peak)}
+    if args.rehearse:
+        result["rehearsal"] = True
+        result["device"] = device
+        result["check_ok"] = check_ok
+        result["conditions"] = conditions
+    elif not args.trace:
+        names = {m["name"] for m in bench["end_to_end"]
+                 if "workloads" not in m or args.workload in m["workloads"]}
+        result["metrics"] = {k: {"value": v, "unit": u}
+                             for k, (v, u) in numbers.items() if k in names}
+    else:
+        counters.update(compile_cache_hits=events.hits,
+                        compile_cache_misses=events.misses)
+        reduced = trace_mod.reduce(trace_mod.find_xplane(trace_dir),
+                                   cell["chips"])
+        ctx = {"spans": spans, "counters": counters, "trace": reduced,
+               "traced": traced}
+        for m, mod in load_readers(bench, args.workload):
+            value = mod.read(ctx)
+            if value is not None:
+                result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
+        result["device"]["busy_s"] = reduced["busy_s"]
+        result["device"]["window_s"] = reduced["window_s"]
+        result["breakdown"] = {"device_ops": reduced["top_ops"][:10],
+                               "idle_gaps": reduced["idle_gaps"][:10]}
+        emit(phase="spans", medians={k: statistics.median(v)
+                                     for k, v in spans.items() if v},
+             counters=counters, traced=traced)
+    shutil.rmtree(out_dir, ignore_errors=True)
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--override", default=None,
+                    help="JSON of parameters laid over the cell's (rehearsals only)")
+    ap.add_argument("--benchmark-file", default=None,
+                    help="another file of BENCHMARK.json's form (tests)")
+    args = ap.parse_args(argv)
+    result = run_cell(args)
+    print(json.dumps(result), flush=True)
+    if args.rehearse:
+        return 3
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
