@@ -449,9 +449,10 @@ def main() -> int:
                     help="enable the telemetry layer (utils/telemetry.py): "
                          "writes telemetry.jsonl + Chrome-trace trace.json "
                          "to DIR and prints the phase summary to stderr. "
-                         "NOTE: telemetry adds per-phase device syncs, so "
-                         "the headline rounds/sec is NOT comparable to an "
-                         "uninstrumented run")
+                         "NOTE: with the exporters on the round loop runs "
+                         "sequentially (no pipelining/overlap), so the "
+                         "headline rounds/sec is NOT comparable to a run "
+                         "without the flag")
     args = ap.parse_args()
 
     config = dict(BENCH_CONFIG)

@@ -96,15 +96,18 @@ _DEFAULTS: Dict[str, Any] = {
                                    # image_train.py:150-164, 268-299)
     "profile_dir": "",             # non-empty: jax.profiler traces per round
     "tensorboard": False,          # scalar summaries (imports TensorFlow)
-    "telemetry": False,            # span tracing + metrics registry + XLA
-                                   # compile/memory instrumentation
-                                   # (utils/telemetry.py): writes
-                                   # telemetry.jsonl + Chrome-trace
-                                   # trace.json per run, adds honest
-                                   # device-sync points to phase spans
-                                   # (serializes round pipelining); off =
-                                   # no files, no per-round work beyond a
-                                   # no-op check
+    "telemetry": False,            # selects the EXPORTERS of
+                                   # utils/telemetry.py: telemetry.jsonl,
+                                   # the Chrome-trace trace.json, the TB
+                                   # mirror, the summary table, the
+                                   # metrics registry. Spans (host layer
+                                   # boundaries, profiler annotations) and
+                                   # the round program's `phase/` scopes
+                                   # are always there and the round runs
+                                   # the same fused program either way;
+                                   # per-phase DEVICE time is read from a
+                                   # profile_dir trace. On, the round loop
+                                   # stays sequential (no pipelining)
     "telemetry_dir": "",           # where telemetry files land; "" = the
                                    # run folder (in-memory only when the
                                    # run saves no results)

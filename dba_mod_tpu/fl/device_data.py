@@ -20,6 +20,7 @@ from dba_mod_tpu import config as cfg
 from dba_mod_tpu.data.batching import stack_ragged
 from dba_mod_tpu.data.datasets import ImageData, LoanData
 from dba_mod_tpu.ops import triggers
+from dba_mod_tpu.utils import telemetry
 
 # fetch(slot, idx[B]) -> (x[B, ...], y[B]); stamp(x, y, adv_index, k,
 # poison_all) -> (x, y, poisoned_mask)
@@ -39,13 +40,16 @@ class DeviceData:
 
 def make_image_device_data(data: ImageData, params: cfg.Params,
                            compute_dtype=jnp.float32) -> DeviceData:
-    train_x = jnp.asarray(data.train_images)          # [N,H,W,C] uint8
-    train_y = jnp.asarray(data.train_labels.astype(np.int32))
-    test_x = jnp.asarray(data.test_images)
-    test_y = jnp.asarray(data.test_labels.astype(np.int32))
     h, w = data.train_images.shape[1:3]
-    bank = jnp.asarray(triggers.build_pixel_pattern_bank(params, h, w),
-                       compute_dtype)
+    # set-up only, so the span may end at a sync: the host-to-device copy
+    with telemetry.span("setup/device_put"):
+        train_x = jnp.asarray(data.train_images)      # [N,H,W,C] uint8
+        train_y = jnp.asarray(data.train_labels.astype(np.int32))
+        test_x = jnp.asarray(data.test_images)
+        test_y = jnp.asarray(data.test_labels.astype(np.int32))
+        bank = jnp.asarray(triggers.build_pixel_pattern_bank(params, h, w),
+                           compute_dtype)
+        jax.block_until_ready((train_x, train_y, test_x, test_y, bank))
     swap = int(params["poison_label_swap"])
 
     def fetch_train(slot, idx):
@@ -70,14 +74,17 @@ def make_loan_device_data(data: LoanData, params: cfg.Params,
                           compute_dtype=jnp.float32) -> DeviceData:
     """LOAN shards are ragged per state → stacked [S, max_n, F] with per-state
     row counts carried by the batch plans' masks. `slot` selects the state."""
-    train_x = jnp.asarray(stack_ragged(data.train_x), compute_dtype)
-    train_y = jnp.asarray(stack_ragged(data.train_y).astype(np.int32))
-    test_x = jnp.asarray(stack_ragged(data.test_x), compute_dtype)
-    test_y = jnp.asarray(stack_ragged(data.test_y).astype(np.int32))
-    values, masks = triggers.build_feature_trigger_bank(
-        params, data.feature_dict, train_x.shape[-1])
-    values = jnp.asarray(values, compute_dtype)
-    masks = jnp.asarray(masks, compute_dtype)
+    with telemetry.span("setup/device_put"):
+        train_x = jnp.asarray(stack_ragged(data.train_x), compute_dtype)
+        train_y = jnp.asarray(stack_ragged(data.train_y).astype(np.int32))
+        test_x = jnp.asarray(stack_ragged(data.test_x), compute_dtype)
+        test_y = jnp.asarray(stack_ragged(data.test_y).astype(np.int32))
+        values, masks = triggers.build_feature_trigger_bank(
+            params, data.feature_dict, train_x.shape[-1])
+        values = jnp.asarray(values, compute_dtype)
+        masks = jnp.asarray(masks, compute_dtype)
+        jax.block_until_ready((train_x, train_y, test_x, test_y, values,
+                               masks))
     swap = int(params["poison_label_swap"])
 
     def fetch_train(slot, idx):

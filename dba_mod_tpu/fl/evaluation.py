@@ -47,9 +47,10 @@ def instrument_eval(fn, name: str, batches: int = 0):
     A zero-overhead passthrough while telemetry is off, so the standalone
     batteries keep deferring their sync to ``finalize_round`` and round
     pipelining is unaffected. With telemetry on, evals that run outside the
-    fused round program (the split-phase dispatch, sequential_debug, the
-    degraded-round re-eval, the LOAN backdoor probe) report honest phase
-    times at the cost of syncing where they are called."""
+    fused round program (sequential_debug, overlap_eval, the degraded-round
+    re-eval, the LOAN backdoor probe) report honest phase times at the cost
+    of syncing where they are called. The fused round's own batteries are
+    timed by their `phase/` scopes under a profiler trace instead."""
     return telemetry.instrument(fn, name, batches=batches)
 
 

@@ -462,85 +462,97 @@ class Experiment:
         eb = int(params.get("eval_batch_size", 0) or
                  params["test_batch_size"])
         if params.is_image:
-            data = self.image_data = load_image_dataset(params)
+            with telemetry.span("setup/data"):
+                data = self.image_data = load_image_dataset(params)
             self.device_data = make_image_device_data(data, params,
                                                       compute_dtype=cdtype)
-            if params["sampling_dirichlet"]:
-                indices = sample_dirichlet_indices(
-                    data.train_labels,
-                    int(params["number_of_total_participants"]),
-                    float(params["dirichlet_alpha"]),
-                    py_rng=random.Random(seed),
-                    np_rng=np.random.RandomState(seed))
-            else:
-                indices = equal_split_indices(
-                    len(data.train_labels),
-                    int(params["number_of_total_participants"]),
-                    py_rng=random.Random(seed))
-            self.client_indices = indices
-            self.client_slots = {name: 0 for name in indices}
-            if params["is_random_namelist"]:
-                self.participants = list(
-                    range(int(params["number_of_total_participants"])))
-            else:
-                self.participants = list(params["participants_namelist"])
-            self.benign_names = sorted(
-                set(self.participants) - set(params.adversary_list))
-            self.num_participants = int(
-                params["number_of_total_participants"])
-
-            clean = build_eval_plan(np.arange(len(data.test_labels)), eb)
-            poison = build_eval_plan(
-                poison_test_indices(data.test_labels,
-                                    int(params["poison_label_swap"])), eb)
-            self.eval_plans = EvalPlans(
-                clean_idx=jnp.asarray(clean.idx),
-                clean_slots=jnp.zeros_like(jnp.asarray(clean.idx)),
-                clean_mask=jnp.asarray(clean.mask),
-                poison_idx=jnp.asarray(poison.idx),
-                poison_slots=jnp.zeros_like(jnp.asarray(poison.idx)),
-                poison_mask=jnp.asarray(poison.mask))
+            with telemetry.span("setup/partition"):
+                self._partition_images(data, seed, eb)
         else:
-            data = self.loan_data = load_loan_dataset(params)
+            with telemetry.span("setup/data"):
+                data = self.loan_data = load_loan_dataset(params)
             self.device_data = make_loan_device_data(data, params,
                                                      compute_dtype=cdtype)
-            state_of = {n: i for i, n in enumerate(data.state_names)}
-            # benign list: first `number_of_total_participants` shards that
-            # are not adversaries (loan_helper.py:134-141)
-            benign = []
-            for j, name in enumerate(data.state_names):
-                if j >= int(params["number_of_total_participants"]):
-                    break
-                if name not in params.adversary_list:
-                    benign.append(name)
-            self.benign_names = benign
-            if params["is_random_namelist"]:
-                self.participants = benign + params.adversary_list
-            else:
-                self.participants = list(params["participants_namelist"])
-            self.client_indices = {
-                name: list(range(len(data.train_y[state_of[name]])))
-                for name in data.state_names}
-            self.client_slots = state_of
-            self.num_participants = len(data.state_names)
+            with telemetry.span("setup/partition"):
+                self._partition_loan(data, eb)
 
-            # eval plans concatenate every state shard (test.py:13-24)
-            b = eb
-            pairs = [(s, i) for s, ys in enumerate(data.test_y)
-                     for i in range(len(ys))]
-            slots = np.array([p[0] for p in pairs], np.int64)
-            rows = np.array([p[1] for p in pairs], np.int64)
-            plan = build_eval_plan(np.arange(len(pairs)), b)
-            # map flat eval positions back to (slot, row)
-            idx = rows[plan.idx.reshape(-1)].reshape(plan.idx.shape)
-            slt = slots[plan.idx.reshape(-1)].reshape(plan.idx.shape)
-            self.eval_plans = EvalPlans(
-                clean_idx=jnp.asarray(idx.astype(np.int32)),
-                clean_slots=jnp.asarray(slt.astype(np.int32)),
-                clean_mask=jnp.asarray(plan.mask),
-                poison_idx=jnp.asarray(idx.astype(np.int32)),
-                poison_slots=jnp.asarray(slt.astype(np.int32)),
-                poison_mask=jnp.asarray(plan.mask))
+    def _partition_images(self, data, seed: int, eb: int):
+        params = self.params
+        if params["sampling_dirichlet"]:
+            indices = sample_dirichlet_indices(
+                data.train_labels,
+                int(params["number_of_total_participants"]),
+                float(params["dirichlet_alpha"]),
+                py_rng=random.Random(seed),
+                np_rng=np.random.RandomState(seed))
+        else:
+            indices = equal_split_indices(
+                len(data.train_labels),
+                int(params["number_of_total_participants"]),
+                py_rng=random.Random(seed))
+        self.client_indices = indices
+        self.client_slots = {name: 0 for name in indices}
+        if params["is_random_namelist"]:
+            self.participants = list(
+                range(int(params["number_of_total_participants"])))
+        else:
+            self.participants = list(params["participants_namelist"])
+        self.benign_names = sorted(
+            set(self.participants) - set(params.adversary_list))
+        self.num_participants = int(
+            params["number_of_total_participants"])
+
+        clean = build_eval_plan(np.arange(len(data.test_labels)), eb)
+        poison = build_eval_plan(
+            poison_test_indices(data.test_labels,
+                                int(params["poison_label_swap"])), eb)
+        self.eval_plans = EvalPlans(
+            clean_idx=jnp.asarray(clean.idx),
+            clean_slots=jnp.zeros_like(jnp.asarray(clean.idx)),
+            clean_mask=jnp.asarray(clean.mask),
+            poison_idx=jnp.asarray(poison.idx),
+            poison_slots=jnp.zeros_like(jnp.asarray(poison.idx)),
+            poison_mask=jnp.asarray(poison.mask))
+
+    def _partition_loan(self, data, eb: int):
+        params = self.params
+        state_of = {n: i for i, n in enumerate(data.state_names)}
+        # benign list: first `number_of_total_participants` shards that
+        # are not adversaries (loan_helper.py:134-141)
+        benign = []
+        for j, name in enumerate(data.state_names):
+            if j >= int(params["number_of_total_participants"]):
+                break
+            if name not in params.adversary_list:
+                benign.append(name)
+        self.benign_names = benign
+        if params["is_random_namelist"]:
+            self.participants = benign + params.adversary_list
+        else:
+            self.participants = list(params["participants_namelist"])
+        self.client_indices = {
+            name: list(range(len(data.train_y[state_of[name]])))
+            for name in data.state_names}
+        self.client_slots = state_of
+        self.num_participants = len(data.state_names)
+
+        # eval plans concatenate every state shard (test.py:13-24)
+        b = eb
+        pairs = [(s, i) for s, ys in enumerate(data.test_y)
+                 for i in range(len(ys))]
+        slots = np.array([p[0] for p in pairs], np.int64)
+        rows = np.array([p[1] for p in pairs], np.int64)
+        plan = build_eval_plan(np.arange(len(pairs)), b)
+        # map flat eval positions back to (slot, row)
+        idx = rows[plan.idx.reshape(-1)].reshape(plan.idx.shape)
+        slt = slots[plan.idx.reshape(-1)].reshape(plan.idx.shape)
+        self.eval_plans = EvalPlans(
+            clean_idx=jnp.asarray(idx.astype(np.int32)),
+            clean_slots=jnp.asarray(slt.astype(np.int32)),
+            clean_mask=jnp.asarray(plan.mask),
+            poison_idx=jnp.asarray(idx.astype(np.int32)),
+            poison_slots=jnp.asarray(slt.astype(np.int32)),
+            poison_mask=jnp.asarray(plan.mask))
 
     # ----------------------------------------------------------------- round
     _STEP_BUCKET = 2       # quantum of the per-round step-count buckets
@@ -589,20 +601,15 @@ class Experiment:
                 from dba_mod_tpu.parallel.mesh import shard_round_inputs
                 tasks_seq, idx, mask, ns = shard_round_inputs(
                     self.mesh, tasks_seq, idx, mask, ns)
-            # warm the program real rounds run: the fused round — or, under
-            # telemetry's split-phase dispatch, the train program (the only
-            # split program whose shape varies with the step bucket;
-            # aggregate/eval are bucket-free), or the overlap scheduler's
-            # round core. The donated twin is warmed on COPIES: donation
+            # warm the program real rounds run: the fused round, or the
+            # overlap scheduler's round core. The donated twin is warmed on
+            # COPIES: donation
             # consumes the input buffers, and these are the live
             # model/defense state. A compile failure propagates.
             if self._overlap and not self.sequential_debug:
                 self.engine.core_fn(self.global_vars, self.fg_state,
                                     tasks_seq, idx, mask, lane, ns,
                                     rng_t, rng_a, *robust_args)
-            elif self._telemetry_split and not self.sequential_debug:
-                self.engine.train_fn(self.global_vars, tasks_seq,
-                                     idx, mask, lane, rng_t)
             elif self._use_donated_round:
                 gv = jax.tree_util.tree_map(lambda x: x.copy(),
                                             self.global_vars)
@@ -645,18 +652,6 @@ class Experiment:
         return self.finalize_round(self.dispatch_round(epoch))
 
     @property
-    def _telemetry_split(self) -> bool:
-        """Split-phase dispatch only while THIS experiment's telemetry is
-        the process-wide current instance: the shared eval/checkpoint
-        wrappers resolve ``telemetry.current()`` at call time, so after
-        another Experiment takes over, the split path would pay its
-        per-phase device syncs with no spans recorded — fall back to the
-        fused program (whose dispatch/finalize spans, recorded on this
-        instance, stay honest: host planning + enqueue / blocking fetch)."""
-        return (self.telemetry.enabled and not self.engine.robust
-                and telemetry.current() is self.telemetry)
-
-    @property
     def _use_donated_round(self) -> bool:
         """Route through the fused round's donated twin (non-CPU, non-robust
         — see the gate in rounds.py) only when nothing re-reads the consumed
@@ -674,14 +669,15 @@ class Experiment:
 
     def dispatch_round(self, epoch: int) -> RoundInFlight:
         """Telemetry/timing shell around :meth:`_dispatch`: the whole host
-        planning + enqueue runs under the ``round/dispatch`` span, and its
-        perf_counter duration lands in ``round_result.csv`` as
-        ``dispatch_time`` (the old single `round_time` measured with
-        ``time.time()`` attributed pipelined fetches to whatever wall
-        segment they landed in)."""
+        planning + enqueue runs under the ``round/dispatch`` span (children
+        ``round/plan``, ``round/stage``, ``round/enqueue``) inside a
+        profiler step annotation, so a device trace's step line carries the
+        round; its perf_counter duration lands in ``round_result.csv`` as
+        ``dispatch_time``."""
         t0 = time.perf_counter()
         self.telemetry.set_epoch(epoch)
-        with self.telemetry.span("round/dispatch"):
+        with jax.profiler.StepTraceAnnotation("round", step_num=epoch), \
+                telemetry.span("round/dispatch", round=epoch):
             fl = self._dispatch(epoch, t0)
         fl.dispatch_time = time.perf_counter() - t0
         return fl
@@ -691,114 +687,119 @@ class Experiment:
         sync — EXCEPT the LOAN adaptive-poison probe below, which must read
         the current global model's backdoor accuracy (loan_train.py:67-75)
         and therefore blocks on all previously dispatched work (pipelining
-        degrades to sequential for those rounds, by necessity), and the
-        explicit per-phase sync points of telemetry's split-phase path. The
+        degrades to sequential for those rounds, by necessity). The
         returned handle feeds `finalize_round`, which performs the round's
         single blocking transfer and the CSV/JSONL recording."""
         params = self.params
-        agent_names, adv_names = select_agents(
-            params, epoch, self.participants, self.benign_names,
-            self.select_rng)
-        logger.info("Server Epoch:%d choose agents: %s", epoch, agent_names)
+        with telemetry.span("round/plan", round=epoch):
+            agent_names, adv_names = select_agents(
+                params, epoch, self.participants, self.benign_names,
+                self.select_rng)
+            logger.info("Server Epoch:%d choose agents: %s", epoch,
+                        agent_names)
 
-        backdoor_acc = None
-        if (params.type == cfg.TYPE_LOAN and self.is_poison_run
-                and any(params.adversary_slot_of(n) >= 0 and
-                        epoch in params.poison_epochs_for(
-                            params.adversary_slot_of(n))
-                        for n in agent_names)):
-            if self.stale_poison_probe and self.last_backdoor_acc is not None:
-                backdoor_acc = self.last_backdoor_acc  # round N-1's battery
+            backdoor_acc = None
+            if (params.type == cfg.TYPE_LOAN and self.is_poison_run
+                    and any(params.adversary_slot_of(n) >= 0 and
+                            epoch in params.poison_epochs_for(
+                                params.adversary_slot_of(n))
+                            for n in agent_names)):
+                if (self.stale_poison_probe
+                        and self.last_backdoor_acc is not None):
+                    backdoor_acc = self.last_backdoor_acc  # N-1's battery
+                else:
+                    with self.guard.watch("round/poison_probe"), \
+                            telemetry.span("round/poison_probe", round=epoch):
+                        backdoor_acc = float(self.engine.backdoor_acc_fn(
+                            self.global_vars))
+
+            slots = np.array([self.client_slots[n] for n in agent_names],
+                             np.int64)
+            # one segment per global epoch in the aggregation interval
+            # (image_train.py:50: the local model trains continuously across
+            # the interval; the server applies the summed update once)
+            seg_epochs = list(range(epoch, epoch + self.interval))
+            if self.dynamic_steps:
+                b = int(params["batch_size"])
+                round_max = max((len(self.client_indices[n])
+                                 for n in agent_names), default=1)
+                min_steps = self._bucket_steps(
+                    max(1, int(np.ceil(round_max / b))))
+                if (self._warmed_buckets
+                        and min_steps not in self._warmed_buckets):
+                    # warm shapes drifting from real round shapes is exactly
+                    # the failure warm_step_buckets exists to prevent
+                    logger.warning(
+                        "dispatch_round: step bucket S=%d was not pre-warmed "
+                        "(warmed: %s); this round pays a fresh XLA compile",
+                        min_steps, sorted(self._warmed_buckets))
             else:
-                with self.guard.watch("round/poison_probe"):
-                    backdoor_acc = float(self.engine.backdoor_acc_fn(
-                        self.global_vars))
+                min_steps = self.steps_per_epoch
+            tasks_list, idx_list, mask_list = [], [], []
+            num_samples_np = None
+            for ep in seg_epochs:
+                tasks_s = build_client_tasks(params, agent_names, ep, slots,
+                                             self.epochs_max, backdoor_acc)
+                plan = build_batch_plan(
+                    [self.client_indices[n] for n in agent_names],
+                    [int(e) for e in tasks_s.num_epochs],
+                    int(params["batch_size"]), self.plan_rng,
+                    min_steps=min_steps, min_epochs=self.epochs_max)
+                if num_samples_np is None:
+                    num_samples_np = plan.num_samples.astype(np.float32)
+                tasks_list.append(tasks_s)
+                idx_list.append(plan.idx)
+                mask_list.append(plan.mask)
 
-        slots = np.array([self.client_slots[n] for n in agent_names],
-                         np.int64)
-        # one segment per global epoch in the aggregation interval
-        # (image_train.py:50: the local model trains continuously across the
-        # interval; the server applies the summed update once)
-        seg_epochs = list(range(epoch, epoch + self.interval))
-        if self.dynamic_steps:
-            b = int(params["batch_size"])
-            round_max = max((len(self.client_indices[n])
-                             for n in agent_names), default=1)
-            min_steps = self._bucket_steps(
-                max(1, int(np.ceil(round_max / b))))
-            if self._warmed_buckets and min_steps not in self._warmed_buckets:
-                # warm shapes drifting from real round shapes is exactly the
-                # failure warm_step_buckets exists to prevent — be loud
-                logger.warning(
-                    "dispatch_round: step bucket S=%d was not pre-warmed "
-                    "(warmed: %s); this round pays a fresh XLA compile",
-                    min_steps, sorted(self._warmed_buckets))
-        else:
-            min_steps = self.steps_per_epoch
-        tasks_list, idx_list, mask_list = [], [], []
-        num_samples_np = None
-        for ep in seg_epochs:
-            tasks_s = build_client_tasks(params, agent_names, ep, slots,
-                                         self.epochs_max, backdoor_acc)
-            plan = build_batch_plan(
-                [self.client_indices[n] for n in agent_names],
-                [int(e) for e in tasks_s.num_epochs],
-                int(params["batch_size"]), self.plan_rng,
-                min_steps=min_steps, min_epochs=self.epochs_max)
-            if num_samples_np is None:
-                num_samples_np = plan.num_samples.astype(np.float32)
-            tasks_list.append(tasks_s)
-            idx_list.append(plan.idx)
-            mask_list.append(plan.mask)
+            if self.mesh is not None:
+                from dba_mod_tpu.parallel.mesh import pad_clients
+                c_pad = pad_clients(len(agent_names), self.mesh)
+                if c_pad != len(agent_names):
+                    if params.aggregation != cfg.AGGR_MEAN:
+                        raise ValueError(
+                            f"no_models={len(agent_names)} does not tile "
+                            f"the {self.mesh.devices.size}-device mesh; pick "
+                            "a multiple (inert-client padding is only sound "
+                            "for FedAvg, whose divisor is the static "
+                            "no_models)")
+                    pad = c_pad - len(agent_names)
+                    tasks_list = [_pad_tasks(t, pad, params.aggregation)
+                                  for t in tasks_list]
+                    idx_list = [np.pad(i, ((0, pad),) + ((0, 0),) * 3)
+                                for i in idx_list]
+                    mask_list = [np.pad(m, ((0, pad),) + ((0, 0),) * 3)
+                                 for m in mask_list]
+                    num_samples_np = np.pad(num_samples_np, (0, pad))
 
-        if self.mesh is not None:
-            from dba_mod_tpu.parallel.mesh import pad_clients
-            c_pad = pad_clients(len(agent_names), self.mesh)
-            if c_pad != len(agent_names):
-                if params.aggregation != cfg.AGGR_MEAN:
-                    raise ValueError(
-                        f"no_models={len(agent_names)} does not tile the "
-                        f"{self.mesh.devices.size}-device mesh; pick a "
-                        "multiple (inert-client padding is only sound for "
-                        "FedAvg, whose divisor is the static no_models)")
-                pad = c_pad - len(agent_names)
-                tasks_list = [_pad_tasks(t, pad, params.aggregation)
-                              for t in tasks_list]
-                idx_list = [np.pad(i, ((0, pad),) + ((0, 0),) * 3)
-                            for i in idx_list]
-                mask_list = [np.pad(m, ((0, pad),) + ((0, 0),) * 3)
-                             for m in mask_list]
-                num_samples_np = np.pad(num_samples_np, (0, pad))
+        with telemetry.span("round/stage", round=epoch):
+            tasks_seq = jax.tree_util.tree_map(
+                lambda *ls: jnp.asarray(np.stack(ls)), *tasks_list)
+            idx_seq = jnp.asarray(np.stack(idx_list))
+            mask_seq = jnp.asarray(np.stack(mask_list))
+            ns_dev = jnp.asarray(num_samples_np)
+            if self.mesh is not None:
+                from dba_mod_tpu.parallel.mesh import shard_round_inputs
+                tasks_seq, idx_seq, mask_seq, ns_dev = shard_round_inputs(
+                    self.mesh, tasks_seq, idx_seq, mask_seq, ns_dev)
 
-        tasks_seq = jax.tree_util.tree_map(
-            lambda *ls: jnp.asarray(np.stack(ls)), *tasks_list)
-        idx_seq = jnp.asarray(np.stack(idx_list))
-        mask_seq = jnp.asarray(np.stack(mask_list))
-        ns_dev = jnp.asarray(num_samples_np)
-        if self.mesh is not None:
-            from dba_mod_tpu.parallel.mesh import shard_round_inputs
-            tasks_seq, idx_seq, mask_seq, ns_dev = shard_round_inputs(
-                self.mesh, tasks_seq, idx_seq, mask_seq, ns_dev)
-
-        self.rng_key, round_key = jax.random.split(self.rng_key)
-        rng_train, rng_agg = jax.random.split(round_key)
-        lane = jnp.arange(idx_seq.shape[1], dtype=jnp.int32)
+            self.rng_key, round_key = jax.random.split(self.rng_key)
+            rng_train, rng_agg = jax.random.split(round_key)
+            lane = jnp.arange(idx_seq.shape[1], dtype=jnp.int32)
         # Three dispatch shapes: the fused round (one program, one dispatch —
-        # the perf path), the robust fused round (adds the screening sync +
-        # host retry loop), and the SPLIT path — clients-one-by-one for
-        # sequential_debug, or vmapped-per-phase when telemetry is on: the
-        # fused round is a single XLA program, so honest per-phase times
-        # require running train/aggregate/evals as separate programs with an
-        # explicit sync each (the same programs sequential_debug and
-        # bench.py's phase probe already exercise).
-        # overlap_eval outranks the telemetry split: its batteries are
-        # instrument_eval-wrapped (each call synced under telemetry) and the
-        # round loop is forced sequential (_run_rounds), so the split core +
-        # standalone batteries give the same honest per-phase attribution
-        # the telemetry split path exists for.
-        use_split = (self.sequential_debug
-                     or (self._telemetry_split and not self._overlap))
-        if not use_split:
+        # the perf path, whatever the `telemetry` knob says), the robust
+        # fused round (adds the screening sync + host retry loop), and the
+        # SPLIT path of sequential_debug (clients one by one, then the same
+        # aggregate/eval programs the fused round inlines). Per-phase device
+        # time of the fused round is read from its `phase/` scopes under a
+        # profiler trace (rounds.py::_round), not by splitting it.
+        with telemetry.span("round/enqueue", round=epoch):
+            if self.sequential_debug:
+                train = self._train_sequential(tasks_seq, idx_seq, mask_seq,
+                                               rng_train)
+                return self._finish_split_round(
+                    epoch, t0, seg_epochs, agent_names, adv_names,
+                    tasks_list, mask_list, tasks_seq, mask_seq, ns_dev,
+                    rng_agg, train)
             if self._overlap:
                 return self._dispatch_overlap(
                     epoch, t0, seg_epochs, agent_names, adv_names,
@@ -833,34 +834,19 @@ class Experiment:
                 vars_after=new_vars, fg_after=new_fg,
                 rng_after=self._snapshot_rng())
 
-        if self.sequential_debug:
-            train = self._train_sequential(tasks_seq, idx_seq, mask_seq,
-                                           rng_train)
-        else:
-            with self.guard.watch("round/train"), \
-                    self.telemetry.span("round/train"):
-                train = self.engine.train_fn(self.global_vars, tasks_seq,
-                                             idx_seq, mask_seq, lane,
-                                             rng_train)
-                self.telemetry.sync(train.deltas)
-        return self._finish_split_round(epoch, t0, seg_epochs, agent_names,
-                                        adv_names, tasks_list, mask_list,
-                                        tasks_seq, mask_seq, ns_dev,
-                                        rng_agg, train)
-
     def _finish_split_round(self, epoch, t0, seg_epochs, agent_names,
                             adv_names, tasks_list, mask_list, tasks_seq,
                             mask_seq, ns_dev, rng_agg,
                             train) -> RoundInFlight:
-        """Aggregate + eval batteries + payload assembly for the split
-        dispatch paths (sequential_debug and telemetry's per-phase mode) —
-        the same tail the fused round program runs on device."""
+        """Aggregate + eval batteries + payload assembly for
+        sequential_debug's split dispatch — the same tail the fused round
+        program runs on device."""
         params = self.params
         tasks_last = jax.tree_util.tree_map(lambda l: l[-1], tasks_seq)
         tasks_first = jax.tree_util.tree_map(lambda l: l[0], tasks_seq)
         from dba_mod_tpu.fl.rounds import nbt_client_deltas
         with self.guard.watch("round/aggregate"), \
-                self.telemetry.span("round/aggregate"):
+                telemetry.span("round/aggregate", round=epoch):
             result = self.engine.aggregate_fn(
                 self.global_vars, self.fg_state, train.deltas,
                 train.fg_grads, train.fg_feature,
@@ -870,9 +856,9 @@ class Experiment:
 
         # dispatch every eval before any host sync — one blocking transfer,
         # deferred to finalize_round so a caller can overlap the next round.
-        # (With telemetry on, the instrumented batteries sync here instead:
-        # honest eval/local + eval/global span times in exchange for the
-        # pipeline overlap.)
+        # (With telemetry on, the instrumented standalone batteries sync
+        # here instead: eval/local + eval/global span times in exchange for
+        # the pipeline overlap — sequential_debug only.)
         prev_deltas = (train.seg_deltas[-1] if train.seg_deltas else
                        jax.tree_util.tree_map(jnp.zeros_like, train.deltas))
         locals_dev = (self.engine.local_evals_fn(
@@ -1004,7 +990,7 @@ class Experiment:
             # the robust round stays ONE fused program (the screening sync
             # below is the pipeline cost it already pays) — telemetry times
             # it as a single round/compute span per attempt
-            with self.telemetry.span("round/compute"):
+            with telemetry.span("round/compute", round=epoch):
                 new_vars, new_fg, payload, deltas_out = self.engine.round_fn(
                     vars_before, fg_before, tasks_seq, idx_seq, mask_seq,
                     lane, ns_dev, rng_train, rng_agg, *extra)
@@ -1017,7 +1003,7 @@ class Experiment:
                                                           new_vars)
                 break
             with self.guard.watch("round/screen_sync"), \
-                    self.telemetry.span("round/screen_sync"):
+                    telemetry.span("round/screen_sync", round=epoch):
                 finite = bool(payload[9].global_finite)  # the one host sync
             healthy, unorm = True, 0.0
             if finite and self._sentinel is not None:
@@ -1118,7 +1104,7 @@ class Experiment:
                 extra = self._robust_round_args(epoch, C,
                                                 norm_mult=norm_mult,
                                                 use_carry=True)
-                with self.telemetry.span("round/compute"):
+                with telemetry.span("round/compute", round=epoch):
                     (new_vars, new_fg, payload, deltas_out,
                      eval_in) = engine.core_fn(
                         vars_before, fg_before, tasks_seq, idx_seq,
@@ -1130,7 +1116,7 @@ class Experiment:
                                                               new_vars)
                     break
                 with self.guard.watch("round/screen_sync"), \
-                        self.telemetry.span("round/screen_sync"):
+                        telemetry.span("round/screen_sync", round=epoch):
                     finite = bool(payload[9].global_finite)
                 healthy, unorm = True, 0.0
                 if finite and self._sentinel is not None:
@@ -1218,12 +1204,18 @@ class Experiment:
                 "rng_key": np.asarray(jax.random.key_data(self.rng_key))}
 
     def finalize_round(self, fl: RoundInFlight) -> Dict[str, Any]:
-        t_fin = time.perf_counter()
+        """The round's one blocking transfer (``round/fetch``) and its
+        recording (``round/record``), both under ``round/finalize``."""
         self.telemetry.set_epoch(fl.epoch)
-        # the round's one blocking transfer — the sync point where a wedged
-        # runtime stalls, hence the watchdog zone (run_guard.py)
+        with telemetry.span("round/finalize", round=fl.epoch):
+            return self._finalize(fl)
+
+    def _finalize(self, fl: RoundInFlight) -> Dict[str, Any]:
+        t_fin = time.perf_counter()
+        # the sync point where a wedged runtime stalls, hence the watchdog
+        # zone (run_guard.py)
         with self.guard.watch("round/finalize"), \
-                self.telemetry.span("round/finalize"):
+                telemetry.span("round/fetch", round=fl.epoch):
             (locals_, globals_, metrics, delta_norms, wv, alpha,
              batches, is_updated, seg_locals, rstats,
              fstats) = jax.device_get(fl.payload)
@@ -1268,14 +1260,18 @@ class Experiment:
             robust["n_dropped"] = int(rstats.n_dropped)
             robust["degraded"] = (bool(rstats.degraded)
                                   or bool(fl.forced_degraded))
-        self._record(fl.epoch, fl.seg_epochs, fl.agent_names, fl.adv_names,
-                     fl.tasks_list, metrics, locals_, globals_, delta_norms,
-                     wv, alpha, times, batches, fl.mask_list, seg_locals,
-                     robust)
-        if self.forensics_writer is not None and fstats is not None:
-            self._record_forensics(fl, locals_, delta_norms, wv, alpha,
-                                   fstats, robust)
-        self._flush_round_telemetry(fl, robust, delta_norms, times)
+        with telemetry.span("round/record", round=fl.epoch):
+            self._record(fl.epoch, fl.seg_epochs, fl.agent_names,
+                         fl.adv_names, fl.tasks_list, metrics, locals_,
+                         globals_, delta_norms, wv, alpha, times, batches,
+                         fl.mask_list, seg_locals, robust)
+            if self.forensics_writer is not None and fstats is not None:
+                self._record_forensics(fl, locals_, delta_norms, wv, alpha,
+                                       fstats, robust)
+            # flushes the histogram window: this round's own
+            # round/record and round/finalize end after it and land in the
+            # next line of telemetry.jsonl
+            self._flush_round_telemetry(fl, robust, delta_norms, times)
         return {"epoch": fl.epoch, "agents": fl.agent_names,
                 "global_acc": float(globals_.clean.acc),
                 "backdoor_acc": (float(globals_.poison.acc)
@@ -1584,7 +1580,7 @@ class Experiment:
         if not params["save_model"] or self.folder is None:
             return
         mgr = self.checkpoint_manager
-        with self.telemetry.span("round/checkpoint"):
+        with telemetry.span("round/checkpoint", round=epoch):
             model_vars = fl.vars_after if fl is not None else self.global_vars
             fg_state = fl.fg_after if fl is not None else self.fg_state
             rng = fl.rng_after if fl is not None else self._snapshot_rng()
@@ -1732,7 +1728,7 @@ class Experiment:
             # after the first full round, and a later round landing in a
             # fresh bucket would otherwise count its legitimate first
             # compile as a retrace regression
-            with self.telemetry.span("engine/warm_buckets"):
+            with telemetry.span("engine/warm_buckets"):
                 self.warm_step_buckets()
         # pipeline_rounds: overlap round N's host fetch/record with round
         # N+1's device compute (depth 1). Checkpoints ride orbax async saves

@@ -729,111 +729,118 @@ class RoundEngine:
                    rng_f=None, prev_deltas=(), norm_mult=None,
                    with_evals=True):
             robust = norm_mult is not None  # trace-time switch
-            train = train_fn(global_vars, tasks_seq, idx_seq, mask_seq,
-                             lane, rng_t)
+            # the four `phase/` scopes name the round's device operations
+            # in a profiler trace (metadata only: same compiled code)
+            with jax.named_scope("phase/train"):
+                train = train_fn(global_vars, tasks_seq, idx_seq, mask_seq,
+                                 lane, rng_t)
             deltas, fg_grads = train.deltas, train.fg_grads
             fg_feature = train.fg_feature
             tasks_last = jax.tree_util.tree_map(lambda l: l[-1], tasks_seq)
             tasks_first = jax.tree_util.tree_map(lambda l: l[0], tasks_seq)
-            nbt = nbt_client_deltas(mask_seq, tasks_seq.scale)
-            stats = None
-            fstats = None
-            deltas_out = ()
-            if robust:
-                counted = num_samples > 0
-                reported = jnp.ones_like(counted)
-                n_dropped = jnp.int32(0)
-                if fcfg.enabled:
-                    plan = flt.make_fault_plan(fcfg, rng_f, counted)
-                    stale = prev_deltas if fcfg.stale_enabled else None
-                    deltas = flt.perturb_tree(deltas, plan, fcfg, stale)
-                    if fg_enabled:
-                        # FoolsGold aggregates the gradient accumulators,
-                        # not the deltas — corrupt that payload too (stale
-                        # replay stays delta-only; see faults.py docstring)
-                        fg_grads = flt.perturb_tree(fg_grads, plan, fcfg)
-                        fg_feature = flt.perturb_tree(fg_feature, plan,
-                                                      fcfg)
-                    reported = ~plan.dropped
-                    n_dropped = jnp.sum(
-                        plan.dropped & counted).astype(jnp.int32)
-                if fcfg.stale_enabled:
-                    deltas_out = deltas  # what the server RECEIVED
-                if screening:
-                    extra = (fg_grads,) if fg_enabled else ()
-                    smask, _norms = screen_client_updates(
-                        deltas, reported, counted, norm_mult, extra)
-                else:
-                    # dropout is server-visible without any screening: a
-                    # client that never reported cannot be aggregated
-                    smask = reported
-                n_quar = jnp.sum(reported & ~smask
-                                 & counted).astype(jnp.int32)
-                n_surv = jnp.sum(smask & counted).astype(jnp.int32)
-                degraded = n_surv < min_surv
-                res = aggregate_fn(global_vars, fg_state, deltas, fg_grads,
-                                   fg_feature, tasks_first.participant_id,
-                                   num_samples, rng_a, nbt,
-                                   mask=smask.astype(jnp.float32))
-                # graceful degradation: too few survivors → skip the
-                # aggregate, carry the global model and defense state
-                new_vars = jax.tree_util.tree_map(
-                    lambda g, a: jnp.where(degraded, g, a),
-                    global_vars, res.new_vars)
-                new_fg = jax.tree_util.tree_map(
-                    lambda o, n: jnp.where(degraded, o, n),
-                    fg_state, res.new_fg_state)
-                gfin = jnp.asarray(True)
-                for l in jax.tree_util.tree_leaves(new_vars):
-                    gfin = gfin & jnp.all(
-                        jnp.isfinite(l.astype(jnp.float32)))
-                stats = RobustStats(n_dropped, n_quar, n_surv, degraded,
-                                    gfin, smask)
-                res = res._replace(new_vars=new_vars, new_fg_state=new_fg)
-                if forensics_on:
-                    # quarantine reason, consistent with the mask actually
-                    # applied: never-reported → dropped; reported but
-                    # screened out → nonfinite or norm_exceeded (screening
-                    # off means smask == reported, so the middle branch is
-                    # unreachable and `finite` is never consulted)
+            with jax.named_scope("phase/aggregate"):
+                nbt = nbt_client_deltas(mask_seq, tasks_seq.scale)
+                stats = None
+                fstats = None
+                deltas_out = ()
+                if robust:
+                    counted = num_samples > 0
+                    reported = jnp.ones_like(counted)
+                    n_dropped = jnp.int32(0)
+                    if fcfg.enabled:
+                        plan = flt.make_fault_plan(fcfg, rng_f, counted)
+                        stale = prev_deltas if fcfg.stale_enabled else None
+                        deltas = flt.perturb_tree(deltas, plan, fcfg, stale)
+                        if fg_enabled:
+                            # FoolsGold aggregates the gradient accumulators,
+                            # not the deltas — corrupt that payload too (stale
+                            # replay stays delta-only; see faults.py docstring)
+                            fg_grads = flt.perturb_tree(fg_grads, plan, fcfg)
+                            fg_feature = flt.perturb_tree(fg_feature, plan,
+                                                          fcfg)
+                        reported = ~plan.dropped
+                        n_dropped = jnp.sum(
+                            plan.dropped & counted).astype(jnp.int32)
+                    if fcfg.stale_enabled:
+                        deltas_out = deltas  # what the server RECEIVED
                     if screening:
-                        finite = _per_client_finite(deltas)
-                        for t in ((fg_grads,) if fg_enabled else ()):
-                            finite = finite & _per_client_finite(t)
+                        extra = (fg_grads,) if fg_enabled else ()
+                        smask, _norms = screen_client_updates(
+                            deltas, reported, counted, norm_mult, extra)
                     else:
-                        finite = jnp.ones_like(smask)
-                    reason = jnp.where(
-                        ~reported, jnp.int32(REASON_DROPPED),
-                        jnp.where(reported & ~smask,
-                                  jnp.where(finite, jnp.int32(REASON_NORM),
-                                            jnp.int32(REASON_NONFINITE)),
-                                  jnp.int32(REASON_OK)))
-                    fstats = forensic_stats(global_vars, new_vars, deltas,
-                                            smask, reason,
-                                            res.num_oracle_calls)
-            else:
-                res = aggregate_fn(global_vars, fg_state, deltas, fg_grads,
-                                   fg_feature, tasks_first.participant_id,
-                                   num_samples, rng_a, nbt)
-                if forensics_on:
-                    C = fg_feature.shape[0]
-                    fstats = forensic_stats(
-                        global_vars, res.new_vars, deltas,
-                        jnp.ones((C,), bool), jnp.zeros((C,), jnp.int32),
-                        res.num_oracle_calls)
+                        # dropout is server-visible without any screening: a
+                        # client that never reported cannot be aggregated
+                        smask = reported
+                    n_quar = jnp.sum(reported & ~smask
+                                     & counted).astype(jnp.int32)
+                    n_surv = jnp.sum(smask & counted).astype(jnp.int32)
+                    degraded = n_surv < min_surv
+                    res = aggregate_fn(global_vars, fg_state, deltas, fg_grads,
+                                       fg_feature, tasks_first.participant_id,
+                                       num_samples, rng_a, nbt,
+                                       mask=smask.astype(jnp.float32))
+                    # graceful degradation: too few survivors → skip the
+                    # aggregate, carry the global model and defense state
+                    new_vars = jax.tree_util.tree_map(
+                        lambda g, a: jnp.where(degraded, g, a),
+                        global_vars, res.new_vars)
+                    new_fg = jax.tree_util.tree_map(
+                        lambda o, n: jnp.where(degraded, o, n),
+                        fg_state, res.new_fg_state)
+                    gfin = jnp.asarray(True)
+                    for l in jax.tree_util.tree_leaves(new_vars):
+                        gfin = gfin & jnp.all(
+                            jnp.isfinite(l.astype(jnp.float32)))
+                    stats = RobustStats(n_dropped, n_quar, n_surv, degraded,
+                                        gfin, smask)
+                    res = res._replace(new_vars=new_vars, new_fg_state=new_fg)
+                    if forensics_on:
+                        # quarantine reason, consistent with the mask actually
+                        # applied: never-reported → dropped; reported but
+                        # screened out → nonfinite or norm_exceeded (screening
+                        # off means smask == reported, so the middle branch is
+                        # unreachable and `finite` is never consulted)
+                        if screening:
+                            finite = _per_client_finite(deltas)
+                            for t in ((fg_grads,) if fg_enabled else ()):
+                                finite = finite & _per_client_finite(t)
+                        else:
+                            finite = jnp.ones_like(smask)
+                        reason = jnp.where(
+                            ~reported, jnp.int32(REASON_DROPPED),
+                            jnp.where(reported & ~smask,
+                                      jnp.where(finite, jnp.int32(REASON_NORM),
+                                                jnp.int32(REASON_NONFINITE)),
+                                      jnp.int32(REASON_OK)))
+                        fstats = forensic_stats(global_vars, new_vars, deltas,
+                                                smask, reason,
+                                                res.num_oracle_calls)
+                else:
+                    res = aggregate_fn(global_vars, fg_state, deltas, fg_grads,
+                                       fg_feature, tasks_first.participant_id,
+                                       num_samples, rng_a, nbt)
+                    if forensics_on:
+                        C = fg_feature.shape[0]
+                        fstats = forensic_stats(
+                            global_vars, res.new_vars, deltas,
+                            jnp.ones((C,), bool), jnp.zeros((C,), jnp.int32),
+                            res.num_oracle_calls)
             prev = (train.seg_deltas[-1] if num_segments > 1 else
                     jax.tree_util.tree_map(jnp.zeros_like, train.deltas))
             if with_evals:
                 # the local battery evaluates what each client TRAINED
                 # (faults model the uplink, not local training) — pre-fault
                 # deltas
-                locals_ = (local_evals(global_vars, train.deltas, tasks_last,
-                                       prev)
-                           if do_local_eval else None)
-                seg_l = (seg_local_evals(global_vars, train.seg_deltas,
-                                         tasks_seq.scale, tasks_seq.adv_slot)
-                         if do_local_eval and num_segments > 1 else None)
-                globals_ = global_evals(res.new_vars)
+                with jax.named_scope("phase/local_battery"):
+                    locals_ = (local_evals(global_vars, train.deltas,
+                                           tasks_last, prev)
+                               if do_local_eval else None)
+                    seg_l = (seg_local_evals(
+                        global_vars, train.seg_deltas, tasks_seq.scale,
+                        tasks_seq.adv_slot)
+                        if do_local_eval and num_segments > 1 else None)
+                with jax.named_scope("phase/global_battery"):
+                    globals_ = global_evals(res.new_vars)
             else:
                 # overlap_eval's round CORE: the eval tail is stripped —
                 # the dispatcher runs the SAME jitted batteries as separate
@@ -956,8 +963,8 @@ class RoundEngine:
                 self.round_fn_donated = jax.jit(round_fn,
                                                 donate_argnums=(0, 1))
 
-        # Split-path forensics (sequential_debug / telemetry's per-phase
-        # dispatch — the robust path is never split): the same ForensicStats
+        # Split-path forensics (sequential_debug — the robust path is never
+        # split): the same ForensicStats
         # as its own tiny jitted program, called by _finish_split_round with
         # an all-ones mask (no screening on the split path). None when
         # forensics is off so the split payload keeps its None slot.

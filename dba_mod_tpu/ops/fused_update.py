@@ -43,6 +43,8 @@ import numpy as np
 # tile grid, 6.4× its logical bytes.
 _VMEM_BUDGET = 6 * 1024 * 1024
 
+KERNEL_NAME = "fused_sgd_update"
+
 # kind → (#inputs, #outputs) per leaf
 _ARITY = {"sgd": (3, 2), "acc": (2, 1), "sel": (2, 1)}
 
@@ -120,9 +122,10 @@ def _run_chunks(entries, lr2, valid2, momentum: float, weight_decay: float,
     outputs: List[Any] = [None] * len(entries)
     chunk: List[int] = []
     used = 0
+    n_calls = 0
 
     def flush():
-        nonlocal chunk, used
+        nonlocal chunk, used, n_calls
         if not chunk:
             return
         kinds = [entries[j][0] for j in chunk]
@@ -135,7 +138,11 @@ def _run_chunks(entries, lr2, valid2, momentum: float, weight_decay: float,
         outs = pl.pallas_call(
             _build_kernel(kinds, momentum, weight_decay),
             out_shape=out_shape, interpret=interpret,
+            # the name a device trace shows the kernel under; the chunk
+            # index follows it (metadata only)
+            name=f"{KERNEL_NAME}_{n_calls}",
         )(lr2, valid2, *ins)
+        n_calls += 1
         outs = list(outs) if isinstance(outs, (list, tuple)) else [outs]
         o = 0
         for j in chunk:

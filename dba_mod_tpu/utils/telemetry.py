@@ -1,74 +1,166 @@
-"""Process-wide telemetry: span tracing, a metrics registry, and XLA
-compile/memory instrumentation for the round path.
+"""Process-wide telemetry: layer-boundary spans on the profiler's clock, a
+metrics registry, and XLA compile/memory instrumentation for the round path.
 
-Until now the only per-round observability was the recorder's CSV/JSONL
-parity set plus a single wall-clock `round_time` — "where did this round's
-time go, did XLA recompile, and what did the device hold" needed an external
-profiler. This module makes those first-class:
-
-- **Spans** — nestable ``with telemetry.span("round/dispatch"):`` blocks
-  timed with ``time.perf_counter()``. Because JAX dispatch is asynchronous, a
-  span that measures device work must end at an explicit sync point:
-  ``telemetry.sync(payload)`` (``jax.block_until_ready``) inside the block,
-  or :func:`instrument`, which wraps a compiled callable so every call runs
-  under a synced span. Spans export as Chrome-trace-format ``trace.json``
-  (open in Perfetto / ``chrome://tracing``) and feed per-round duration
-  histograms.
-- **Metrics registry** — counters (cumulative), gauges (last value) and
-  histograms (windowed between flushes). :meth:`Telemetry.flush_round`
-  writes one JSON line per round to ``telemetry.jsonl`` and mirrors scalars
-  to the recorder's TensorBoard writer under ``telemetry/...`` tags.
-- **XLA instrumentation** — a ``jax.monitoring`` listener counts every
-  backend compile (jit cache miss that reaches XLA); after
-  :meth:`Telemetry.mark_warm` any further compile increments
-  ``xla/recompiles_after_warmup`` and logs loudly, so silent retrace
-  regressions fail in tests instead of burning device-minutes in
-  production. Per-round device memory gauges come from
-  ``jax.local_devices()[0].memory_stats()`` where the backend provides it
-  (TPU does; CPU returns None and the gauges are simply absent).
+- **Spans** — ``with telemetry.span("round/plan", round=epoch):`` is the one
+  way the program marks a layer boundary, and it is always on. A span enters
+  a ``jax.profiler.TraceAnnotation`` (so under any ``jax.profiler`` trace —
+  ``profile_dir``, or a benchmark harness — it lies in the same
+  ``.xplane.pb`` as the device operations) and appends one
+  :class:`SpanRecord` (name, start/end in ``time.time_ns()``, which is the
+  clock the profiler's ``TraceMe`` reads; the enclosing span; the round) to a
+  bounded process-wide list, read with :func:`spans`. A span times HOST work:
+  it never syncs the device, so the program that is traced is the program
+  that is timed. Device time per phase comes from the ``jax.named_scope``
+  names inside the round program (``phase/train``, ``phase/aggregate``,
+  ``phase/local_battery``, ``phase/global_battery``; fl/rounds.py) under a
+  profiler trace. With nothing exporting a span costs two clock reads, one
+  list append and an inactive ``TraceMe``.
+- **Compile stages** — a ``jax.monitoring`` listener, installed once per
+  process and counting whether or not the knob is on, sums seconds per stage
+  and per jitted function: ``xla/trace_secs``, ``xla/lower_secs``,
+  ``xla/compile_secs`` (on a persistent-cache hit this is the load) and
+  ``xla/cache_retrieval_secs``; read with :func:`compile_stages`.
+- **Exporters** (the ``telemetry`` knob) — :class:`Telemetry` adds the
+  metrics registry (counters cumulative, gauges last value, histograms
+  windowed between flushes), one JSON line per round in ``telemetry.jsonl``,
+  the Chrome-trace ``trace.json`` written from the span list, the
+  TensorBoard mirror under ``telemetry/...``, the end-of-run summary table,
+  the recompile-after-warmup alarm (:meth:`Telemetry.mark_warm`) and device
+  memory gauges (``memory_stats()``; absent on the CPU backend). The knob
+  selects exporters only: it never decides whether spans exist nor which
+  program a round runs.
 
 The module keeps ONE process-wide current instance (:func:`current`),
-defaulting to a no-op null object: call sites in the round path pay a single
-attribute check when telemetry is off, and the knobs (``telemetry``,
-``telemetry_dir`` in config.py) add no files and no per-round work. These
-files are additive observability, not part of the reference-parity CSV set
-(PARITY.md).
+defaulting to a no-op null object that holds no state. These files are
+additive observability, not part of the reference-parity CSV set (PARITY.md).
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import logging
 import os
+import re
 import threading
 import time
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 logger = logging.getLogger("dba_mod_tpu")
-
-# Round-pipelining metric family (fl/experiment.py, fl/async_rounds.py —
-# README "Round pipelining"). Emitted only when overlap_eval is ON and
-# telemetry is ON, which forces the round loop SEQUENTIAL: per-phase span
-# attribution (dispatch vs eval vs finalize) is only honest when phases do
-# not overlap, so the engines trade the pipelining away rather than record
-# misattributed spans. The counters below therefore measure the split
-# program running serially — the hidden-time clocks come from the
-# experiment's host-side accumulators (bench.py reports them per lane).
-#   overlap/rounds              counter — rounds run through the split path
-#   overlap/hidden_eval_s       gauge   — cumulative eval+sync seconds that
-#                                         ran behind the next dispatch
-#   overlap/dispatch_ahead_depth gauge  — in-flight rounds ahead (depth 1)
-#   overlap/eval_wait_s         histogram — per-round blocking fetch tail
-OVERLAP_METRIC_PREFIX = "overlap/"
 
 # jax.monitoring event fired on every backend compile — i.e. every jit cache
 # miss that actually reaches XLA (tracing-only cache hits don't fire it).
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # persistent-compile-cache misses (only fired when the disk cache is enabled)
 PERSISTENT_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# fired inside the backend-compile event on a persistent-cache hit, with no
+# fun_name: it takes the name of the lowering that preceded it on the thread
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla/trace_secs",
+    LOWER_EVENT: "xla/lower_secs",
+    BACKEND_COMPILE_EVENT: "xla/compile_secs",
+    CACHE_RETRIEVAL_EVENT: "xla/cache_retrieval_secs",
+}
 
 _LOCK = threading.Lock()
+
+
+# ------------------------------------------------------------------- spans
+class SpanRecord(NamedTuple):
+    """One finished span. Times are ``time.time_ns()``: the clock of the
+    profiler's annotations (an xplane's ``profile_start_time`` is its zero)."""
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[str]   # the enclosing span on this thread
+    round: Optional[int]    # `round=` of this span, else the enclosing one's
+    tid: int
+
+
+MAX_SPAN_RECORDS = 200_000  # about 20 spans a round; later ones are dropped
+_records: List[SpanRecord] = []
+_dropped = 0
+_local = threading.local()  # .stack: open (name, round) pairs; .lowered
+
+
+def _stack() -> list:
+    stack = getattr(_local, "stack", None)
+    if stack is None:
+        stack = _local.stack = []
+    return stack
+
+
+class _Span:
+    __slots__ = ("name", "ids", "_annotation", "_parent", "_round", "_t0")
+
+    def __init__(self, name: str, ids: Dict[str, Any]):
+        self.name = name
+        self.ids = ids
+
+    def __enter__(self):
+        stack = _stack()
+        self._parent, outer_round = stack[-1] if stack else (None, None)
+        self._round = self.ids.get("round", outer_round)
+        stack.append((self.name, self._round))
+        self._annotation = TraceAnnotation(self.name, **self.ids)
+        self._annotation.__enter__()
+        self._t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        global _dropped
+        end = time.time_ns()
+        self._annotation.__exit__(*exc)
+        _stack().pop()
+        record = SpanRecord(self.name, self._t0, end, self._parent,
+                            self._round, threading.get_ident())
+        kept = len(_records) < MAX_SPAN_RECORDS
+        if kept:
+            _records.append(record)
+        else:
+            _dropped += 1
+        if _current.enabled:
+            _current._on_span(record, kept)
+        return False
+
+
+def span(name: str, **ids):
+    """Nestable block that marks a layer boundary of the program; `ids`
+    (``round=epoch``) go onto the profiler annotation and the record."""
+    return _Span(name, ids)
+
+
+def spans(since: int = 0) -> List[SpanRecord]:
+    """The process's span records from index `since`, in order of their end."""
+    return _records[since:]
+
+
+def spans_dropped() -> int:
+    return _dropped
+
+
+def phase() -> str:
+    """The calling thread's innermost open span, "-" outside any."""
+    stack = getattr(_local, "stack", None)
+    return stack[-1][0] if stack else "-"
+
+
+def span_stack() -> List[str]:
+    """Names of the calling thread's open spans (thread-local — a caller that
+    needs another thread's stack captures it *in* that thread, as the
+    watchdog does at zone entry)."""
+    return [name for name, _ in getattr(_local, "stack", None) or ()]
+
+
+class _SpanAccess:
+    """`t.span(...)`, `t.phase()`, `t.span_stack()` on either telemetry
+    object: the module's, whatever instance is current."""
+    span = staticmethod(span)
+    phase = staticmethod(phase)
+    span_stack = staticmethod(span_stack)
 
 
 def _percentile(sorted_vals: List[float], q: float) -> float:
@@ -146,17 +238,14 @@ class _NullMetric:
 
 
 _NULL_METRIC = _NullMetric()
-_NULL_CM = contextlib.nullcontext()  # reusable; nullcontext holds no state
 
 
-class _NullTelemetry:
-    """The disabled telemetry object: every operation is a no-op, `enabled`
-    is the one attribute hot paths check. Shared singleton."""
+class _NullTelemetry(_SpanAccess):
+    """The disabled telemetry object: it exports nothing and holds no state
+    (spans are recorded by the module, not by an instance). `enabled` is the
+    one attribute hot paths check. Shared singleton."""
     enabled = False
     current_epoch: Optional[int] = None
-
-    def span(self, name: str):
-        return _NULL_CM
 
     def sync(self, x: Any) -> Any:
         return x
@@ -169,12 +258,6 @@ class _NullTelemetry:
 
     def histogram(self, name: str) -> _NullMetric:
         return _NULL_METRIC
-
-    def phase(self) -> str:
-        return "-"
-
-    def span_stack(self) -> List[str]:
-        return []
 
     def set_epoch(self, epoch: Optional[int]) -> None:
         pass
@@ -201,26 +284,24 @@ class _NullTelemetry:
 NULL = _NullTelemetry()
 
 
-class Telemetry:
-    """One run's telemetry state. Construct via :func:`configure` so call
-    sites throughout the round path resolve it through :func:`current`."""
+class Telemetry(_SpanAccess):
+    """One run's exporters and registry. Construct via :func:`configure` so
+    call sites throughout the round path resolve it through :func:`current`.
+    Its ``trace.json`` and summary table cover the span records made since
+    it was built."""
 
     enabled = True
     TRACE_WRITE_EVERY = 20  # flushes between periodic trace.json rewrites
 
     def __init__(self, folder: Optional[Path] = None,
-                 tb_sink: Optional[Callable[[str, float, int], None]] = None,
-                 max_trace_events: int = 200_000):
+                 tb_sink: Optional[Callable[[str, float, int], None]] = None):
         self.folder = Path(folder) if folder is not None else None
         self.tb_sink = tb_sink
-        self.max_trace_events = int(max_trace_events)
-        self._origin = time.perf_counter()
+        self._origin_ns = time.time_ns()
+        self._first_span = len(_records)
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
-        self._trace_events: List[dict] = []
-        self._span_all: Dict[str, List[float]] = {}
-        self._local = threading.local()
         self._flush_count = 0
         self._warm = False
         self.current_epoch: Optional[int] = None
@@ -253,57 +334,22 @@ class Telemetry:
         return h
 
     # ---------------------------------------------------------------- spans
-    def _stack(self) -> List[str]:
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
-            stack = self._local.stack = []
-        return stack
+    def own_spans(self) -> List[SpanRecord]:
+        return _records[self._first_span:]
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        """Nestable timed block. End device-measuring spans at a sync point:
-        call :meth:`sync` on the measured payload inside the block."""
-        stack = self._stack()
-        stack.append(name)
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            stack.pop()
-            self._record_span(name, t0, dur)
+    def _on_span(self, record: SpanRecord, kept: bool) -> None:
+        """A span ended while this instance is current: feed the per-round
+        duration histogram; count a record the full list turned away."""
+        if not kept:
+            self.counter("trace/dropped_events").inc()
+        self.histogram(f"span/{record.name}").observe(
+            (record.end_ns - record.start_ns) / 1e9)
 
     def sync(self, x: Any) -> Any:
-        """``jax.block_until_ready`` on `x` — the explicit device-sync point
-        that makes a span honest under JAX's async dispatch."""
+        """``jax.block_until_ready`` on `x` — for the standalone programs of
+        the split paths (:func:`instrument`), never the fused round."""
         import jax
         return jax.block_until_ready(x)
-
-    def _record_span(self, name: str, t0: float, dur: float) -> None:
-        event = {"name": name, "ph": "X", "cat": "span",
-                 "ts": (t0 - self._origin) * 1e6, "dur": dur * 1e6,
-                 "pid": os.getpid(), "tid": threading.get_ident()}
-        with _LOCK:
-            if len(self._trace_events) < self.max_trace_events:
-                self._trace_events.append(event)
-                dropped = False
-            else:
-                dropped = True
-            self._span_all.setdefault(name, []).append(dur)
-        if dropped:
-            self.counter("trace/dropped_events").inc()
-        self.histogram(f"span/{name}").observe(dur)
-
-    def phase(self) -> str:
-        stack = getattr(self._local, "stack", None)
-        return stack[-1] if stack else "-"
-
-    def span_stack(self) -> List[str]:
-        """Copy of the calling thread's open-span stack (thread-local —
-        callers that need another thread's stack must capture it *in* that
-        thread, e.g. the watchdog captures at zone entry)."""
-        stack = getattr(self._local, "stack", None)
-        return list(stack) if stack else []
 
     def set_epoch(self, epoch: Optional[int]) -> None:
         self.current_epoch = epoch
@@ -387,9 +433,14 @@ class Telemetry:
         trace."""
         if self.folder is None:
             return
-        with _LOCK:
-            events = list(self._trace_events)
-        meta = [{"name": "process_name", "ph": "M", "pid": os.getpid(),
+        pid = os.getpid()
+        events = [{"name": r.name, "ph": "X", "cat": "span",
+                   "ts": (r.start_ns - self._origin_ns) / 1e3,
+                   "dur": (r.end_ns - r.start_ns) / 1e3,
+                   "pid": pid, "tid": r.tid,
+                   "args": {"round": r.round, "parent": r.parent}}
+                  for r in self.own_spans()]
+        meta = [{"name": "process_name", "ph": "M", "pid": pid,
                  "args": {"name": "dba_mod_tpu"}}]
         doc = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
         path = self.folder / "trace.json"
@@ -401,8 +452,11 @@ class Telemetry:
     def summary_table(self) -> str:
         """End-of-run phase summary: p50/p95 per span, recompile count, peak
         device memory."""
-        with _LOCK:
-            spans = {k: sorted(v) for k, v in self._span_all.items()}
+        by_name: Dict[str, List[float]] = {}
+        for r in self.own_spans():
+            by_name.setdefault(r.name, []).append(
+                (r.end_ns - r.start_ns) / 1e9)
+        spans = {k: sorted(v) for k, v in by_name.items()}
         lines = [f"{'span':<32} {'count':>6} {'total_s':>9} "
                  f"{'p50_ms':>9} {'p95_ms':>9}"]
         for name in sorted(spans):
@@ -439,24 +493,19 @@ def current() -> Any:
 def configure(enabled: bool, folder: Optional[Path] = None,
               tb_sink: Optional[Callable[[str, float, int], None]] = None,
               ) -> Any:
-    """Install (or clear) the process-wide telemetry instance. With
-    `enabled` False the null object is installed and no files are touched.
+    """Install (or clear) the process-wide exporter instance. With `enabled`
+    False the null object is installed and no files are touched; spans and
+    compile stages are recorded either way.
     One instance per process: a second Experiment in the same process takes
-    over the module-level current, so spans from SHARED code paths
-    (checkpoint.py, rounds.py eval wrappers) follow the most recent
-    experiment — an Experiment's own round spans go through its
-    `self.telemetry` handle and are unaffected by the takeover."""
+    over the module-level current, so the span histograms and the counters
+    of SHARED code paths (checkpoint.py, rounds.py eval wrappers) follow the
+    most recent experiment; an Experiment's own registry and per-round flush
+    go through its `self.telemetry` handle and are unaffected."""
     global _current
-    if not enabled:
-        _current = NULL
-        return NULL
-    _current = Telemetry(folder=folder, tb_sink=tb_sink)
-    install_xla_listeners()
+    install_xla_listeners()  # compile stages count with the knob off too
+    _current = (Telemetry(folder=folder, tb_sink=tb_sink) if enabled
+                else NULL)
     return _current
-
-
-def span(name: str):
-    return _current.span(name)
 
 
 def sync(x: Any) -> Any:
@@ -505,12 +554,39 @@ def instrument(fn: Callable, name: str, batches: int = 0) -> Callable:
 
 
 # ------------------------------------------------------------- XLA listeners
+_compile_stages: Dict[str, Dict[str, float]] = {}
+_JIT_WRAPPER = re.compile(r"^p?jit[(_]|\)$")
+
+
+def compile_stages() -> Dict[str, Dict[str, float]]:
+    """Seconds this process spent per jitted function and compile stage:
+    ``{"round_fn": {"xla/trace_secs": .., "xla/lower_secs": ..,
+    "xla/compile_secs": .., "xla/cache_retrieval_secs": ..}, ...}``. A stage
+    a function never reached is absent (no retrieval on a cache miss)."""
+    with _LOCK:
+        return {fun: dict(stages) for fun, stages in _compile_stages.items()}
+
+
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
+    stage = COMPILE_STAGE_OF.get(event)
+    if stage is None:
+        return
+    # tracing names the function (`round_fn`), lowering and the backend
+    # compile its module (`jit(round_fn)`, `jit_round_fn`)
+    fun = str(kwargs.get("fun_name") or getattr(_local, "lowered", "?"))
+    fun = _JIT_WRAPPER.sub("", fun)
+    if event == LOWER_EVENT:
+        _local.lowered = fun
+    with _LOCK:
+        stages = _compile_stages.setdefault(fun, {})
+        stages[stage] = stages.get(stage, 0.0) + float(duration)
     t = _current
-    if not t.enabled or event != BACKEND_COMPILE_EVENT:
+    if not t.enabled:
+        return
+    t.histogram(stage).observe(duration)
+    if event != BACKEND_COMPILE_EVENT:
         return
     t.counter("xla/compiles").inc()
-    t.histogram("xla/compile_secs").observe(duration)
     if t._warm:
         t.counter("xla/recompiles_after_warmup").inc()
         logger.warning(
@@ -542,10 +618,8 @@ class _PhaseFilter(logging.Filter):
     record so the formatter can show where in the round a line came from."""
 
     def filter(self, record: logging.LogRecord) -> bool:
-        t = _current
-        ep = t.current_epoch
-        record.phase = (f"e{ep}/{t.phase()}" if ep is not None
-                        else t.phase())
+        ep = _current.current_epoch
+        record.phase = f"e{ep}/{phase()}" if ep is not None else phase()
         return True
 
 

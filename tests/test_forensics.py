@@ -14,8 +14,8 @@ Coverage:
      measurably lower aggregation weights than benign clients in the
      emitted CSV (the ISSUE acceptance gate);
   5. the `report` renderer produces a self-contained HTML round-audit;
-  6. split-dispatch parity — telemetry's per-phase path fills the same
-     forensic record via the standalone forensic_fn.
+  6. telemetry parity — `telemetry: true` keeps the fused round program,
+     whose ForensicStats slot fills the same forensic record.
 
 Experiment builds dominate the wall clock here, so the benign-FedAvg and
 sybil-FoolsGold runs are module-scoped fixtures shared by every test that
@@ -210,13 +210,22 @@ def test_report_html(sybil_run):
     assert "http://" not in stripped and "https://" not in stripped
 
 
-# --------------------------------------------- split-dispatch (telemetry)
-def test_split_dispatch_fills_forensics(tmp_path):
-    """Telemetry's per-phase dispatch path assembles the same forensic
-    record via the standalone forensic_fn."""
-    e, _ = _run_to_folder(
-        tmp_path, dict(BASE, forensics=True, telemetry=True), 2)
+# ------------------------------------------ fused dispatch under telemetry
+def test_split_dispatch_fills_forensics(tmp_path, mean_run):
+    """`telemetry: true` no longer splits the round: the fused program's own
+    ForensicStats slot fills the record (the standalone forensic_fn is
+    sequential_debug's alone), with the numbers of a telemetry-off run."""
+    from dba_mod_tpu.utils import telemetry as tel
+    try:
+        e, _ = _run_to_folder(
+            tmp_path, dict(BASE, forensics=True, telemetry=True), 2)
+    finally:
+        tel.configure(enabled=False)
+    assert e.engine.round_fn._cache_size() == 1
+    assert e.engine.forensic_fn._cache_size() == 0
+    assert e.engine.train_fn._cache_size() == 0  # no split-phase program
     header, rows = _read_csv(e.folder)
+    assert (header, rows) == _read_csv(mean_run[0].folder)
     assert len(rows) == 2 * 4
     recs = [dict(zip(header, r)) for r in rows]
     assert all(r["verdict"] == "1" and r["reason"] == "ok" for r in recs)

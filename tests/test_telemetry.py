@@ -1,13 +1,17 @@
 """Telemetry subsystem (utils/telemetry.py) + the recorder's crash-safe
 saves.
 
-Covers: span nesting and timing monotonicity, Chrome-trace JSON schema,
-counter/gauge/histogram flush semantics (cumulative counters, windowed
-histograms), the XLA recompile listener (fires on a forced retrace, silent
-on a cache hit), no-op mode adding no files, idempotent logging setup, the
-recorder's atomic save (a failure mid-write leaves the previous file
-intact), and the end-to-end Experiment wiring — telemetry files with the
-required per-round spans, and none at all when the knob is off.
+Covers: span nesting and timing monotonicity, the always-on span records
+(name, clock, parent, round) of a run with the knob off, Chrome-trace JSON
+schema, counter/gauge/histogram flush semantics (cumulative counters,
+windowed histograms), the XLA listeners (recompile alarm; compile stages per
+jitted function with the knob off), no-op mode adding no files, idempotent
+logging setup, the recorder's atomic save (a failure mid-write leaves the
+previous file intact), the names the device trace needs (the round program's
+four `phase/` scopes, the fused update's kernel name), the benchmark's
+reduction of them (chipbench/phases.py), and the end-to-end Experiment wiring
+— `telemetry: true` runs the same fused program as off, to the bit, and only
+adds the exporters' files.
 """
 import csv
 import json
@@ -46,13 +50,12 @@ def test_span_nesting_and_timing_monotonicity(enabled_tel):
         time.sleep(0.01)
         with tel.span("inner"):
             time.sleep(0.01)
-    events = {e["name"]: e for e in enabled_tel._trace_events}
-    outer, inner = events["outer"], events["inner"]
-    assert inner["dur"] > 0 and outer["dur"] >= inner["dur"]
+    records = {r.name: r for r in enabled_tel.own_spans()}
+    outer, inner = records["outer"], records["inner"]
+    assert inner.end_ns > inner.start_ns
+    assert inner.parent == "outer" and outer.parent is None
     # containment: the inner span starts no earlier and ends no later
-    assert inner["ts"] >= outer["ts"]
-    assert (inner["ts"] + inner["dur"]
-            <= outer["ts"] + outer["dur"] + 1.0)  # 1 µs slack
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
     # spans feed duration histograms
     assert enabled_tel.histogram("span/outer").total_count == 1
     assert enabled_tel.histogram("span/inner").total_count == 1
@@ -233,7 +236,85 @@ def test_round_header_carries_split_times():
 
 
 # ------------------------------------------------------------- end-to-end
-def test_experiment_telemetry_end_to_end(tmp_path):
+ROUND_SPANS = {"round/dispatch": ("round/plan", "round/stage",
+                                  "round/enqueue"),
+               "round/finalize": ("round/fetch", "round/record")}
+
+
+def _state_and_rows(e):
+    leaves = [np.asarray(l) for l in jax.tree_util.tree_leaves(e.global_vars)]
+    rows = [{k: v for k, v in row.items() if not k.endswith("time")}
+            for row in e.recorder._jsonl_rows]
+    return leaves, rows
+
+
+@pytest.fixture(scope="module")
+def off_run(tmp_path_factory):
+    """Two rounds with the knob off, shared read-only: (experiment, the span
+    records it made, the compile stages of the process after it)."""
+    n0 = len(tel.spans())
+    tmp = tmp_path_factory.mktemp("tel_off")
+    e = Experiment(Params.from_dict(dict(
+        SMOKE, run_dir=str(tmp / "runs"), save_model=True,
+        checkpoint_dir=str(tmp / "saved_models"))))
+    e.run()
+    return e, tel.spans(n0), tel.compile_stages()
+
+
+def test_span_records_carry_name_clock_parent_round(off_run):
+    _, records, _ = off_run
+    names = {r.name for r in records}
+    assert {"setup/data", "setup/device_put", "setup/partition",
+            "engine/build", "round/checkpoint"} <= names
+    for parent, children in ROUND_SPANS.items():
+        assert {parent, *children} <= names
+    now = time.time_ns()
+    for r in records:
+        assert now - 3600 * 10 ** 9 < r.start_ns <= r.end_ns <= now
+        if r.name.startswith("round/"):
+            assert r.round in (1, 2)
+        if r.name.startswith("setup/"):
+            assert r.round is None and r.parent is None
+
+
+def test_round_children_lie_inside_dispatch_and_finalize(off_run):
+    _, records, _ = off_run
+    for rnd in (1, 2):
+        of = {r.name: r for r in records if r.round == rnd}
+        for parent, children in ROUND_SPANS.items():
+            p = of[parent]
+            at = p.start_ns
+            for name in children:   # in order, inside, not overlapping
+                c = of[name]
+                assert c.parent == parent
+                assert at <= c.start_ns <= c.end_ns <= p.end_ns
+                at = c.end_ns
+        assert of["round/checkpoint"].start_ns >= of["round/finalize"].end_ns
+
+
+def test_experiment_telemetry_off_writes_no_files(off_run):
+    e, records, _ = off_run
+    assert records  # spans exist with the knob off
+    assert not (e.folder / "telemetry.jsonl").exists()
+    assert not (e.folder / "trace.json").exists()
+    assert e.telemetry is tel.NULL
+
+
+def test_compile_stages_count_with_the_knob_off(off_run):
+    e, _, stages = off_run
+    assert e.engine.round_fn._cache_size() == 1
+    got = stages["round_fn"]
+    assert {"xla/trace_secs", "xla/lower_secs", "xla/compile_secs"} <= set(got)
+    assert all(v > 0 for v in got.values())
+    # the batteries' and the reference's compiles are told from the round's
+    assert "round_fn" not in [k for k in stages if k != "round_fn"]
+    assert len(stages) > 1
+
+
+def test_experiment_telemetry_end_to_end(tmp_path, off_run):
+    """`telemetry: true` adds the exporters' files to a run of the SAME
+    fused program: the per-round spans of the fused path, no split-phase
+    span, one compiled round program, numbers equal to the knob-off run's."""
     e = Experiment(Params.from_dict(dict(
         SMOKE, telemetry=True, run_dir=str(tmp_path))))
     try:
@@ -244,18 +325,24 @@ def test_experiment_telemetry_end_to_end(tmp_path):
         doc = json.loads((folder / "trace.json").read_text())
         names = {ev["name"] for ev in doc["traceEvents"]
                  if ev.get("ph") == "X"}
-        assert {"round/dispatch", "round/finalize", "round/train",
-                "round/aggregate", "eval/local", "eval/global"} <= names
+        assert {"round/dispatch", "round/plan", "round/stage",
+                "round/enqueue", "round/finalize", "round/fetch",
+                "round/record", "engine/build", "setup/data"} <= names
+        assert not {"round/train", "round/aggregate", "eval/local",
+                    "eval/global"} & names
+        assert e.engine.round_fn._cache_size() == 1
+        assert e.engine.train_fn._cache_size() == 0
         lines = [json.loads(line) for line in
                  (folder / "telemetry.jsonl").read_text().splitlines()]
         assert [ln["epoch"] for ln in lines] == [1, 2]
         last = lines[-1]
-        # per-round span durations for dispatch/finalize/eval
-        for span in ("span/round/dispatch", "span/round/finalize",
-                     "span/eval/global"):
+        # per-round span durations; a round's finalize and record end after
+        # its flush, so round 1's are in round 2's line
+        for span in ("span/round/dispatch", "span/round/plan",
+                     "span/round/fetch", "span/round/finalize",
+                     "span/round/record"):
             assert last["histograms"][span]["count"] >= 1
         assert last["counters"]["rounds"] == 2
-        assert last["counters"]["eval/batches"] > 0
         # no retraces once the first full round has compiled everything
         assert last["counters"]["xla/recompiles_after_warmup"] == 0
         # the recorder carries the honest split times
@@ -267,32 +354,32 @@ def test_experiment_telemetry_end_to_end(tmp_path):
         assert float(times["finalize_time"]) > 0
         summary = e.telemetry.summary_table()
         assert "round/dispatch" in summary and "xla compiles" in summary
+        leaves_on, rows_on = _state_and_rows(e)
     finally:
         tel.configure(enabled=False)
-
-
-def test_experiment_telemetry_off_writes_no_files(tmp_path):
-    e = Experiment(Params.from_dict(dict(SMOKE, run_dir=str(tmp_path))))
-    e.run_round(1)
-    assert not (e.folder / "telemetry.jsonl").exists()
-    assert not (e.folder / "trace.json").exists()
-    assert e.telemetry is tel.NULL
+    leaves_off, rows_off = _state_and_rows(off_run[0])
+    assert rows_on == rows_off
+    for a, b in zip(leaves_on, leaves_off):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_split_path_falls_back_after_takeover(tmp_path):
-    """A later configure() (another Experiment taking over the process-wide
-    instance) must not leave the first experiment paying the split path's
-    per-phase syncs with no spans recorded — it falls back to the fused
-    program while still flushing per-round metrics on its own instance."""
+    """There is no split path to fall back from: after a later configure()
+    (another Experiment taking over the process-wide instance) the first
+    experiment still runs the fused program, still records its spans, and
+    still flushes per-round metrics on its own instance."""
     e = Experiment(Params.from_dict(dict(
         SMOKE, telemetry=True, telemetry_dir=str(tmp_path / "t"))),
         save_results=False)
     try:
-        assert e._telemetry_split
         tel.configure(enabled=False)  # a second experiment takes over
-        assert not e._telemetry_split  # → fused dispatch from here on
+        n0 = len(tel.spans())
         r = e.run_round(1)
         assert r["dispatch_time"] > 0
+        assert e.engine.round_fn._cache_size() == 1
+        assert e.engine.train_fn._cache_size() == 0
+        assert {"round/dispatch", "round/enqueue", "round/fetch"} <= {
+            s.name for s in tel.spans(n0)}
         lines = [json.loads(line) for line in
                  (tmp_path / "t" / "telemetry.jsonl").read_text()
                  .splitlines()]
@@ -302,18 +389,97 @@ def test_split_path_falls_back_after_takeover(tmp_path):
 
 
 def test_telemetry_split_path_matches_fused_metrics(tmp_path):
-    """telemetry=true routes rounds through the split-phase programs (the
-    same computations the fused round runs, as separate jits); the recorded
-    round metrics must agree with the fused path's."""
-    r_fused = Experiment(Params.from_dict(dict(SMOKE)),
-                         save_results=False).run_round(1)
+    """telemetry=true and telemetry=false dispatch the same round program
+    and record equal results — exactly, not to a tolerance."""
+    e_off = Experiment(Params.from_dict(dict(SMOKE)), save_results=False)
+    r_off = e_off.run_round(1)
     e = Experiment(Params.from_dict(dict(
         SMOKE, telemetry=True, telemetry_dir=str(tmp_path / "t"))),
         save_results=False)
     try:
-        r_split = e.run_round(1)
-        assert r_split["agents"] == r_fused["agents"]
-        np.testing.assert_allclose(r_split["global_acc"],
-                                   r_fused["global_acc"], rtol=1e-5)
+        r_on = e.run_round(1)
     finally:
         tel.configure(enabled=False)
+    timed = ("round_time", "dispatch_time", "finalize_time")
+    assert ({k: v for k, v in r_on.items() if k not in timed}
+            == {k: v for k, v in r_off.items() if k not in timed})
+    for on, off in zip(*(_state_and_rows(x)[0] for x in (e, e_off))):
+        np.testing.assert_array_equal(on, off)
+    for eng in (e.engine, e_off.engine):
+        assert eng.round_fn._cache_size() == 1
+        assert eng.train_fn._cache_size() == 0
+
+
+# ------------------------------------------- names the device trace needs
+def test_round_program_holds_the_four_phase_scopes(off_run):
+    e, _, _ = off_run
+    tasks_seq, idx_seq, mask_seq, ns, lane = e.build_static_round_inputs(3)
+    rng_t, rng_a = jax.random.split(jax.random.key(0))
+    text = e.engine.round_fn.lower(
+        e.global_vars, e.fg_state, tasks_seq, idx_seq, mask_seq, lane, ns,
+        rng_t, rng_a).as_text(debug_info=True)
+    for scope in ("phase/train", "phase/aggregate", "phase/local_battery",
+                  "phase/global_battery"):
+        assert f"jit(round_fn)/{scope}/" in text, scope
+
+
+def test_fused_update_pallas_call_carries_the_kernel_name():
+    from dba_mod_tpu.ops.fused_update import (KERNEL_NAME,
+                                              make_fused_step_update)
+    fused = make_fused_step_update(0.9, 5e-4, False, use_pallas=True,
+                                   interpret=True)
+    w = {"a": jnp.ones((3, 40)), "b": jnp.ones((3, 8, 16))}
+
+    def step(w, g):
+        lr, valid = jnp.full((3,), 0.1), jnp.ones((3,), bool)
+        return jax.vmap(fused)(lr, valid, w, g, g, {}, {}, {})
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    calls = list(pallas_calls(jax.make_jaxpr(step)(w, w).jaxpr))
+    assert calls
+    for i, eqn in enumerate(calls):
+        assert eqn.params["name"] == f"{KERNEL_NAME}_{i}"
+
+
+# --------------------------------- the benchmark's reduction of the names
+@pytest.mark.parametrize("case", ["nested_while", "unnamed_while",
+                                  "gap_in_fetch", "gap_in_no_span"])
+def test_phases_reduction_on_synthetic_events(case):
+    from chipbench import phases, selfcheck_phases
+    r = phases.reduce_events(*selfcheck_phases.synthetic_events())
+    if case == "nested_while":
+        # %while.30 [0.5, 6.5] s covers its body's operations: counted once
+        assert r["scope_s"]["phase/train"] == pytest.approx(6.0)
+        assert r["kernel_s"] == pytest.approx(0.75)
+        assert r["unattributed_s"] == pytest.approx(0.1)
+        (op, secs), = r["unattributed_ops"]
+        assert op.startswith("%copy.1") and secs == pytest.approx(0.1)
+        assert sum(r["scope_s"].values()) + r["unattributed_s"] == \
+            pytest.approx(r["busy_s"])
+    elif case == "unnamed_while":
+        # %while.27 [6.6, 7.6] s has no scope path; 0.9 s of it is named
+        # local-battery work, so the loop, its gap and its unnamed
+        # %reverse.1 count there and nothing of it is left unattributed
+        assert r["scope_s"]["phase/local_battery"] == pytest.approx(1.0)
+        assert not [op for op, _ in r["unattributed_ops"]
+                    if op.startswith(("%while.27", "%reverse.1"))]
+    elif case == "gap_in_fetch":
+        assert r["idle_by_program_span"]["round/fetch"] == pytest.approx(0.2)
+        assert r["idle_by_program_span"]["round/plan"] == pytest.approx(0.5)
+    else:
+        assert r["idle_by_program_span"]["no_span"] == pytest.approx(1.7)
+        assert r["idle_attributed_pct"] == pytest.approx(100 * 0.7 / 2.4)
+
+
+def test_phases_selfcheck_reduces_the_recorded_trace():
+    """chipbench/testdata/phases_sample.xplane.pb (recorded on a TPU v5e):
+    scope paths from the event metadata, the named kernel, the program's
+    spans; and every reader of them on a synthetic and an empty context."""
+    from chipbench import selfcheck_phases
+    assert selfcheck_phases.main() == 0
