@@ -17,4 +17,4 @@ from dba_mod_tpu.data.datasets import (ImageData, LoanData, load_image_dataset,
 from dba_mod_tpu.data.partition import (equal_split_indices,
                                         sample_dirichlet_indices)
 from dba_mod_tpu.data.batching import (BatchPlan, EvalPlan, build_batch_plan,
-                                       build_eval_plan)
+                                       build_eval_plan, plan_step_counts)

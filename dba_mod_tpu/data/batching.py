@@ -5,7 +5,10 @@ every internal epoch and yields a partial final batch (image_helper.py:252-263,
 drop_last=False). The TPU-native equivalent precomputes, per round, an index
 tensor [clients, epochs, steps, batch] plus a validity mask; the jitted client
 step gathers rows straight from the device-resident dataset — the host ships
-only these small int32 plans each round.
+only these small int32 plans each round. The plan's shape is static (one
+compiled round program); the client step's loop runs only the steps in which
+some client's mask holds a real row (fl/client.py::active_steps), so a step
+padded in every client costs nothing, and `plan_step_counts` counts both.
 
 Shuffling uses per-client numpy RNG rather than the reference's global torch
 RNG: the sequential loop's RNG stream is inherently irreproducible under
@@ -14,7 +17,7 @@ parallel clients, so parity here is statistical (SURVEY §7.2.4).
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence
+from typing import Dict, List, Sequence
 
 import numpy as np
 
@@ -68,6 +71,21 @@ def build_batch_plan(client_indices: Sequence[Sequence[int]],
             mask[c, e] = m.reshape(S, batch_size)
     return BatchPlan(idx=idx.astype(np.int32), mask=mask, num_samples=sizes,
                      num_epochs=np.asarray(client_epochs, np.int32))
+
+
+def plan_step_counts(masks: Sequence[np.ndarray]) -> Dict[str, int]:
+    """What a round's plan asks of the steps loop, from its masks (one
+    [C, E, S, B] per segment): `steps_plan` the loop's static length over
+    the segments (E x S each), `steps_run` the steps in which ANY lane holds a
+    real batch — what the client step's loop reads from the same mask and
+    runs, rounded up to its chunk (fl/client.py::active_steps, STEP_CHUNK) —
+    `lane_steps_real` the real client-steps, and `lanes` (C). `steps_run x lanes - lane_steps_real` lane-steps still
+    run masked: what packing lanes could win."""
+    real = np.stack([np.asarray(m).any(axis=-1) for m in masks])  # [I,C,E,S]
+    return {"steps_plan": int(real.shape[0] * real.shape[2] * real.shape[3]),
+            "steps_run": int(real.any(axis=1).sum()),
+            "lane_steps_real": int(real.sum()),
+            "lanes": int(real.shape[1])}
 
 
 def build_eval_plan(indices: np.ndarray, batch_size: int) -> EvalPlan:

@@ -1,5 +1,7 @@
-"""The single-client local-training step — one `lax.scan`, vmapped over the
-clients axis by the round engine.
+"""The single-client local-training step — one loop over the plan's steps,
+vmapped over the clients axis by the round engine. The loop's trip count is
+read from the round's mask: only the steps in which SOME lane has a real
+batch run (`active_steps`), in their original order, STEP_CHUNK at a time.
 
 Capability parity with the reference client loop (image_train.py:21-315,
 loan_train.py:17-261), re-expressed as data-dependent selects so benign and
@@ -66,22 +68,52 @@ def _select_tree(pred, new, old):
         lambda a, b: jnp.where(pred, a, b), new, old)
 
 
+# The steps loop runs in chunks of this many plan steps: an outer loop with a
+# trip count read from the mask around an inner loop of static length. The
+# inner loop is there for the compiler: XLA:TPU compiled the step body
+# differently where it sat directly in a loop (or a conditional) whose trip
+# count it does not know — one convolution's weight gradient came out 87 %
+# off the plain reference (PERF.md, PR 25) — and as it always had inside a
+# loop of known length. Up to STEP_CHUNK - 1 fully masked steps run with the
+# last chunk.
+STEP_CHUNK = 4
+
+
+def active_steps(mask):
+    """mask [C, E, S, B] of one segment -> (order [E*S], n_chunks): the flat
+    ids of the steps in which any lane holds a real batch, first and in their
+    original order (the rest follow), and how many chunks of STEP_CHUNK
+    positions of `order` hold them all. A step masked in every lane is a
+    no-op by construction — the update selects the old state and every
+    metric adds 0 — so the loop leaves it out; what a step computes depends
+    on its (epoch, step) id, never on how many steps ran before it."""
+    active = jnp.any(mask, axis=(0, 3)).reshape(-1)
+    n_run = jnp.sum(active, dtype=jnp.int32)
+    return (jnp.argsort(~active, stable=True),
+            (n_run + STEP_CHUNK - 1) // STEP_CHUNK)
+
+
 def make_client_step(model_def: ModelDef, data: DeviceData,
                      hyper: RoundHyper, fg_enabled: bool,
                      fused_pallas: bool = False,
                      fused_interpret: bool = False):
-    """Returns client_step(start_vars, task_row, idx[E,S,B], mask[E,S,B],
-    rng) -> SegmentResult, suitable for vmap over (start_vars, task_row, idx,
-    mask, rng). `fused_pallas` routes the per-step state update through the
-    fused multi-tensor kernel (ops/fused_update.py) when the engine runs
-    unsharded on TPU; the math is identical either way."""
+    """Returns client_step(start_vars, benign_mom, task_row, idx[E,S,B],
+    mask[E,S,B], rng, order[E*S], n_chunks) -> SegmentResult, suitable for
+    vmap over (start_vars, benign_mom, task_row, idx, mask, rng) with `order`
+    and `n_chunks` (`active_steps` of the whole segment's mask) unbatched:
+    the loop runs the steps `order[:n_chunks * STEP_CHUNK]`, one `while`
+    whose predicate no lane batches around STEP_CHUNK steps at a time.
+    `fused_pallas` routes the per-step state update through the fused
+    multi-tensor kernel (ops/fused_update.py) when the engine runs unsharded
+    on TPU; the math is identical either way."""
     fused_update = make_fused_step_update(
         hyper.momentum, hyper.weight_decay, fg_enabled,
         use_pallas=fused_pallas, interpret=fused_interpret)
 
     def client_step(start_vars: ModelVars, benign_mom: Any, task: ClientTask,
-                    idx, mask, rng) -> SegmentResult:
+                    idx, mask, rng, order, n_chunks) -> SegmentResult:
         E, S, B = idx.shape
+        idx, mask = idx.reshape(E * S, B), mask.reshape(E * S, B)
         params0, bn0 = start_vars.params, start_vars.batch_stats
         # The benign optimizer lives for the whole round (image_train.py:33 is
         # outside the global-epoch loop), so its momentum chains across
@@ -94,9 +126,12 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
         zeros_e = jnp.zeros((E,), jnp.float32)
         metrics0 = ClientMetrics(zeros_e, zeros_e, zeros_e, zeros_e)
 
-        def step(carry, inp):
-            params, bn, mom, fg, m = carry
-            step_i, bidx, bmask = inp
+        def step(i, carry):
+            params, bn, mom, fg, m, tracked = carry
+            # position i of `order`; the last chunk may reach past its end:
+            # such a position reads the last step with nothing valid in it
+            step_i = order[jnp.minimum(i, E * S - 1)]
+            bidx, bmask = idx[step_i], mask[step_i] & (i < E * S)
             e = step_i // S
             x, y = data.fetch_train(task.slot, bidx)
             x, y, sel = data.stamp(x, y, task.adv_index,
@@ -126,10 +161,11 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
             (loss, (logits, new_bn)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             lr = task.lr_row[e]
-            # Padded steps (mask all-false: epochs beyond this client's count,
-            # or steps beyond its batches) must be no-ops; the fused op does
-            # torch-SGD + the validity selects (+ FoolsGold accumulation)
-            # over the whole state in one logical op.
+            # This lane's padded steps (mask all-false: epochs beyond this
+            # client's count, or steps beyond its batches, at a step another
+            # lane needs) must be no-ops; the fused op does torch-SGD + the
+            # validity selects (+ FoolsGold accumulation) over the whole
+            # state in one logical op.
             valid = jnp.sum(bmask) > 0
             params, mom, fg, bn = fused_update(lr, valid, params, grads,
                                                mom, fg, new_bn, bn)
@@ -147,20 +183,26 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
             if hyper.track_batches:
                 # the reference measures the distance AFTER the step
                 # (image_train.py:238: optimizer.step() precedes it)
-                ys = (vf * loss, vf * tree_dist_norm(params, params0))
-            else:
-                ys = None  # nothing stacked, nothing transferred
-            return (params, bn, mom, fg, m), ys
+                bl, bd = tracked  # a step's one real visit adds to a zero
+                tracked = (
+                    bl.at[step_i].add(vf * loss),
+                    bd.at[step_i].add(vf * tree_dist_norm(params, params0)))
+            return params, bn, mom, fg, m, tracked
 
-        xs = (jnp.arange(E * S), idx.reshape(E * S, B),
-              mask.reshape(E * S, B))
-        carry, ys = jax.lax.scan(step, (params0, bn0, mom0, fg0, metrics0),
-                                 xs)
-        (params, bn, mom, fg, metrics) = carry
-        if hyper.track_batches:
-            batch_loss, batch_dist = ys
-        else:  # zero-width channels: shape-compatible, cost-free
-            batch_loss = batch_dist = jnp.zeros((0,), jnp.float32)
+        def chunk(j, carry):
+            return jax.lax.fori_loop(
+                0, STEP_CHUNK, lambda k, c: step(j * STEP_CHUNK + k, c), carry)
+
+        # [E*S] per-step channels, zero where no step ran; zero-width when
+        # tracking is off: shape-compatible, nothing carried or transferred
+        tracked0 = (jnp.zeros((E * S if hyper.track_batches else 0,),
+                              jnp.float32),) * 2
+        # a dynamic trip count makes the outer loop a `while` (nothing
+        # differentiates through the steps loop: the gradient is inside each
+        # step)
+        params, bn, mom, fg, metrics, (batch_loss, batch_dist) = \
+            jax.lax.fori_loop(0, n_chunks, chunk,
+                              (params0, bn0, mom0, fg0, metrics0, tracked0))
         # a poison segment leaves the benign buffers untouched
         benign_mom_out = _select_tree(is_poison_seg, benign_mom, mom)
 

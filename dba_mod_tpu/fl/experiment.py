@@ -24,7 +24,8 @@ import numpy as np
 from dba_mod_tpu import config as cfg
 from dba_mod_tpu import checkpoint as ckpt
 from dba_mod_tpu.data import (build_batch_plan, build_eval_plan,
-                              load_image_dataset, load_loan_dataset)
+                              load_image_dataset, load_loan_dataset,
+                              plan_step_counts)
 from dba_mod_tpu.data.partition import (equal_split_indices,
                                         poison_test_indices,
                                         sample_dirichlet_indices)
@@ -411,11 +412,13 @@ class Experiment:
         self.last_backdoor_acc: Optional[float] = None
         # Per-round step-count bucketing: the static plan pads every client to
         # the GLOBAL max client size; a round of 10 sampled clients usually
-        # needs far fewer steps, and masked padding steps cost full compute.
-        # dynamic_steps sizes the plan to the round's own max, quantized to
-        # multiples of _STEP_BUCKET so the jitted round compiles a handful of
-        # shapes instead of one-per-round. Identical numerics: dropped steps
-        # were fully-masked no-ops (tests/test_fl_integration.py).
+        # needs far fewer steps. dynamic_steps sizes the plan to the round's
+        # own max, quantized to multiples of _STEP_BUCKET so the jitted round
+        # compiles a handful of shapes instead of one-per-round. Identical
+        # numerics: dropped steps were fully-masked no-ops
+        # (tests/test_fl_integration.py) — which the client step's loop no
+        # longer runs at any plan width (fl/client.py::active_steps), so the
+        # knob buys nothing now and is queued for removal (ROADMAP D5).
         self.dynamic_steps = bool(params.get("dynamic_steps", False))
         self._warmed_buckets: set = set()
         self._apply_resume_aux()
@@ -691,7 +694,7 @@ class Experiment:
         returned handle feeds `finalize_round`, which performs the round's
         single blocking transfer and the CSV/JSONL recording."""
         params = self.params
-        with telemetry.span("round/plan", round=epoch):
+        with telemetry.span("round/plan", round=epoch) as plan_span:
             agent_names, adv_names = select_agents(
                 params, epoch, self.participants, self.benign_names,
                 self.select_rng)
@@ -770,6 +773,7 @@ class Experiment:
                     mask_list = [np.pad(m, ((0, pad),) + ((0, 0),) * 3)
                                  for m in mask_list]
                     num_samples_np = np.pad(num_samples_np, (0, pad))
+            plan_span.count(**plan_step_counts(mask_list))
 
         with telemetry.span("round/stage", round=epoch):
             tasks_seq = jax.tree_util.tree_map(

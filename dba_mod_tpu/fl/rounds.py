@@ -30,7 +30,8 @@ import numpy as np
 from dba_mod_tpu import config as cfg
 from dba_mod_tpu.models import ModelDef, ModelVars
 from dba_mod_tpu.fl import faults as flt
-from dba_mod_tpu.fl.client import ClientMetrics, make_client_step
+from dba_mod_tpu.fl.client import (ClientMetrics, active_steps,
+                                   make_client_step)
 from dba_mod_tpu.fl.device_data import DeviceData
 from dba_mod_tpu.fl.evaluation import EvalResult, make_eval_fn
 from dba_mod_tpu.fl.state import ClientTask, RoundHyper
@@ -402,9 +403,13 @@ class RoundEngine:
                     res = grouped_step(start, benign_mom, tasks_s,
                                        idx_seq[s], mask_seq[s], rngs)
                 else:
-                    res = jax.vmap(client_step)(start, benign_mom, tasks_s,
-                                                idx_seq[s], mask_seq[s],
-                                                rngs)
+                    # the steps loop runs only the steps some lane needs:
+                    # its trip count comes from the mask, inside the program
+                    order, n_chunks = active_steps(mask_seq[s])
+                    res = jax.vmap(
+                        client_step, in_axes=(0,) * 6 + (None, None))(
+                            start, benign_mom, tasks_s, idx_seq[s],
+                            mask_seq[s], rngs, order, n_chunks)
                 start = res.end_vars
                 benign_mom = res.benign_mom
                 if fg_enabled:

@@ -7,8 +7,9 @@ metrics registry, and XLA compile/memory instrumentation for the round path.
   ``profile_dir``, or a benchmark harness — it lies in the same
   ``.xplane.pb`` as the device operations) and appends one
   :class:`SpanRecord` (name, start/end in ``time.time_ns()``, which is the
-  clock the profiler's ``TraceMe`` reads; the enclosing span; the round) to a
-  bounded process-wide list, read with :func:`spans`. A span times HOST work:
+  clock the profiler's ``TraceMe`` reads; the enclosing span; the round; the
+  counts the block gave it with ``.count(...)``) to a bounded process-wide
+  list, read with :func:`spans`. A span times HOST work:
   it never syncs the device, so the program that is traced is the program
   that is timed. Device time per phase comes from the ``jax.named_scope``
   names inside the round program (``phase/train``, ``phase/aggregate``,
@@ -78,6 +79,7 @@ class SpanRecord(NamedTuple):
     parent: Optional[str]   # the enclosing span on this thread
     round: Optional[int]    # `round=` of this span, else the enclosing one's
     tid: int
+    counts: Optional[Dict[str, int]] = None  # what `_Span.count` was given
 
 
 MAX_SPAN_RECORDS = 200_000  # about 20 spans a round; later ones are dropped
@@ -94,11 +96,19 @@ def _stack() -> list:
 
 
 class _Span:
-    __slots__ = ("name", "ids", "_annotation", "_parent", "_round", "_t0")
+    __slots__ = ("name", "ids", "counts", "_annotation", "_parent", "_round",
+                 "_t0")
 
     def __init__(self, name: str, ids: Dict[str, Any]):
         self.name = name
         self.ids = ids
+        self.counts = None
+
+    def count(self, **counts: int) -> None:
+        """Exact counts of the work inside this span (``round/plan``: the
+        plan's steps), recorded where the work happens: they go onto the
+        span's record."""
+        self.counts = {**(self.counts or {}), **counts}
 
     def __enter__(self):
         stack = _stack()
@@ -116,7 +126,7 @@ class _Span:
         self._annotation.__exit__(*exc)
         _stack().pop()
         record = SpanRecord(self.name, self._t0, end, self._parent,
-                            self._round, threading.get_ident())
+                            self._round, threading.get_ident(), self.counts)
         kept = len(_records) < MAX_SPAN_RECORDS
         if kept:
             _records.append(record)
@@ -438,7 +448,8 @@ class Telemetry(_SpanAccess):
                    "ts": (r.start_ns - self._origin_ns) / 1e3,
                    "dur": (r.end_ns - r.start_ns) / 1e3,
                    "pid": pid, "tid": r.tid,
-                   "args": {"round": r.round, "parent": r.parent}}
+                   "args": {"round": r.round, "parent": r.parent,
+                            **(r.counts or {})}}
                   for r in self.own_spans()]
         meta = [{"name": "process_name", "ph": "M", "pid": pid,
                  "args": {"name": "dba_mod_tpu"}}]

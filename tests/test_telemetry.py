@@ -483,3 +483,60 @@ def test_phases_selfcheck_reduces_the_recorded_trace():
     spans; and every reader of them on a synthetic and an empty context."""
     from chipbench import selfcheck_phases
     assert selfcheck_phases.main() == 0
+
+
+# ----------------------------------- the plan's step counts and their readers
+def test_span_counts_go_onto_the_record_and_the_chrome_trace(enabled_tel,
+                                                             tmp_path):
+    with tel.span("round/plan", round=3) as sp:
+        sp.count(steps_plan=8, steps_run=6)
+        sp.count(lane_steps_real=7)
+    with tel.span("round/stage", round=3):
+        pass
+    plan, stage = enabled_tel.own_spans()
+    assert plan.counts == {"steps_plan": 8, "steps_run": 6,
+                           "lane_steps_real": 7}
+    assert stage.counts is None
+    enabled_tel.write_trace()
+    events = {e["name"]: e for e in json.loads(
+        (tmp_path / "trace.json").read_text())["traceEvents"]
+        if e["ph"] == "X"}
+    assert events["round/plan"]["args"]["steps_run"] == 6
+    assert "steps_run" not in events["round/stage"]["args"]
+
+
+@pytest.mark.parametrize("reader", ["train_steps_run_pct",
+                                    "train_lane_fill_pct"])
+@pytest.mark.parametrize("records", ["counted", "bare", "none"])
+def test_step_count_readers(reader, records):
+    """chipbench/metrics/train_*_pct.py on the program's own span records: a
+    window of three rounds after one of set-up; nothing (not zero) from a
+    program that does not count."""
+    from chipbench import selfcheck_steps as sc
+    _, mod = sc.readers()[reader]  # found by name, as the harness finds it
+    made = sc.synthetic_records()
+    if records == "counted":
+        want = {"train_steps_run_pct": 20.0, "train_lane_fill_pct": 35.0}
+        assert mod.read(sc.context(made, 3)) == pytest.approx(want[reader])
+        # the real record type, through the program's own store
+        n0 = len(tel.spans())
+        for r in made:
+            with tel.span(r.name, round=r.round) as sp:
+                if r.counts:
+                    sp.count(**r.counts)
+        ctx = sc.context(tel.spans(n0), 3)
+        assert mod.read(ctx) == pytest.approx(want[reader])
+    elif records == "bare":
+        bare = [sc.BareSpan(*r[:5]) for r in made]
+        assert mod.read(sc.context(bare, 3)) is None
+    else:
+        assert mod.read(sc.context(None, 0)) is None
+        assert mod.read(sc.context([], 3)) is None
+
+
+def test_steps_selfcheck_reads_the_recorded_sample():
+    """chipbench/testdata/steps_sample.json (the `round/plan` records of a run
+    of `tiny_dba_attack` on a TPU v5e): both readers against counts made by
+    hand, and BENCHMARK.json's entries for them."""
+    from chipbench import selfcheck_steps
+    assert selfcheck_steps.main() == 0
