@@ -49,7 +49,10 @@ program's spans are host-plane events.
 - the traced span is `chipbench/trace.py`'s: first to last `chipbench/*`
   annotation;
 - an idle gap of device 0 is attributed to the leaf program span that covers
-  its midpoint, else to `no_span`.
+  its midpoint, else to `no_span`;
+- `by_round` (in the printed line, read by no metric): the same reduction
+  over each traced round's own three annotations, so that a clean and a
+  poisoned round of one trace can be told apart.
 
 `python -m chipbench.phases <dir-or-file>` prints the reduction and what the
 trace holds; `--record-sample <dir>` records a small trace on the device that
@@ -293,6 +296,22 @@ def reduce_events(ops: list, harness: list, spans: list) -> Dict[str, Any]:
                                     / idle_s if idle_s and spans else None)}
 
 
+def reduce_by_round(t: Dict[str, Any]) -> list:
+    """One reduction for each traced round (the harness opens a round with
+    `chipbench/dispatch` and waits for the device before it closes it, so a
+    round's operations lie inside its three annotations): what sets a clean
+    round apart from a poisoned one in the same trace."""
+    starts = [i for i, a in enumerate(t["harness"])
+              if a[0] == trace.ANNOTATION_PREFIX + "dispatch"]
+    keep = ("window_s", "busy_s", "scope_s", "kernel_s", "unattributed_s",
+            "idle_by_program_span")
+    out = []
+    for i, j in zip(starts, starts[1:] + [len(t["harness"])]):
+        r = reduce_events(t["ops"], t["harness"][i:j], t["spans"])
+        out.append({k: r[k] for k in keep})
+    return out
+
+
 @functools.lru_cache(maxsize=1)
 def _reduce_run() -> Optional[Dict[str, Any]]:
     path = find_run_xplane()
@@ -303,6 +322,7 @@ def _reduce_run() -> Optional[Dict[str, Any]]:
         return None
     reduced = reduce_events(t["ops"], t["harness"], t["spans"])
     print(json.dumps({"phase": "program_spans", **reduced,
+                      "by_round": reduce_by_round(t),
                       "round_program_compile": compile_stages()}), flush=True)
     return reduced
 

@@ -5,7 +5,9 @@ A PR that renames one of these has to keep the benchmark running:
 - `dba_mod_tpu.config.Params.from_dict`
 - `dba_mod_tpu.fl.experiment.Experiment(params, save_results=True)` and its
   attributes `select_rng`, `plan_rng`, `rng_key`, `global_vars`, `fg_state`
-  (assigned from `--seed` after the build), `engine`, `folder`,
+  (assigned from `--seed` after the build; `select_rng` again from the
+  population's seed at the start of every period of the window), `engine`,
+  `folder`,
   `image_data`, `steps_per_epoch`, `epochs_max`, `_use_donated_round`,
   `last_global_loss`
 - `Experiment.run_round`, `dispatch_round`, `finalize_round`, `save_model`
@@ -13,7 +15,7 @@ A PR that renames one of these has to keep the benchmark running:
   feed of the output check, at the window's own shape)
 - `engine.round_fn_donated` / `engine.round_fn` (the compiled round program the
   window drives), `engine.fused_pallas`, `engine.fused_interpret`
-- `RoundInFlight.payload`, `.mask_list`, `.agent_names`
+- `RoundInFlight.payload`, and the key `agents` of a finished round's result
 - `dba_mod_tpu.models.ModelVars`, and the flax auto-names of the ResNet tree
   (`Conv_0`, `BatchNorm_0`, `BasicBlock_<i>`, `Dense_0`)
 - `dba_mod_tpu.utils.compile_cache.enable_compile_cache`
@@ -45,16 +47,22 @@ def make_params(config: Dict[str, Any], traffic: Dict[str, Any], out_dir: Path,
                 first_window_epoch: int, cut: Dict[str, Any] | None = None,
                 overrides: Dict[str, Any] | None = None):
     """The configuration's parameters with the traffic's schedule laid over
-    them: which rounds of the window are poisoned, and by whom. Returns the
-    program's Params and the plain dict they were made from (the reference
-    reads the dict, never the program's object)."""
+    them: which rounds of a period are poisoned, and by whom, repeated over
+    `periods_max` periods of `period_rounds` rounds from the window's first
+    epoch on (adversary i poisons the i-th of `poison_window_rounds` in every
+    period). Returns the program's Params and the plain dict they were made
+    from (the reference reads the dict, never the program's object)."""
     from dba_mod_tpu.config import Params
     raw = dict(config["params"])
     raw["is_poison"] = bool(traffic["is_poison"])
     rounds = list(traffic.get("poison_window_rounds", []))
+    period, periods = int(traffic["period_rounds"]), int(traffic["periods_max"])
+    if any(not 1 <= r <= period for r in rounds):
+        raise SystemExit("chipbench: a poisoned round outside the traffic's period")
     for i in range(int(raw["trigger_num"])):
         raw[f"{i}_poison_epochs"] = (
-            [first_window_epoch - 1 + rounds[i]] if i < len(rounds) else [])
+            [first_window_epoch - 1 + p * period + rounds[i]
+             for p in range(periods)] if i < len(rounds) else [])
     raw["num_devices"] = int(traffic.get("num_devices", 0))
     raw["epochs"] = 10 ** 6
     raw["run_dir"] = str(out_dir / "runs")
@@ -139,12 +147,20 @@ def from_program(model_vars, names) -> Dict[str, np.ndarray]:
     return out
 
 
+def seed_selection(exp, seed: int) -> None:
+    """Which clients the coming rounds select, and in which order."""
+    exp.select_rng = random.Random(int(seed))
+
+
 def seed_state(exp, seed: int, state: Dict[str, Any]) -> None:
-    """`--seed` drives the traffic on top of the fixed population: initial
-    weights, client selection, batch order, device RNG."""
+    """`--seed` drives what cannot move a round's time, on top of the fixed
+    population: initial weights, batch order, device RNG; and the client
+    selection of set-up's rounds (the two check rounds, the warm round). The
+    window's selection is not `--seed`'s: `run.run_window` sets it from the
+    population's seed at the start of every period (`seed_selection`)."""
     shapes = tree_shapes(exp.global_vars)
     exp.global_vars = None  # free the old state first: no second copy at the peak
-    exp.select_rng = random.Random(int(seed))
+    seed_selection(exp, seed)
     exp.plan_rng = np.random.RandomState(int(seed) % (2 ** 32))
     exp.rng_key = jax.random.key(int(seed) % (2 ** 31 - 1))
     exp.global_vars = to_program(shapes, state)
@@ -187,9 +203,11 @@ def engine_report(exp, on_tpu: bool) -> Dict[str, Any]:
 def check_round(exp, epoch: int, real_steps: int) -> Dict[str, Any]:
     """One call of the window's own compiled round program, at the window's
     own shapes, on a feed in which every client takes only its first
-    `real_steps` steps (the rest of the static plan is masked, which the
-    program treats as no-ops at full cost). Returns the feed and what the
-    program produced, on the host."""
+    `real_steps` steps: the rest of the static plan is masked, and the client
+    step's loop runs only the steps some lane needs (in chunks of a few), so
+    a check round runs one chunk. The clients are those `exp.select_rng`
+    draws: `--seed`'s. Returns the feed and what the program produced, on the
+    host."""
     tasks_seq, idx_seq, mask_seq, ns, lane = exp.build_static_round_inputs(epoch)
     mask = np.array(mask_seq)
     mask[:, :, 1:] = False
@@ -217,17 +235,6 @@ def check_round(exp, epoch: int, real_steps: int) -> Dict[str, Any]:
             "delta_norms": np.asarray(delta_norms),                   # [C]
             "global_loss": float(globals_.clean.loss),
             "global_acc": float(globals_.clean.acc)}
-
-
-def useful_steps(fl) -> tuple:
-    """(real, executed) client-steps of a dispatched round, from its plan's
-    mask [C,E,S,B]: a step is real where any row of its batch is."""
-    real = executed = 0
-    for mask in fl.mask_list:
-        m = np.asarray(mask)
-        real += int(m.any(axis=-1).sum())
-        executed += int(np.prod(m.shape[:-1]))
-    return real, executed
 
 
 def recorded_rows(exp) -> list:

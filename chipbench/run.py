@@ -3,11 +3,19 @@
     python -m chipbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Fails at once without a TPU or with fewer devices than the cell's `chips`;
-builds the configuration's `Experiment`; seeds the traffic; compiles and warms
-the cell's one round program (the two check rounds and one whole round);
-measures the program's own sequential loop for `--seconds`; compares the check
-rounds with the plain reference; prints findings as JSON lines and, last, the
-result object of the benchmark's contract.
+builds the configuration's `Experiment`; seeds the weights, the batch order
+and the check rounds' selection from `--seed`; compiles and warms the cell's
+one round program (the two check rounds and one whole round); then measures
+the program's own sequential loop over **whole periods of the traffic's
+schedule** (`period_rounds` in the traffic file): periods are started until
+`--seconds` have passed and the one in flight is finished, and at the start of
+every period the selection RNG is set from the configuration's
+`population_seed`, so every period of every run at every seed selects the same
+clients and times the same job. With `--trace 1` the window rounds the traffic
+names (`trace_window_rounds`) are traced and the window ends with the last of
+them. After the window the check rounds are compared with the plain reference;
+findings go out as JSON lines and, last, the result object of the benchmark's
+contract.
 
 `--rehearse` walks the same control flow on whatever backend is there at tiny
 sizes (Pallas interpreted): it prints no metric and is never `correct`.
@@ -171,6 +179,92 @@ def end_to_end(rounds_s, failed, no_models, window_s, peak_bytes, setup_s):
             "setup_s": (setup_s, "s")}
 
 
+def trace_span_of(traffic) -> tuple:
+    """(first, last) window round of the traced span: the profiler runs from
+    the start of the first round the traffic names to the end of the last."""
+    rounds = [int(r) for r in traffic["trace_window_rounds"]]
+    if not rounds or min(rounds) < 1 or max(rounds) > int(traffic["period_rounds"]):
+        raise SystemExit("chipbench: the traffic's trace_window_rounds are not "
+                         "rounds of its period")
+    return min(rounds), max(rounds)
+
+
+def run_window(exp, seconds, first_epoch, period, periods_max, selection_seed,
+               trace_span=None, trace_dir=None, spans=None):
+    """The window: the program's own sequential loop over whole periods of the
+    traffic's schedule. A period is started while `seconds` have not passed
+    (and fewer than `periods_max`, the periods the schedule was laid over, have
+    run) and is always finished. At the start of every period the selection is
+    seeded from `selection_seed`, the population's: every period of every run
+    selects the same clients in the same order.
+
+    `trace_span` (first, last window round) makes it a traced window: every
+    round is split into dispatch / device wait / finalize under the harness's
+    clocks (`spans`) and annotations, the profiler runs over the rounds of the
+    span, and the window ends with the last of them (writing the profile takes
+    longer than the window; a traced run prints no end-to-end metric)."""
+    import jax
+    from chipbench import program
+    rounds_s, results, failed, traced = [], [], 0, None
+    t_window = time.perf_counter()
+    periods = 0
+    while (traced is None and periods < periods_max
+           and time.perf_counter() - t_window < seconds):
+        program.seed_selection(exp, selection_seed)
+        for r in range(1, period + 1):
+            epoch = first_epoch + periods * period + r - 1
+            if trace_span and periods == 0 and r == trace_span[0]:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0  # annotations only: no hook
+                jax.profiler.start_trace(       # on the planner's Python calls
+                    str(trace_dir), profiler_options=options)
+                t_trace = time.perf_counter()
+            t0 = time.perf_counter()
+            try:
+                if trace_span:
+                    with jax.profiler.TraceAnnotation("chipbench/dispatch"):
+                        fl = exp.dispatch_round(epoch)
+                    t1 = time.perf_counter()
+                    with jax.profiler.TraceAnnotation("chipbench/device_wait"):
+                        jax.block_until_ready((fl.payload, exp.global_vars))
+                    t2 = time.perf_counter()
+                    with jax.profiler.TraceAnnotation("chipbench/finalize"):
+                        res = exp.finalize_round(fl)
+                    t3 = time.perf_counter()
+                    spans["dispatch"].append(t1 - t0)
+                    spans["device_wait"].append(t2 - t1)
+                    spans["finalize"].append(t3 - t2)
+                else:
+                    res = exp.run_round(epoch)
+                exp.save_model(epoch)
+                res["global_loss"] = exp.last_global_loss
+                if not math.isfinite(res["global_loss"]):
+                    failed += 1
+                results.append(res)
+            except Exception as e:  # a round that raised is a failed round
+                emit(phase="round_failed", epoch=epoch, error=repr(e))
+                failed += 1
+            rounds_s.append(time.perf_counter() - t0)
+            if trace_span and periods == 0 and r == trace_span[1]:
+                jax.block_until_ready(exp.global_vars)
+                window_rounds = list(range(trace_span[0], trace_span[1] + 1))
+                traced = {"window_s": time.perf_counter() - t_trace,
+                          "rounds": len(window_rounds),
+                          "window_rounds": window_rounds}
+                jax.profiler.stop_trace()
+                break
+        periods += 1
+    jax.block_until_ready(exp.global_vars)
+    return {"rounds_s": rounds_s, "results": results, "failed": failed,
+            "window_s": time.perf_counter() - t_window, "periods": periods,
+            "traced": traced}
+
+
+def selection_repeats(agents, period) -> bool:
+    """Every period of the window selected what its first period did."""
+    return all(names == agents[i % period] for i, names in enumerate(agents))
+
+
 def run_cell(args, sabotage=None) -> dict:
     """One run. `sabotage(exp)` is for chipbench/tests only: it breaks the
     timed path underneath the harness. Returns the result object."""
@@ -189,7 +283,7 @@ def run_cell(args, sabotage=None) -> dict:
                          f"JAX sees {len(devices)}")
     devices = devices[:cell["chips"]]
 
-    from chipbench import check, program, trace as trace_mod
+    from chipbench import check, program, steps, trace as trace_mod
     events = CompileEvents()
     cache_dir = program.enable_cache()
     out_dir = HERE / "_out" / f"{args.workload}.{args.seed}.{args.trace}"
@@ -203,6 +297,7 @@ def run_cell(args, sabotage=None) -> dict:
         rehearsal = json.loads((HERE / "rehearsal.json").read_text())
         cut = {**rehearsal["cut"],
                **rehearsal["by_type"].get(config["params"]["type"], {})}
+        traffic = {**traffic, **rehearsal["by_traffic"].get(cell["traffic"], {})}
     first_window_epoch = FIRST_WINDOW_EPOCH
     overrides = dict(getattr(args, "overrides", None) or {})
     if getattr(args, "override", None):
@@ -239,63 +334,16 @@ def run_cell(args, sabotage=None) -> dict:
     compiles_before = events.total()
     setup_s = time.perf_counter() - T_PROCESS
 
-    # ---- the window: the program's own sequential loop
-    rounds_s, results, failed = [], [], 0
+    # ---- the window: the program's own sequential loop, in whole periods
+    period = int(traffic["period_rounds"])
     spans.update(dispatch=[], device_wait=[], finalize=[])
-    counters = {"real_client_steps": 0, "executed_client_steps": 0}
-    traced = None
-    trace_rounds = int(traffic.get("trace_rounds", 2)) if args.trace else 0
     trace_dir = out_dir / "trace"
-    t_window = time.perf_counter()
-    epoch = first_window_epoch
-    while time.perf_counter() - t_window < args.seconds:
-        if args.trace and len(rounds_s) == 0:
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0  # annotations only: no hook on
-            jax.profiler.start_trace(       # every Python call of the planner
-                str(trace_dir), profiler_options=options)
-            t_trace = time.perf_counter()
-        t0 = time.perf_counter()
-        try:
-            if args.trace:
-                with jax.profiler.TraceAnnotation("chipbench/dispatch"):
-                    fl = exp.dispatch_round(epoch)
-                t1 = time.perf_counter()
-                with jax.profiler.TraceAnnotation("chipbench/device_wait"):
-                    jax.block_until_ready((fl.payload, exp.global_vars))
-                t2 = time.perf_counter()
-                with jax.profiler.TraceAnnotation("chipbench/finalize"):
-                    r = exp.finalize_round(fl)
-                t3 = time.perf_counter()
-                spans["dispatch"].append(t1 - t0)
-                spans["device_wait"].append(t2 - t1)
-                spans["finalize"].append(t3 - t2)
-                real, executed = program.useful_steps(fl)
-                counters["real_client_steps"] += real
-                counters["executed_client_steps"] += executed
-            else:
-                r = exp.run_round(epoch)
-            exp.save_model(epoch)
-            r["global_loss"] = exp.last_global_loss
-            if not math.isfinite(r["global_loss"]):
-                failed += 1
-            results.append(r)
-        except Exception as e:  # a round that raised is a failed round
-            emit(phase="round_failed", epoch=epoch, error=repr(e))
-            failed += 1
-        rounds_s.append(time.perf_counter() - t0)
-        if args.trace and len(rounds_s) == trace_rounds:
-            jax.block_until_ready(exp.global_vars)
-            traced = {"window_s": time.perf_counter() - t_trace,
-                      "rounds": trace_rounds}
-            jax.profiler.stop_trace()
-        epoch += 1
-    jax.block_until_ready(exp.global_vars)
-    window_s = time.perf_counter() - t_window
-    if args.trace and traced is None:  # the window was shorter than the trace
-        traced = {"window_s": time.perf_counter() - t_trace,
-                  "rounds": len(rounds_s)}
-        jax.profiler.stop_trace()
+    won = run_window(exp, args.seconds, first_window_epoch, period,
+                     int(traffic["periods_max"]), int(config["population_seed"]),
+                     trace_span_of(traffic) if args.trace else None,
+                     trace_dir, spans)
+    rounds_s, results, failed = won["rounds_s"], won["results"], won["failed"]
+    window_s, traced = won["window_s"], won["traced"]
     compiles_in_window = events.total() - compiles_before
     memory = device_memory(devices)
     attempted = len(rounds_s)
@@ -304,10 +352,12 @@ def run_cell(args, sabotage=None) -> dict:
     rows = program.recorded_rows(exp)
     window_rows = [r for r in rows if r["epoch"] >= first_window_epoch]
     engine = program.engine_report(exp, dev.platform == "tpu")
+    agents = [[str(a) for a in r["agents"]] for r in results]
     emit(phase="window", window_s=window_s, rounds_s=rounds_s,
          global_acc=[r["global_acc"] for r in results],
          global_loss=[r["global_loss"] for r in results],
          backdoor_acc=[r["backdoor_acc"] for r in results],
+         periods=won["periods"], period_rounds=period, agents=agents,
          recorded_rows=len(window_rows), compiles_in_window=compiles_in_window,
          compile=events.snapshot(), engine=engine, memory=memory)
 
@@ -327,6 +377,10 @@ def run_cell(args, sabotage=None) -> dict:
         and [r["epoch"] for r in window_rows]
         == list(range(first_window_epoch, first_window_epoch + len(window_rows))),
         "no_failed_round": failed == 0 and attempted > 0,
+        # an untraced window is whole periods of one selection
+        "whole_periods": bool(args.trace) or (
+            attempted == won["periods"] * period
+            and (failed > 0 or selection_repeats(agents, period))),
         "engine": engine["ok"],
         "agrees_with_reference": check_ok,
     }
@@ -351,8 +405,8 @@ def run_cell(args, sabotage=None) -> dict:
         result["metrics"] = {k: {"value": v, "unit": u}
                              for k, (v, u) in numbers.items() if k in names}
     else:
-        counters.update(compile_cache_hits=events.hits,
-                        compile_cache_misses=events.misses)
+        counters = {"compile_cache_hits": events.hits,
+                    "compile_cache_misses": events.misses}
         reduced = trace_mod.reduce(trace_mod.find_xplane(trace_dir),
                                    cell["chips"])
         ctx = {"spans": spans, "counters": counters, "trace": reduced,
@@ -367,7 +421,16 @@ def run_cell(args, sabotage=None) -> dict:
                                "idle_gaps": reduced["idle_gaps"][:10]}
         emit(phase="spans", medians={k: statistics.median(v)
                                      for k, v in spans.items() if v},
-             counters=counters, traced=traced)
+             counters=counters, traced=traced,
+             plan_counts=steps.window_counts(ctx))
+    # every number compared beside its limit: the result's last key
+    result["compared"] = {
+        **{row["number"]: {"value": row["value"], "limit": row["limit"]}
+           for row in compared},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0},
+        "failed_rounds": {"value": failed, "limit": 0},
+        "rows_not_recorded": {"value": attempted - failed - len(window_rows),
+                              "limit": 0}}
     shutil.rmtree(out_dir, ignore_errors=True)
     return result
 
@@ -386,6 +449,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     result = run_cell(args)
     print(json.dumps(result), flush=True)
+    for name, row in result["compared"].items():
+        print(f"chipbench: {name} {row['value']!r} limit {row['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     if args.rehearse:
         return 3
     return 0

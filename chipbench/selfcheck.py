@@ -6,8 +6,10 @@
 - the reduction on `testdata/sample.xplane.pb`, a small trace recorded on a
   TPU v5e by `python -m chipbench.trace --record-sample` (three annotated
   steps of two 1024x1024 matmuls each);
-- every per-layer reader on a synthetic context, and the end-to-end
-  arithmetic on a synthetic list of round times.
+- the per-layer readers of the harness's own clocks, counters and trace
+  reduction on a synthetic context (`selfcheck_phases` and `selfcheck_steps`
+  check the readers of the program's spans, scopes and counts), and the
+  end-to-end arithmetic on a synthetic list of round times.
 Exits non-zero on the first disagreement.
 """
 from __future__ import annotations
@@ -76,17 +78,19 @@ def main() -> int:
     ctx = {"spans": {"build": [20.0], "first_round": [100.0],
                      "steady_round": [4.0, 5.0], "dispatch": [0.010, 0.030, 0.020],
                      "finalize": [0.004, 0.002, 0.003]},
-           "counters": {"compile_cache_hits": 3, "real_client_steps": 30,
-                        "executed_client_steps": 120},
+           "counters": {"compile_cache_hits": 3},
            "trace": r, "traced": {"rounds": 2, "window_s": 10.0}}
     bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     want = {"build_s": 20.0, "compile_s": 96.0, "compile_cache_hits": 3,
-            "dispatch_ms": 20.0, "finalize_ms": 3.0, "useful_step_share": 25.0,
+            "dispatch_ms": 20.0, "finalize_ms": 3.0,
             "device_idle_pct": 40.0, "round_device_ms": 3000.0}
-    for m, mod in harness.load_readers(bench, bench["workloads"][0]["name"]):
+    readers = [(m, mod) for m, mod in harness.load_readers(
+        bench, bench["workloads"][0]["name"]) if m["name"] in want]
+    check({m["name"] for m, _ in readers} == set(want),
+          "BENCHMARK.json lists every reader this self-check expects")
+    for m, mod in readers:
         value = mod.read(ctx)
-        check(m["name"] in want and close(value, want[m["name"]]),
-              f"reader {m['name']} = {value}")
+        check(close(value, want[m["name"]]), f"reader {m['name']} = {value}")
         check((mod.LAYER, mod.UNIT, mod.MOVES) == (m["layer"], m["unit"], m["moves"]),
               f"reader {m['name']} states the layer, unit and moves of BENCHMARK.json")
         empty = mod.read({"spans": {}, "counters": {}, "trace": None, "traced": None})

@@ -138,6 +138,17 @@ def main() -> int:
           f"recorded trace: idle and busy fill the window; "
           f"{sample['idle_attributed_pct']:.1f} % of the idle time attributed")
 
+    by_round = phases.reduce_by_round(t)
+    # (rounds of a millisecond: the device's clock lies a round off the
+    # host's there, so only the sums are held)
+    check(len(by_round) == 3
+          and all(b["busy_s"] <= b["window_s"] for b in by_round)
+          and close(sum(b["scope_s"].get("phase/train", 0.0) for b in by_round),
+                    sample["scope_s"]["phase/train"], 1e-6)
+          and close(sum(b["busy_s"] for b in by_round), sample["busy_s"], 1e-6),
+          "recorded trace: a reduction for each of the three rounds; their "
+          "busy and train seconds add up to the trace's")
+
     ctx = synthetic_context(r)
     empty = {"spans": {}, "counters": {}, "trace": None, "traced": None,
              "program_spans": None, "compile_stages": {}, "phases": None}

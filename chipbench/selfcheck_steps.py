@@ -3,7 +3,9 @@
     python -m chipbench.selfcheck_steps
 
 - both readers on a synthetic context with a known answer: four rounds
-  recorded, three in the window, one of them with no real step;
+  recorded, three in the window, one of them with no real step; the shares
+  are sums of parts over sums of wholes (a median of per-round shares would
+  read 10 and 61 there, not 20 and 35);
 - both on `testdata/steps_sample.json`: the `round/plan` records of one run
   of `tiny_dba_attack` on a TPU v5e (set-up's two check rounds and warm round,
   then the window), against counts made by hand from the same records;
@@ -14,7 +16,6 @@ Exits non-zero on the first disagreement.
 from __future__ import annotations
 
 import json
-import statistics
 import sys
 from collections import namedtuple
 from pathlib import Path
@@ -41,8 +42,9 @@ def synthetic_records():
         "steps_plan": plan, "steps_run": run, "lane_steps_real": real,
         "lanes": lanes}
     plans = [count(370, 1, 10, 10),      # a check round of set-up: not read
-             count(370, 74, 370, 10), count(370, 296, 592, 10),
-             count(370, 0, 0, 10)]       # no real step: no lane fill
+             count(370, 37, 370, 10),    # 10 % of the plan, every lane full
+             count(370, 185, 407, 10),   # 50 %, 22 % full: one lane's tail
+             count(370, 0, 0, 10)]       # no real step: it adds to the plan only
     records = []
     for rnd, c in enumerate(plans, 1):
         records += [Span("round/plan", rnd * 100, rnd * 100 + 7, None, rnd, c),
@@ -62,8 +64,8 @@ def readers():
 def main() -> int:
     found = readers()
     ctx = context(synthetic_records(), 3)
-    want = {"train_steps_run_pct": 100 * 74 / 370,       # of 20, 80, 0
-            "train_lane_fill_pct": (50.0 + 20.0) / 2}    # of 50, 20, none
+    want = {"train_steps_run_pct": 100 * (37 + 185 + 0) / (3 * 370),   # 20
+            "train_lane_fill_pct": 100 * (370 + 407) / (370 + 1850)}   # 35
     empty = context(None, 0)
     bare = context([BareSpan(*r[:5]) for r in synthetic_records()], 3)
     for name, (m, mod) in found.items():
@@ -91,11 +93,10 @@ def main() -> int:
         <= c["steps_run"] * c["lanes"] for c in window),
         f"recorded sample: {n} window rounds, every count within its bounds")
     by_hand = {
-        "train_steps_run_pct": statistics.median(
-            100 * c["steps_run"] / c["steps_plan"] for c in window),
-        "train_lane_fill_pct": statistics.median(
-            100 * c["lane_steps_real"] / (c["steps_run"] * c["lanes"])
-            for c in window)}
+        "train_steps_run_pct": 100 * sum(c["steps_run"] for c in window)
+        / sum(c["steps_plan"] for c in window),
+        "train_lane_fill_pct": 100 * sum(c["lane_steps_real"] for c in window)
+        / sum(c["steps_run"] * c["lanes"] for c in window)}
     for name, (_, mod) in found.items():
         value = mod.read(context(records, n))
         check(close(value, by_hand[name])

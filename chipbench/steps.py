@@ -21,7 +21,6 @@ file) gives `None` for every number here, never 0 and never an exception.
 """
 from __future__ import annotations
 
-import statistics
 from typing import Callable, List, Optional
 
 from chipbench import phases
@@ -42,10 +41,12 @@ def window_counts(ctx) -> Optional[List[dict]]:
     return counts if all(counts) else None
 
 
-def window_median_pct(ctx, part: Callable[[dict], int],
-                      whole: Callable[[dict], int]) -> Optional[float]:
-    """Median over the window's rounds of 100 x part / whole, each from one
-    round's counts; a round whose whole is 0 has no share."""
-    shares = [100.0 * part(c) / whole(c)
-              for c in window_counts(ctx) or () if whole(c)]
-    return statistics.median(shares) if shares else None
+def window_total_pct(ctx, part: Callable[[dict], int],
+                     whole: Callable[[dict], int]) -> Optional[float]:
+    """100 x the sum of the parts over the sum of the wholes, over the
+    window's rounds: a round weighs by its steps, so a poisoned round of 280
+    steps counts for more than a clean one of 64. Nothing where no round has
+    a whole."""
+    counts = window_counts(ctx) or ()
+    total = sum(whole(c) for c in counts)
+    return 100.0 * sum(part(c) for c in counts) / total if total else None
