@@ -330,7 +330,7 @@ def measure_phases(exp) -> dict:
             return res.wv[0]
         prev = jax.tree_util.tree_map(jnp.zeros_like, train.deltas)
         lev = exp.engine.local_evals_fn(exp.global_vars, train.deltas,
-                                        tasks_last, prev)
+                                        tasks_seq, prev)
         if k == 2:
             return lev.clean.acc[0]
         gev = exp.engine.global_evals_fn(res.new_vars)

@@ -77,7 +77,7 @@ def main():
 
     def run_new():
         jax.device_get(engine.local_evals_fn(
-            exp.global_vars, train.deltas, tasks_last, prev).clean.acc[0])
+            exp.global_vars, train.deltas, tasks_seq, prev).clean.acc[0])
 
     run_new()
     out["local_eval_old_clean_only_s"] = round(
@@ -86,15 +86,14 @@ def main():
         min(timeit(run_new) for _ in range(3)) - lat, 4)
     # clean-only via the stacked kernel, for apples-to-apples
     from dba_mod_tpu.fl.evaluation import make_stacked_eval_fn
-    stacked_clean = make_stacked_eval_fn(engine.model_def, engine.data,
-                                         poison=False)
+    stacked_clean = make_stacked_eval_fn(engine.model_def, engine.data)
 
     def new_clean_only(global_vars, deltas, tasks):
         unscaled = jax.tree_util.tree_map(
             lambda g, d: g + d / tasks.scale.reshape(
                 (-1,) + (1,) * (d.ndim - 1)), global_vars, deltas)
         return stacked_clean(unscaled, plans.clean_idx, plans.clean_slots,
-                             plans.clean_mask, jnp.int32(-1))
+                             plans.clean_mask)
 
     new_clean_fn = jax.jit(new_clean_only)
     jax.device_get(new_clean_fn(exp.global_vars, train.deltas,

@@ -666,11 +666,9 @@ class AsyncDriver:
             nbt = nbt_client_deltas(mask_seq, tasks_seq.scale)
             locals_dev = None
             if exp.local_eval:
-                tasks_last = jax.tree_util.tree_map(lambda l: l[0],
-                                                    tasks_seq)
                 prev = jax.tree_util.tree_map(jnp.zeros_like, train.deltas)
                 locals_dev = exp.engine.local_evals_fn(
-                    exp.global_vars, train.deltas, tasks_last, prev)
+                    exp.global_vars, train.deltas, tasks_seq, prev)
             deltas = train.deltas
             dropped = np.zeros(len(agent_names), bool)
             delay_mult = np.ones(len(agent_names))

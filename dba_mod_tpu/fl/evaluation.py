@@ -14,16 +14,18 @@ images dropped (image_helper.py:148-172), expressed in the eval plan's index
 set; the LOAN branches iterate every state shard (test.py:13-24) — here the
 plan concatenates all shards with a per-row slot array.
 
-Local (per-client) evals vmap the same kernel over stacked client models —
-ten models' test passes in one XLA computation instead of the reference's
-sequential loop.
+Local (per-client) clean evals vmap the kernel's forward pass over stacked
+client models — ten models' test passes in one XLA computation instead of the
+reference's sequential loop; the poisoned local evals run the kernel on one
+client model per recorded row (`local_battery_jobs`).
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dba_mod_tpu.models import ModelDef, ModelVars
 from dba_mod_tpu.fl.device_data import DeviceData
@@ -113,49 +115,36 @@ def make_eval_fn(model_def: ModelDef, data: DeviceData, poison: bool):
     return evaluate
 
 
-def make_stacked_eval_fn(model_def: ModelDef, data: DeviceData, poison: bool,
-                         per_client_trigger: bool = False):
+def make_stacked_eval_fn(model_def: ModelDef, data: DeviceData):
     """evaluate_stacked(stacked_vars [C, ...], idx[S,B], slots[S,B],
-    mask[S,B], adv) -> EvalResult with [C] leaves.
+    mask[S,B]) -> EvalResult with [C] leaves: the clean test of C client
+    models over ONE shared eval plan.
 
-    The per-client local battery evaluates C client models over ONE shared
-    eval plan — fetching and trigger-stamping each test batch inside a
-    per-client vmap (the naive formulation) gathers and stamps every batch
-    C times. Here the batch fetch (and, unless `per_client_trigger`, the
-    stamp) is hoisted out of the model vmap: one gather per batch, shared
-    by all C models; only the forward passes are batched over clients.
-    Numerics are bit-identical to vmapping :func:`make_eval_fn` — same ops,
-    same per-client accumulation order (tests/test_eval_stacked.py).
+    Fetching each test batch inside a per-client vmap (the naive formulation)
+    gathers every batch C times. Here the fetch is hoisted out of the model
+    vmap: one gather per batch, shared by all C models; only the forward
+    passes are batched over clients. Numerics are bit-identical to vmapping
+    :func:`make_eval_fn` — same ops, same per-client accumulation order
+    (tests/test_eval_stacked.py). The poisoned tests of single client models
+    go through :func:`make_eval_fn` itself, one recorded row at a time
+    (:func:`local_battery_jobs`)."""
 
-    `per_client_trigger=True` is the Mytest_poison_agent_trigger variant
-    (test.py:180-239): `adv` is a [C] array and each client's model is
-    evaluated against its own trigger pattern, so only the stamp stays
-    under the vmap; the fetch is still shared."""
-
-    def evaluate_stacked(stacked_vars: ModelVars, idx, slots, mask,
-                         adv) -> EvalResult:
+    def evaluate_stacked(stacked_vars: ModelVars, idx, slots,
+                         mask) -> EvalResult:
         def body(carry, inp):
             loss_sum, correct, count = carry         # [C] each
             bidx, bslot, bmask = inp
             x, y = data.fetch_test(bslot, bidx)      # ONE gather, shared
-            if poison and not per_client_trigger:
-                x, y, _ = data.stamp(x, y, adv, 0, poison_all=True)
             bmaskf = bmask.astype(jnp.float32)
 
-            def per_model(mv: ModelVars, adv_c):
-                if poison and per_client_trigger:
-                    xx, yy, _ = data.stamp(x, y, adv_c, 0, poison_all=True)
-                else:
-                    xx, yy = x, y
-                logits, _ = model_def.apply(mv, xx, train=False)
-                loss = cross_entropy_sum(logits, yy, bmask)
+            def per_model(mv: ModelVars):
+                logits, _ = model_def.apply(mv, x, train=False)
+                loss = cross_entropy_sum(logits, y, bmask)
                 preds = jnp.argmax(logits, axis=-1)
-                return (loss, jnp.sum((preds == yy) * bmaskf),
+                return (loss, jnp.sum((preds == y) * bmaskf),
                         jnp.sum(bmaskf))
 
-            adv_vec = (adv if per_client_trigger else
-                       jnp.zeros((loss_sum.shape[0],), jnp.int32))
-            dl, dc, dn = jax.vmap(per_model)(stacked_vars, adv_vec)
+            dl, dc, dn = jax.vmap(per_model)(stacked_vars)
             return (loss_sum + dl, correct + dc, count + dn), None
 
         C = jax.tree_util.tree_leaves(stacked_vars)[0].shape[0]
@@ -167,3 +156,56 @@ def make_stacked_eval_fn(model_def: ModelDef, data: DeviceData, poison: bool,
                           correct=correct, count=count)
 
     return evaluate_stacked
+
+
+def local_battery_jobs(poisoning_per_batch, adv_slot, num_epochs,
+                       baseline: bool, forensics: bool = False):
+    """Which poisoned tests of the local battery an attack run's recorder
+    writes: (pre, post, trigger), each a [C] bool, from the [I, C] task rows
+    of the segments the battery covers (numpy on the host, jax in the
+    program: the one rule both read). As the reference, only a poisoning
+    client's model is tested on poisoned data (image_train.py:150-164,
+    :275-295):
+
+    - `pre` (pre-scaling model, combined trigger) and `post` (submitted
+      model, combined trigger) for a lane that poisoned in ANY of the
+      segments; no `pre` under `baseline` (:148);
+    - `trigger` (submitted model, the lane's own trigger) for a listed
+      adversary, poisoning or not (:285-295);
+    - with `forensics`, `post` for every real lane as well
+      (`_record_forensics` reads its accuracy); a mesh's padding lanes
+      (no epochs) get nothing."""
+    poisoning = (poisoning_per_batch > 0).any(axis=0)
+    real = (num_epochs > 0).any(axis=0)
+    post = poisoning | real if forensics else poisoning
+    return (poisoning & (not baseline), post, (adv_slot >= 0).any(axis=0))
+
+
+def job_order(wanted):
+    """wanted [N] bool -> (order [N], n_jobs): the ids of the wanted jobs
+    first, in their original order (the rest follow), and how many they are:
+    the trip count of the job loop, read inside the program."""
+    return (jnp.argsort(~wanted, stable=True),
+            jnp.sum(wanted, dtype=jnp.int32))
+
+
+def battery_eval_counts(tasks_list, is_poison_run: bool, baseline: bool,
+                        forensics: bool) -> Dict[str, int]:
+    """What a round's tasks ask of the local batteries, counted on the host
+    from the task rows the program reads (one ClientTask of [C] numpy leaves
+    per segment; every segment runs a battery, the last one gating on the
+    whole round's rows and alone serving `forensics`): `battery_evals_run`
+    the single-model tests that run (the clean test of every lane plus
+    :func:`local_battery_jobs`) over `battery_evals_plan`, all four parts
+    (the clean one alone outside an attack run) for every lane."""
+    rows = [np.stack([getattr(t, f) for t in tasks_list]) for f in
+            ("poisoning_per_batch", "adv_slot", "num_epochs")]
+    n_seg, lanes = rows[0].shape
+    run = n_seg * lanes
+    for s in range(n_seg if is_poison_run else 0):
+        last = s == n_seg - 1
+        run += sum(int(j.sum()) for j in local_battery_jobs(
+            *(r if last else r[s:s + 1] for r in rows), baseline,
+            forensics and last))
+    return {"battery_evals_run": run,
+            "battery_evals_plan": (4 if is_poison_run else 1) * n_seg * lanes}

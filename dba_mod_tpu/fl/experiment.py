@@ -773,7 +773,11 @@ class Experiment:
                     mask_list = [np.pad(m, ((0, pad),) + ((0, 0),) * 3)
                                  for m in mask_list]
                     num_samples_np = np.pad(num_samples_np, (0, pad))
-            plan_span.count(**plan_step_counts(mask_list))
+            plan_span.count(
+                **plan_step_counts(mask_list),
+                **(evaluation.battery_eval_counts(
+                    tasks_list, self.is_poison_run, bool(params["baseline"]),
+                    self.engine.forensics) if self.local_eval else {}))
 
         with telemetry.span("round/stage", round=epoch):
             tasks_seq = jax.tree_util.tree_map(
@@ -846,7 +850,6 @@ class Experiment:
         sequential_debug's split dispatch — the same tail the fused round
         program runs on device."""
         params = self.params
-        tasks_last = jax.tree_util.tree_map(lambda l: l[-1], tasks_seq)
         tasks_first = jax.tree_util.tree_map(lambda l: l[0], tasks_seq)
         from dba_mod_tpu.fl.rounds import nbt_client_deltas
         with self.guard.watch("round/aggregate"), \
@@ -866,13 +869,12 @@ class Experiment:
         prev_deltas = (train.seg_deltas[-1] if train.seg_deltas else
                        jax.tree_util.tree_map(jnp.zeros_like, train.deltas))
         locals_dev = (self.engine.local_evals_fn(
-            self.global_vars, train.deltas, tasks_last, prev_deltas)
+            self.global_vars, train.deltas, tasks_seq, prev_deltas)
             if self.local_eval else None)
         seg_locals_dev = None
         if self.local_eval and self.engine.seg_local_evals_fn is not None:
             seg_locals_dev = self.engine.seg_local_evals_fn(
-                self.global_vars, train.seg_deltas, tasks_seq.scale,
-                tasks_seq.adv_slot)
+                self.global_vars, train.seg_deltas, tasks_seq)
         globals_dev = self.engine.global_evals_fn(result.new_vars)
         fstats_dev = None
         if self.engine.forensic_fn is not None:
@@ -1170,20 +1172,18 @@ class Experiment:
         # batteries share device 0 behind N+1's enqueue and the overlap
         # hides the host-side fetch/record/checkpoint path.
         deltas_pre, prev_dev, seg_deltas = eval_in
-        tasks_last = jax.tree_util.tree_map(lambda l: l[-1], tasks_seq)
-        scales, adv_slots = tasks_seq.scale, tasks_seq.adv_slot
         vars_old, vars_new = vars_before, new_vars
-        (vars_old, vars_new, deltas_pre, prev_dev, seg_deltas, tasks_last,
-         scales, adv_slots) = evaluation.place_eval_inputs(
+        (vars_old, vars_new, deltas_pre, prev_dev, seg_deltas,
+         tasks_ev) = evaluation.place_eval_inputs(
             (vars_old, vars_new, deltas_pre, prev_dev, seg_deltas,
-             tasks_last, scales, adv_slots), self._eval_device)
+             tasks_seq), self._eval_device)
         locals_dev = (engine.local_evals_fn(vars_old, deltas_pre,
-                                            tasks_last, prev_dev)
+                                            tasks_ev, prev_dev)
                       if self.local_eval else None)
         seg_locals_dev = None
         if self.local_eval and engine.seg_local_evals_fn is not None:
             seg_locals_dev = engine.seg_local_evals_fn(
-                vars_old, list(seg_deltas), scales, adv_slots)
+                vars_old, list(seg_deltas), tasks_ev)
         globals_dev = engine.global_evals_fn(vars_new)
         payload = ((locals_dev, globals_dev) + payload[2:8]
                    + (seg_locals_dev,) + payload[9:])

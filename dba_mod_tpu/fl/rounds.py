@@ -33,7 +33,9 @@ from dba_mod_tpu.fl import faults as flt
 from dba_mod_tpu.fl.client import (ClientMetrics, active_steps,
                                    make_client_step)
 from dba_mod_tpu.fl.device_data import DeviceData
-from dba_mod_tpu.fl.evaluation import EvalResult, make_eval_fn
+from dba_mod_tpu.fl.evaluation import (EvalResult, job_order,
+                                       local_battery_jobs, make_eval_fn,
+                                       make_stacked_eval_fn)
 from dba_mod_tpu.fl.state import ClientTask, RoundHyper
 from dba_mod_tpu.ops import aggregation as agg
 from dba_mod_tpu.ops.losses import tree_global_norm
@@ -299,6 +301,68 @@ class EvalPlans:
     poison_mask: jax.Array
 
 
+def make_local_battery(model_def: ModelDef, data: DeviceData,
+                       plans: EvalPlans, is_poison_run: bool, baseline: bool):
+    """battery(unscaled [C, ...], scaled [C, ...], tasks ([I, C] rows of the
+    segments whose flags gate the poison parts), forensics) -> LocalEvals
+    with [C] leaves: clean on the pre-scaling model (image_train.py:150-155,
+    :268-271), poison pre on it (:157-164), poison post + per-agent trigger
+    on the submitted one (:275-282, :291-295).
+
+    Clean part: every client's row is recorded, and the C models share ONE
+    eval plan, so the batch fetch is hoisted out of the model vmap (one
+    gather per batch instead of C). Poison parts: only a poisoning client's
+    model is tested on poisoned data, so they run as a list of single-model
+    jobs, one per row the recorder writes (`local_battery_jobs`), read from
+    the round's tasks inside the program: job id = part * C + lane, the
+    loop's trip count the number of jobs (0 in a clean round), a slot with no
+    job left at zeros (count 0). The eval inside a job is a scan of static
+    length over the poison plan."""
+    eval_clean_s = make_stacked_eval_fn(model_def, data)
+    eval_poison = make_eval_fn(model_def, data, poison=True)
+
+    def poison_jobs(unscaled: ModelVars, scaled: ModelVars, adv_slots,
+                    wanted):
+        C = adv_slots.shape[0]
+        order, n_jobs = job_order(jnp.stack(wanted).reshape(-1))
+
+        def job(i, rows):
+            j = order[i]
+            part, lane = j // C, j % C
+            model = jax.tree_util.tree_map(
+                lambda u, s: jnp.where(part == 0, u[lane], s[lane]),
+                unscaled, scaled)
+            r = eval_poison(model, plans.poison_idx, plans.poison_slots,
+                            plans.poison_mask,
+                            jnp.where(part == 2, adv_slots[lane], -1))
+            return jax.tree_util.tree_map(lambda row, v: row.at[j].set(v),
+                                          rows, r)
+
+        rows = jax.lax.fori_loop(
+            0, n_jobs, job,
+            EvalResult(*(jnp.zeros((3 * C,), jnp.float32),) * 4))
+        return [jax.tree_util.tree_map(lambda l: l[k * C:(k + 1) * C], rows)
+                for k in range(3)]
+
+    def battery(unscaled: ModelVars, scaled: ModelVars, tasks: ClientTask,
+                forensics: bool) -> LocalEvals:
+        clean = eval_clean_s(unscaled, plans.clean_idx, plans.clean_slots,
+                             plans.clean_mask)
+        if is_poison_run:
+            pre, post, agent = poison_jobs(
+                unscaled, scaled, tasks.adv_slot[-1],
+                local_battery_jobs(
+                    tasks.poisoning_per_batch, tasks.adv_slot,
+                    tasks.num_epochs, baseline, forensics))
+        else:
+            C = tasks.adv_slot.shape[1]
+            pre = post = agent = EvalResult(
+                *(jnp.zeros((C,), jnp.float32),) * 4)
+        return LocalEvals(clean, pre, post, agent)
+
+    return battery
+
+
 class RoundEngine:
     """Holds the jitted round + eval computations for one experiment config.
 
@@ -536,66 +600,40 @@ class RoundEngine:
             self.train_fn = jax.jit(train_fn)
             self.aggregate_fn = jax.jit(aggregate_fn)
 
-        # Stacked local battery: C client models share ONE eval plan, so the
-        # batch fetch + combined-trigger stamp are hoisted out of the model
-        # vmap — one gather per batch instead of C (the naive per-client
-        # vmap gathered and stamped every test batch C times per battery).
-        from dba_mod_tpu.fl.evaluation import make_stacked_eval_fn
-        eval_clean_s = make_stacked_eval_fn(model_def, data, poison=False)
-        eval_poison_s = make_stacked_eval_fn(model_def, data, poison=True)
-        eval_agent_s = make_stacked_eval_fn(model_def, data, poison=True,
-                                            per_client_trigger=True)
+        battery = make_local_battery(model_def, data, plans, is_poison_run,
+                                     bool(params["baseline"]))
 
         def _bc(s, leaf):
             """[C] → [C, 1, ...] for per-client scalars against [C, ...]."""
             return s.reshape((s.shape[0],) + (1,) * (leaf.ndim - 1))
 
-        def _stacked_battery(unscaled: ModelVars, scaled: ModelVars,
-                             adv_slots) -> LocalEvals:
-            """The per-client battery (all leaves [C]): clean on the
-            pre-scaling model (image_train.py:150-155, :268-271), poison pre
-            on it (:157-164), poison post + per-agent trigger on the
-            submitted one (:275-282, :291-295)."""
-            clean = eval_clean_s(unscaled, plans.clean_idx, plans.clean_slots,
-                                 plans.clean_mask, jnp.int32(-1))
-            if is_poison_run:
-                pre = eval_poison_s(unscaled, plans.poison_idx,
-                                    plans.poison_slots, plans.poison_mask,
-                                    jnp.int32(-1))
-                post = eval_poison_s(scaled, plans.poison_idx,
-                                     plans.poison_slots, plans.poison_mask,
-                                     jnp.int32(-1))
-                agent = eval_agent_s(scaled, plans.poison_idx,
-                                     plans.poison_slots, plans.poison_mask,
-                                     adv_slots)
-            else:
-                C = adv_slots.shape[0]
-                zero = EvalResult(*(jnp.zeros((C,), jnp.float32),) * 4)
-                pre = post = agent = zero
-            return LocalEvals(clean, pre, post, agent)
-
         def local_evals(global_vars: ModelVars, deltas: ModelVars,
-                        tasks: ClientTask,
+                        tasks_seq: ClientTask,
                         prev_deltas: ModelVars) -> LocalEvals:
             # `prev_deltas` anchors the final segment: the pre-scaling model
             # is (global + prev) + (Δ - prev)/scale — for interval=1 prev is
             # zero and this reduces to global + Δ/scale; for interval>1 it
             # divides only the FINAL segment's step by its scale (earlier
-            # segments' contributions were already scaled when submitted)
+            # segments' contributions were already scaled when submitted).
+            # The round-final poison rows gate on ALL the round's segments
+            # (a client may poison epoch 3 of a (3,4) round), hence tasks_seq
+            scale = tasks_seq.scale[-1]
             unscaled = jax.tree_util.tree_map(
-                lambda g, p, d: g + p + (d - p) / _bc(tasks.scale, d),
+                lambda g, p, d: g + p + (d - p) / _bc(scale, d),
                 global_vars, prev_deltas, deltas)
             scaled = jax.tree_util.tree_map(lambda g, d: g + d, global_vars,
                                             deltas)
-            return _stacked_battery(unscaled, scaled, tasks.adv_slot)
+            return battery(unscaled, scaled, tasks_seq, forensics_on)
 
         if mesh is not None:
             from dba_mod_tpu.parallel.mesh import (client_sharding,
-                                                   replicated_sharding)
+                                                   replicated_sharding,
+                                                   segment_client_sharding)
             self.local_evals_fn = jax.jit(
                 local_evals,
                 in_shardings=(replicated_sharding(mesh),
-                              client_sharding(mesh), client_sharding(mesh),
+                              client_sharding(mesh),
+                              segment_client_sharding(mesh),
                               client_sharding(mesh)))
         else:
             self.local_evals_fn = jax.jit(local_evals)
@@ -607,8 +645,8 @@ class RoundEngine:
         # per-agent trigger test (:273-295) — the final segment is covered by
         # local_evals above, intermediate segments here, with the same
         # LocalEvals battery per segment.
-        def seg_local_evals(global_vars: ModelVars, seg_deltas, scales_seq,
-                            adv_slots_seq):
+        def seg_local_evals(global_vars: ModelVars, seg_deltas,
+                            tasks_seq: ClientTask):
             outs = []
             prev = None
             for s, cur in enumerate(seg_deltas):
@@ -617,12 +655,16 @@ class RoundEngine:
                 # live model of this segment: anchor (global + prev Δ) plus
                 # this segment's step, unscaled for the pre rows
                 unscaled = jax.tree_util.tree_map(
-                    lambda g, p, c: g + p + (c - p) / _bc(scales_seq[s], c),
+                    lambda g, p, c: g + p + (c - p) / _bc(
+                        tasks_seq.scale[s], c),
                     global_vars, prev, cur)
                 scaled = jax.tree_util.tree_map(
                     lambda g, c: g + c, global_vars, cur)
-                outs.append(_stacked_battery(unscaled, scaled,
-                                             adv_slots_seq[s]))
+                # an intermediate segment's rows gate on its own flags
+                outs.append(battery(
+                    unscaled, scaled,
+                    jax.tree_util.tree_map(lambda l: l[s:s + 1], tasks_seq),
+                    False))
                 prev = cur
             return outs
 
@@ -636,7 +678,6 @@ class RoundEngine:
                     in_shardings=(replicated_sharding(mesh),
                                   [client_sharding(mesh)]
                                   * (num_segments - 1),
-                                  segment_client_sharding(mesh),
                                   segment_client_sharding(mesh)))
             else:
                 self.seg_local_evals_fn = jax.jit(seg_local_evals)
@@ -693,13 +734,15 @@ class RoundEngine:
         # Standalone batteries get telemetry spans with honest device-sync
         # points (fl/evaluation.py:instrument_eval) — a passthrough while
         # telemetry is off, so the fused/pipelined paths keep their deferred
-        # sync. `batches` counts eval-plan scan steps (= batch fetches; the
-        # stacked batteries share one gather across the C client models).
+        # sync. `batches` counts the eval-plan scan steps known when the
+        # engine is built (= batch fetches; the stacked clean part shares one
+        # gather across the C client models). The local battery's poison jobs
+        # vary with the round: the `round/plan` span counts them
+        # (`battery_evals_run`).
         from dba_mod_tpu.fl.evaluation import instrument_eval
         clean_steps = int(plans.clean_idx.shape[0])
         poison_steps = int(plans.poison_idx.shape[0])
-        local_batches = clean_steps + (3 * poison_steps if is_poison_run
-                                       else 0)
+        local_batches = clean_steps
         global_batches = clean_steps + ((1 + n_triggers) * poison_steps
                                         if is_poison_run else 0)
         self.local_evals_fn = instrument_eval(
@@ -741,7 +784,6 @@ class RoundEngine:
                                  lane, rng_t)
             deltas, fg_grads = train.deltas, train.fg_grads
             fg_feature = train.fg_feature
-            tasks_last = jax.tree_util.tree_map(lambda l: l[-1], tasks_seq)
             tasks_first = jax.tree_util.tree_map(lambda l: l[0], tasks_seq)
             with jax.named_scope("phase/aggregate"):
                 nbt = nbt_client_deltas(mask_seq, tasks_seq.scale)
@@ -838,11 +880,10 @@ class RoundEngine:
                 # deltas
                 with jax.named_scope("phase/local_battery"):
                     locals_ = (local_evals(global_vars, train.deltas,
-                                           tasks_last, prev)
+                                           tasks_seq, prev)
                                if do_local_eval else None)
                     seg_l = (seg_local_evals(
-                        global_vars, train.seg_deltas, tasks_seq.scale,
-                        tasks_seq.adv_slot)
+                        global_vars, train.seg_deltas, tasks_seq)
                         if do_local_eval and num_segments > 1 else None)
                 with jax.named_scope("phase/global_battery"):
                     globals_ = global_evals(res.new_vars)

@@ -1,6 +1,8 @@
-"""The local-battery fetch/stamp hoist (make_stacked_eval_fn) must be
-bit-identical to vmapping the per-client eval kernel — same ops, same
-accumulation order, one shared gather instead of C."""
+"""The local battery's clean part (make_stacked_eval_fn: the fetch hoisted
+out of the model vmap) must be bit-identical to vmapping the per-client eval
+kernel — same ops, same accumulation order, one shared gather instead of C.
+The poison parts run that kernel itself, one recorded row at a time
+(tests/test_local_battery_jobs.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -42,24 +44,10 @@ def _eq(a, b):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
-def test_stacked_clean_and_combined_poison_bit_exact():
+def test_stacked_clean_bit_exact():
     mdef, dd, stacked, idx, slots, mask = _setup()
-    for poison in (False, True):
-        per = make_eval_fn(mdef, dd, poison=poison)
-        ref = jax.vmap(per, in_axes=(0, None, None, None, None))(
-            stacked, idx, slots, mask, jnp.int32(-1))
-        got = make_stacked_eval_fn(mdef, dd, poison=poison)(
-            stacked, idx, slots, mask, jnp.int32(-1))
-        _eq(got, ref)
-
-
-def test_stacked_per_client_trigger_bit_exact():
-    mdef, dd, stacked, idx, slots, mask = _setup()
-    advs = jnp.asarray([0, 1, -1], jnp.int32)  # each client its own trigger
-    per = make_eval_fn(mdef, dd, poison=True)
-    ref = jax.vmap(per, in_axes=(0, None, None, None, 0))(
-        stacked, idx, slots, mask, advs)
-    got = make_stacked_eval_fn(mdef, dd, poison=True,
-                               per_client_trigger=True)(
-        stacked, idx, slots, mask, advs)
+    per = make_eval_fn(mdef, dd, poison=False)
+    ref = jax.vmap(per, in_axes=(0, None, None, None, None))(
+        stacked, idx, slots, mask, jnp.int32(-1))
+    got = make_stacked_eval_fn(mdef, dd)(stacked, idx, slots, mask)
     _eq(got, ref)
