@@ -6,9 +6,12 @@ drop_last=False). The TPU-native equivalent precomputes, per round, an index
 tensor [clients, epochs, steps, batch] plus a validity mask; the jitted client
 step gathers rows straight from the device-resident dataset — the host ships
 only these small int32 plans each round. The plan's shape is static (one
-compiled round program); the client step's loop runs only the steps in which
-some client's mask holds a real row (fl/client.py::active_steps), so a step
-padded in every client costs nothing, and `plan_step_counts` counts both.
+compiled round program); the client step's full-width loop runs only the
+steps in which some client's mask holds a real row (fl/client.py::
+active_steps), so a step padded in every client costs nothing, and stops
+after the last step two clients share: what one client alone still needs
+runs as a job at width 1 (fl/client.py::split_steps). `plan_step_counts`
+counts all of it from the same masks.
 
 Shuffling uses per-client numpy RNG rather than the reference's global torch
 RNG: the sequential loop's RNG stream is inherently irreproducible under
@@ -73,19 +76,37 @@ def build_batch_plan(client_indices: Sequence[Sequence[int]],
                      num_epochs=np.asarray(client_epochs, np.int32))
 
 
-def plan_step_counts(masks: Sequence[np.ndarray]) -> Dict[str, int]:
-    """What a round's plan asks of the steps loop, from its masks (one
-    [C, E, S, B] per segment): `steps_plan` the loop's static length over
-    the segments (E x S each), `steps_run` the steps in which ANY lane holds a
-    real batch — what the client step's loop reads from the same mask and
-    runs, rounded up to its chunk (fl/client.py::active_steps, STEP_CHUNK) —
-    `lane_steps_real` the real client-steps, and `lanes` (C). `steps_run x lanes - lane_steps_real` lane-steps still
-    run masked: what packing lanes could win."""
+def plan_step_counts(masks: Sequence[np.ndarray], chunk: int,
+                     narrow_tail: bool) -> Dict[str, int]:
+    """What a round's plan asks of the steps loops, from its masks (one
+    [C, E, S, B] per segment). The plan: `steps_plan` the loops' static
+    length over the segments (E x S each), `steps_run` the steps in which ANY
+    lane holds a real batch, `lane_steps_real` the real client-steps, and
+    `lanes` (C). What the program runs of it, by fl/client.py's rule in
+    numpy (`chunk` its STEP_CHUNK, `narrow_tail` whether the engine builds
+    the job loop): `steps_wide` the positions the full-width loop runs —
+    the steps that run up to the last one two or more lanes share, or all of
+    them without the job loop, rounded up to the chunk — and
+    `lane_steps_narrow` the real client-steps past that boundary, run one
+    lane at a time. `steps_wide x lanes + lane_steps_narrow -
+    lane_steps_real` slots still run masked: what packing lanes could win."""
     real = np.stack([np.asarray(m).any(axis=-1) for m in masks])  # [I,C,E,S]
-    return {"steps_plan": int(real.shape[0] * real.shape[2] * real.shape[3]),
-            "steps_run": int(real.any(axis=1).sum()),
-            "lane_steps_real": int(real.sum()),
-            "lanes": int(real.shape[1])}
+    n_seg, lanes = real.shape[:2]
+    live = real.reshape(n_seg, lanes, -1).sum(axis=1)             # [I, E*S]
+    tail = narrow_tail and lanes > 1
+    steps_wide = lane_steps_narrow = 0
+    for seg in live:
+        seg = seg[seg > 0]                # by position of the loops' order
+        last = np.flatnonzero(seg >= 2 if tail else seg > 0)
+        n_wide = -(-(last[-1] + 1 if len(last) else 0) // chunk) * chunk
+        steps_wide += int(n_wide)
+        lane_steps_narrow += int(seg[n_wide:].sum())
+    return {"steps_plan": int(n_seg * live.shape[1]),
+            "steps_run": int((live > 0).sum()),
+            "lane_steps_real": int(live.sum()),
+            "lanes": int(lanes),
+            "steps_wide": steps_wide,
+            "lane_steps_narrow": lane_steps_narrow}
 
 
 def build_eval_plan(indices: np.ndarray, batch_size: int) -> EvalPlan:

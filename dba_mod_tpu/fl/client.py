@@ -1,7 +1,13 @@
-"""The single-client local-training step — one loop over the plan's steps,
-vmapped over the clients axis by the round engine. The loop's trip count is
-read from the round's mask: only the steps in which SOME lane has a real
-batch run (`active_steps`), in their original order, STEP_CHUNK at a time.
+"""The local-training step of one segment: a single client's step body, run
+by two loops whose trip counts are read from the round's mask. The
+full-width loop runs every lane (`vmap` over the clients axis) through the
+steps in which SOME lane has a real batch (`active_steps`), in their
+original order, STEP_CHUNK at a time, up to the last step that two or more
+lanes need; what is left holds at most one live lane a step (an adversary's
+extra epochs beside benign lanes), and a job loop runs it one lane at a
+time at width 1 (`split_steps`): nine masked lanes cost nine lanes' time.
+An engine with a sharded clients axis, or one lane, runs the full-width
+loop alone.
 
 Capability parity with the reference client loop (image_train.py:21-315,
 loan_train.py:17-261), re-expressed as data-dependent selects so benign and
@@ -39,6 +45,7 @@ import jax.numpy as jnp
 
 from dba_mod_tpu.models import ModelDef, ModelVars
 from dba_mod_tpu.fl.device_data import DeviceData
+from dba_mod_tpu.fl.evaluation import job_order
 from dba_mod_tpu.fl.state import ClientTask, RoundHyper
 from dba_mod_tpu.ops.fused_update import make_fused_step_update
 from dba_mod_tpu.ops.losses import cross_entropy, tree_dist_norm
@@ -93,47 +100,99 @@ def active_steps(mask):
             (n_run + STEP_CHUNK - 1) // STEP_CHUNK)
 
 
+class StepSplit(NamedTuple):
+    """How one segment's steps divide between the two loops."""
+    order: jax.Array       # [E*S] `active_steps`' order
+    n_wide: jax.Array      # chunks of `order` the full-width loop runs
+    job_lanes: jax.Array   # [C] the lanes with a tail first, in lane order
+    n_jobs: jax.Array      # how many they are: the job loop's trip count
+    lane_order: jax.Array  # [C, E*S] a lane's own tail steps first, in order
+    n_tail: jax.Array      # [C] how many those are
+
+
+def split_steps(mask) -> StepSplit:
+    """mask [C, E, S, B] of one segment -> where the full-width loop stops
+    and what each lane still has to run alone. The full-width loop runs the
+    positions of `order` up to and including the last one at which two or
+    more lanes hold a real batch, rounded up to STEP_CHUNK; every position
+    after that holds at most one live lane, and a lane with a real step
+    there is one job: its own real steps past the boundary, in their order.
+    Lanes are independent within a segment, so no lane's sequence of steps
+    changes. A width-1 step never costs more than a full-width one, so
+    there is nothing to tune: an equal-split or all-benign round has no job
+    and runs what `active_steps` says. data/batching.py::plan_step_counts
+    is the same rule in numpy."""
+    C = mask.shape[0]
+    real = jnp.any(mask, axis=3).reshape(C, -1)            # [C, E*S] by id
+    order, _ = active_steps(mask)
+    real = real[:, order]                                  # by position
+    pos = jnp.arange(real.shape[1], dtype=jnp.int32)
+    shared = jnp.sum(real, axis=0, dtype=jnp.int32) >= 2
+    n_wide = (jnp.max(jnp.where(shared, pos + 1, 0))
+              + STEP_CHUNK - 1) // STEP_CHUNK
+    tail = real & (pos >= n_wide * STEP_CHUNK)
+    n_tail = jnp.sum(tail, axis=1, dtype=jnp.int32)
+    return StepSplit(
+        order, n_wide, *job_order(n_tail > 0),
+        order[jnp.argsort(~tail, axis=1, stable=True)], n_tail)
+
+
 def make_client_step(model_def: ModelDef, data: DeviceData,
                      hyper: RoundHyper, fg_enabled: bool,
                      fused_pallas: bool = False,
-                     fused_interpret: bool = False):
-    """Returns client_step(start_vars, benign_mom, task_row, idx[E,S,B],
-    mask[E,S,B], rng, order[E*S], n_chunks) -> SegmentResult, suitable for
-    vmap over (start_vars, benign_mom, task_row, idx, mask, rng) with `order`
-    and `n_chunks` (`active_steps` of the whole segment's mask) unbatched:
-    the loop runs the steps `order[:n_chunks * STEP_CHUNK]`, one `while`
-    whose predicate no lane batches around STEP_CHUNK steps at a time.
-    `fused_pallas` routes the per-step state update through the fused
-    multi-tensor kernel (ops/fused_update.py) when the engine runs unsharded
-    on TPU; the math is identical either way."""
+                     fused_interpret: bool = False,
+                     narrow_tail: bool = True):
+    """Returns segment_step(start_vars, benign_mom, tasks, idx[C,E,S,B],
+    mask[C,E,S,B], rngs[C]) -> SegmentResult, every argument and result
+    stacked [C, ...]: one segment of every lane. The single client's step
+    body is written once and run by the full-width loop (`vmap` over the
+    lanes; one `while` whose predicate no lane batches around STEP_CHUNK
+    steps at a time) and, with `narrow_tail` and more than one lane, by the
+    job loop that finishes a lane's tail at width 1 (`split_steps`). The
+    engine clears `narrow_tail` where the clients axis is sharded: taking
+    one lane out of a sharded stack is a collective.
+    `fused_pallas` routes the full-width loop's per-step state update
+    through the fused multi-tensor kernel (ops/fused_update.py) when the
+    engine runs unsharded on TPU; the math is identical either way."""
     fused_update = make_fused_step_update(
         hyper.momentum, hyper.weight_decay, fg_enabled,
         use_pallas=fused_pallas, interpret=fused_interpret)
 
-    def client_step(start_vars: ModelVars, benign_mom: Any, task: ClientTask,
-                    idx, mask, rng, order, n_chunks) -> SegmentResult:
-        E, S, B = idx.shape
-        idx, mask = idx.reshape(E * S, B), mask.reshape(E * S, B)
-        params0, bn0 = start_vars.params, start_vars.batch_stats
+    def lane_init(start_vars: ModelVars, benign_mom: Any, task: ClientTask,
+                  E: int, S: int):
+        params0 = start_vars.params
         # The benign optimizer lives for the whole round (image_train.py:33 is
         # outside the global-epoch loop), so its momentum chains across
         # segments; the poison optimizer is fresh per poison epoch
         # (image_train.py:63 inside the loop) → zero buffers.
-        is_poison_seg = task.poisoning_per_batch > 0
-        zeros = sgd_init(params0)
-        mom0 = _select_tree(is_poison_seg, zeros, benign_mom)
+        mom0 = _select_tree(task.poisoning_per_batch > 0, sgd_init(params0),
+                            benign_mom)
         fg0 = jax.tree_util.tree_map(jnp.zeros_like, params0)
         zeros_e = jnp.zeros((E,), jnp.float32)
         metrics0 = ClientMetrics(zeros_e, zeros_e, zeros_e, zeros_e)
+        # [E*S] per-step channels, zero where no step ran; zero-width when
+        # tracking is off: shape-compatible, nothing carried or transferred
+        tracked0 = (jnp.zeros((E * S if hyper.track_batches else 0,),
+                              jnp.float32),) * 2
+        return params0, start_vars.batch_stats, mom0, fg0, metrics0, tracked0
+
+    def lane_steps(source, carry, params0, task: ClientTask, idx, mask, rng,
+                   order, n_valid, n_chunks):
+        """One lane through the steps `order[:n_chunks * STEP_CHUNK]`, of
+        which the first `n_valid` may hold a real batch; `source`: the
+        arrays the batches are fetched from."""
+        E, S, B = idx.shape
+        idx, mask = idx.reshape(E * S, B), mask.reshape(E * S, B)
 
         def step(i, carry):
             params, bn, mom, fg, m, tracked = carry
-            # position i of `order`; the last chunk may reach past its end:
-            # such a position reads the last step with nothing valid in it
+            # position i of `order`; the last chunk may reach past
+            # `n_valid`, or past the plan's end: such a position reads some
+            # step with nothing valid in it
             step_i = order[jnp.minimum(i, E * S - 1)]
-            bidx, bmask = idx[step_i], mask[step_i] & (i < E * S)
+            bidx, bmask = idx[step_i], mask[step_i] & (i < n_valid)
             e = step_i // S
-            x, y = data.fetch_train(task.slot, bidx)
+            x, y = data.fetch_train(task.slot, bidx, source)
             x, y, sel = data.stamp(x, y, task.adv_index,
                                    task.poisoning_per_batch)
             # derive from (epoch, step-within-epoch), NOT the flat index:
@@ -193,27 +252,58 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
             return jax.lax.fori_loop(
                 0, STEP_CHUNK, lambda k, c: step(j * STEP_CHUNK + k, c), carry)
 
-        # [E*S] per-step channels, zero where no step ran; zero-width when
-        # tracking is off: shape-compatible, nothing carried or transferred
-        tracked0 = (jnp.zeros((E * S if hyper.track_batches else 0,),
-                              jnp.float32),) * 2
         # a dynamic trip count makes the outer loop a `while` (nothing
         # differentiates through the steps loop: the gradient is inside each
         # step)
-        params, bn, mom, fg, metrics, (batch_loss, batch_dist) = \
-            jax.lax.fori_loop(0, n_chunks, chunk,
-                              (params0, bn0, mom0, fg0, metrics0, tracked0))
-        # a poison segment leaves the benign buffers untouched
-        benign_mom_out = _select_tree(is_poison_seg, benign_mom, mom)
+        return jax.lax.fori_loop(0, n_chunks, chunk, carry)
 
+    def lane_finish(carry, start_vars: ModelVars, benign_mom: Any,
+                    task: ClientTask) -> SegmentResult:
+        params, bn, mom, fg, metrics, (batch_loss, batch_dist) = carry
+        # a poison segment leaves the benign buffers untouched
+        benign_mom_out = _select_tree(task.poisoning_per_batch > 0,
+                                      benign_mom, mom)
         # Model-replacement scaling over the FULL state (image_train.py:166-171
         # iterates state_dict — BN stats included) against the segment anchor.
-        end_vars = ModelVars(
-            params=jax.tree_util.tree_map(
-                lambda a, w: a + task.scale * (w - a), params0, params),
-            batch_stats=jax.tree_util.tree_map(
-                lambda a, w: a + task.scale * (w - a), bn0, bn))
+        end_vars = jax.tree_util.tree_map(
+            lambda a, w: a + task.scale * (w - a), start_vars,
+            ModelVars(params, bn))
         return SegmentResult(end_vars, benign_mom_out, fg, metrics,
                              batch_loss, batch_dist)
 
-    return client_step
+    def segment_step(start_vars: ModelVars, benign_mom: Any,
+                     tasks: ClientTask, idx, mask, rngs) -> SegmentResult:
+        C, E, S, _ = idx.shape
+        feed = (start_vars.params, tasks, idx, mask, rngs)
+        split = split_steps(mask) if narrow_tail and C > 1 else None
+        order, n_wide = (active_steps(mask) if split is None
+                         else (split.order, split.n_wide))
+        # with two loops reading the dataset, one traced value for both:
+        # as a constant it would ride in the executable once a loop (1.2 GB
+        # each at Tiny-ImageNet's size, and past 2 GiB the executable can
+        # no longer be written to the compile cache)
+        source = data.train_source
+        if split is not None:
+            source = jax.lax.optimization_barrier(source)
+        carry = jax.vmap(lambda *lane: lane_init(*lane, E, S))(
+            start_vars, benign_mom, tasks)
+        carry = jax.vmap(lane_steps, in_axes=(None,) + (0,) * 6 + (None,) * 3)(
+            source, carry, *feed, order, E * S, n_wide)
+
+        def job(k, carry):
+            c = split.job_lanes[k]
+            n = split.n_tail[c]
+            row = jax.tree_util.tree_map(
+                lambda l: jax.lax.dynamic_index_in_dim(l, c, keepdims=False),
+                (carry, *feed))
+            done = lane_steps(source, *row, split.lane_order[c], n,
+                              (n + STEP_CHUNK - 1) // STEP_CHUNK)
+            return jax.tree_util.tree_map(
+                lambda l, r: jax.lax.dynamic_update_index_in_dim(l, r, c, 0),
+                carry, done)
+
+        if split is not None:
+            carry = jax.lax.fori_loop(0, split.n_jobs, job, carry)
+        return jax.vmap(lane_finish)(carry, start_vars, benign_mom, tasks)
+
+    return segment_step

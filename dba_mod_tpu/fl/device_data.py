@@ -23,8 +23,10 @@ from dba_mod_tpu.ops import triggers
 from dba_mod_tpu.utils import telemetry
 
 # fetch(slot, idx[B]) -> (x[B, ...], y[B]); stamp(x, y, adv_index, k,
-# poison_all) -> (x, y, poisoned_mask)
-FetchFn = Callable[[jax.Array, jax.Array], Tuple[jax.Array, jax.Array]]
+# poison_all) -> (x, y, poisoned_mask). fetch_train takes a third, optional
+# argument: the arrays to read in place of the ones it closed over
+# (`DeviceData.train_source`, or a traced value made from it)
+FetchFn = Callable[..., Tuple[jax.Array, jax.Array]]
 StampFn = Callable[..., Tuple[jax.Array, jax.Array, jax.Array]]
 
 
@@ -36,6 +38,12 @@ class DeviceData:
     num_train: int
     num_test: int
     compute_dtype: jnp.dtype
+    # (images or features, labels) as fetch_train reads them. A jitted
+    # program that closes over them carries them as a constant, and XLA
+    # copies that constant into the body of every loop that reads it: a
+    # program with two such loops hands fetch_train one traced value
+    # instead (fl/client.py)
+    train_source: Tuple[jax.Array, jax.Array]
 
 
 def make_image_device_data(data: ImageData, params: cfg.Params,
@@ -52,9 +60,9 @@ def make_image_device_data(data: ImageData, params: cfg.Params,
         jax.block_until_ready((train_x, train_y, test_x, test_y, bank))
     swap = int(params["poison_label_swap"])
 
-    def fetch_train(slot, idx):
-        x = train_x[idx].astype(compute_dtype) / 255.0
-        return x, train_y[idx]
+    def fetch_train(slot, idx, source=(train_x, train_y)):
+        xs, ys = source
+        return xs[idx].astype(compute_dtype) / 255.0, ys[idx]
 
     def fetch_test(slot, idx):
         x = test_x[idx].astype(compute_dtype) / 255.0
@@ -67,7 +75,8 @@ def make_image_device_data(data: ImageData, params: cfg.Params,
     return DeviceData(fetch_train, fetch_test, stamp,
                       num_train=len(data.train_labels),
                       num_test=len(data.test_labels),
-                      compute_dtype=compute_dtype)
+                      compute_dtype=compute_dtype,
+                      train_source=(train_x, train_y))
 
 
 def make_loan_device_data(data: LoanData, params: cfg.Params,
@@ -87,8 +96,9 @@ def make_loan_device_data(data: LoanData, params: cfg.Params,
                                masks))
     swap = int(params["poison_label_swap"])
 
-    def fetch_train(slot, idx):
-        return train_x[slot, idx], train_y[slot, idx]
+    def fetch_train(slot, idx, source=(train_x, train_y)):
+        xs, ys = source
+        return xs[slot, idx], ys[slot, idx]
 
     def fetch_test(slot, idx):
         return test_x[slot, idx], test_y[slot, idx]
@@ -100,4 +110,5 @@ def make_loan_device_data(data: LoanData, params: cfg.Params,
     return DeviceData(fetch_train, fetch_test, stamp,
                       num_train=sum(len(y) for y in data.train_y),
                       num_test=sum(len(y) for y in data.test_y),
-                      compute_dtype=compute_dtype)
+                      compute_dtype=compute_dtype,
+                      train_source=(train_x, train_y))

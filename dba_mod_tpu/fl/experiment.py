@@ -30,6 +30,7 @@ from dba_mod_tpu.data.partition import (equal_split_indices,
                                         poison_test_indices,
                                         sample_dirichlet_indices)
 from dba_mod_tpu.fl import evaluation
+from dba_mod_tpu.fl.client import STEP_CHUNK
 from dba_mod_tpu.fl.device_data import (make_image_device_data,
                                         make_loan_device_data)
 from dba_mod_tpu.fl.rounds import EvalPlans, RoundEngine
@@ -774,7 +775,9 @@ class Experiment:
                                  for m in mask_list]
                     num_samples_np = np.pad(num_samples_np, (0, pad))
             plan_span.count(
-                **plan_step_counts(mask_list),
+                **plan_step_counts(
+                    mask_list, STEP_CHUNK,
+                    self.engine.narrow_tail and not self.sequential_debug),
                 **(evaluation.battery_eval_counts(
                     tasks_list, self.is_poison_run, bool(params["baseline"]),
                     self.engine.forensics) if self.local_eval else {}))

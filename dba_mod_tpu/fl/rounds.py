@@ -30,8 +30,7 @@ import numpy as np
 from dba_mod_tpu import config as cfg
 from dba_mod_tpu.models import ModelDef, ModelVars
 from dba_mod_tpu.fl import faults as flt
-from dba_mod_tpu.fl.client import (ClientMetrics, active_steps,
-                                   make_client_step)
+from dba_mod_tpu.fl.client import ClientMetrics, make_client_step
 from dba_mod_tpu.fl.device_data import DeviceData
 from dba_mod_tpu.fl.evaluation import (EvalResult, job_order,
                                        local_battery_jobs, make_eval_fn,
@@ -415,9 +414,14 @@ class RoundEngine:
             mesh is None and jax.default_backend() == "tpu")
         self.fused_pallas = fused_pallas
         self.fused_interpret = bool(params.get("fused_interpret", False))
-        client_step = make_client_step(
+        # one lane's tail at width 1 (fl/client.py::split_steps): taking a
+        # lane out of a sharded stack is a collective nobody has priced, so
+        # the mesh path keeps the full-width loop alone
+        self.narrow_tail = mesh is None
+        segment_step = make_client_step(
             model_def, data, hyper, fg_enabled, fused_pallas=fused_pallas,
-            fused_interpret=self.fused_interpret)
+            fused_interpret=self.fused_interpret,
+            narrow_tail=self.narrow_tail)
         # grouped-layout client execution (models/grouped.py): holds the
         # grouped layout vmap's conv batching re-derives per conv. The one
         # A/B on record (benchmarks/grouped_ab.py, TRAIN_FLOOR.md round-5
@@ -437,7 +441,7 @@ class RoundEngine:
                 "model and an unsharded clients axis")
         if self.use_grouped:
             from dba_mod_tpu.fl.grouped_client import make_grouped_client_step
-            grouped_step = make_grouped_client_step(model_def, data, hyper,
+            segment_step = make_grouped_client_step(model_def, data, hyper,
                                                     fg_enabled)
         eval_clean = make_eval_fn(model_def, data, poison=False)
         eval_poison = make_eval_fn(model_def, data, poison=True)
@@ -463,17 +467,11 @@ class RoundEngine:
                 rngs = jax.vmap(
                     lambda i: jax.random.fold_in(seg_rng, i))(lane)
                 tasks_s = jax.tree_util.tree_map(lambda l: l[s], tasks_seq)
-                if self.use_grouped:
-                    res = grouped_step(start, benign_mom, tasks_s,
-                                       idx_seq[s], mask_seq[s], rngs)
-                else:
-                    # the steps loop runs only the steps some lane needs:
-                    # its trip count comes from the mask, inside the program
-                    order, n_chunks = active_steps(mask_seq[s])
-                    res = jax.vmap(
-                        client_step, in_axes=(0,) * 6 + (None, None))(
-                            start, benign_mom, tasks_s, idx_seq[s],
-                            mask_seq[s], rngs, order, n_chunks)
+                # the steps loops run only the steps some lane needs, and
+                # one lane's tail at width 1: their trip counts come from
+                # the mask, inside the program (fl/client.py)
+                res = segment_step(start, benign_mom, tasks_s, idx_seq[s],
+                                   mask_seq[s], rngs)
                 start = res.end_vars
                 benign_mom = res.benign_mom
                 if fg_enabled:

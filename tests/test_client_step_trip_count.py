@@ -1,6 +1,8 @@
-"""The client step's loop runs only the steps some lane needs (fl/client.py:
-`active_steps` -> a `while` over chunks of STEP_CHUNK steps), and what it
-computes is what the full-length loop computed, to the bit.
+"""The client step's loops run only the steps some lane needs (fl/client.py:
+`active_steps` -> a full-width `while` over chunks of STEP_CHUNK steps up to
+the last step two lanes share; `split_steps` -> a job loop that finishes one
+lane's tail at width 1), and what they compute is what the full-length loop
+computed, to the bit.
 
 The reference kept here (`make_full_length_client_step`) is the loop as it
 was before: one `lax.scan` over all E x S plan steps, masked steps included.
@@ -8,13 +10,24 @@ An engine built with it in place of `make_client_step` is driven on the same
 feeds as the program's own, on one device and on the 8-virtual-device
 `clients` mesh:
 
-- heavy_tail: a Dirichlet population's round with one 10-epoch adversary
+- heavy_tail: a Dirichlet population's round with one 6-epoch adversary
   beside 2-epoch benign lanes (the shape of the paper's attack round);
 - all_full: every lane real at every step (the loop runs what it ran; E*S
-  = 30 is no multiple of the chunk, so the last chunk reaches past the plan);
+  = 18 is no multiple of the chunk, so the last chunk reaches past the plan);
 - empty_client: one lane with no batch at all;
 - check_k1 / check_k3: the benchmark's output-check feed
-  (chipbench/program.py::check_round): only the first 1 or 3 steps of epoch 0.
+  (chipbench/program.py::check_round): only the first 1 or 3 steps of epoch 0;
+- solo_lane: the adversary's lane holds all the data: the full-width loop
+  runs nothing and every step is a job's;
+- two_tails: a second long lane (the widest benign lane, trained 6 epochs
+  as the adversary is): the full-width loop runs to the last step the two
+  share, and the tail starts after the shorter;
+- two_jobs: the widest benign lane trained 3 epochs: past the last step it
+  shares with the adversary it still holds one of its own, so the round has
+  two jobs (in a plan of whole epochs, the most a round can have).
+
+On the mesh (and with one lane) the engine builds no job loop: the same
+feeds run the full-width loop to the end.
 """
 from typing import Any
 
@@ -28,7 +41,7 @@ import dba_mod_tpu.fl.rounds as rounds_mod
 from dba_mod_tpu.config import Params
 from dba_mod_tpu.data.batching import plan_step_counts
 from dba_mod_tpu.fl.client import (STEP_CHUNK, ClientMetrics, SegmentResult,
-                                   _select_tree, active_steps)
+                                   _select_tree, active_steps, split_steps)
 from dba_mod_tpu.fl.experiment import Experiment
 from dba_mod_tpu.models import ModelVars
 from dba_mod_tpu.ops.fused_update import make_fused_step_update
@@ -40,7 +53,7 @@ CFG = dict(
     type="mnist", lr=0.1, batch_size=8, epochs=4, no_models=8,
     number_of_total_participants=16, eta=0.8,
     aggregation_methods="foolsgold", internal_epochs=2,
-    internal_poison_epochs=10, is_poison=True, synthetic_data=True,
+    internal_poison_epochs=6, is_poison=True, synthetic_data=True,
     synthetic_train_size=96, synthetic_test_size=128, momentum=0.9,
     decay=0.0005, sampling_dirichlet=True, dirichlet_alpha=0.5,
     local_eval=False, poison_label_swap=2, poisoning_per_batch=4,
@@ -49,19 +62,22 @@ CFG = dict(
     vis_train_batch_loss=True, batch_track_distance=True,
     **{"0_poison_pattern": [[0, 0], [0, 1], [0, 2], [0, 3]],
        "0_poison_epochs": [1, 2, 3]})
-CASES = ("heavy_tail", "all_full", "empty_client", "check_k1", "check_k3")
+CASES = ("heavy_tail", "all_full", "empty_client", "check_k1", "check_k3",
+         "solo_lane", "two_tails", "two_jobs")
 
 
 def make_full_length_client_step(model_def, data, hyper, fg_enabled,
-                                 fused_pallas=False, fused_interpret=False):
-    """The steps loop before the trip count: `lax.scan` over every one of
-    the E x S plan steps. Takes and ignores `order` and `n_chunks`."""
+                                 fused_pallas=False, fused_interpret=False,
+                                 narrow_tail=True):
+    """The steps loop before the trip counts: every lane through one
+    `lax.scan` over every one of the E x S plan steps (`narrow_tail` is
+    taken and ignored: there is one loop, at full width)."""
     fused_update = make_fused_step_update(
         hyper.momentum, hyper.weight_decay, fg_enabled,
         use_pallas=fused_pallas, interpret=fused_interpret)
 
     def client_step(start_vars: ModelVars, benign_mom: Any, task, idx, mask,
-                    rng, order, n_chunks) -> SegmentResult:
+                    rng) -> SegmentResult:
         E, S, B = idx.shape
         params0, bn0 = start_vars.params, start_vars.batch_stats
         is_poison_seg = task.poisoning_per_batch > 0
@@ -125,7 +141,7 @@ def make_full_length_client_step(model_def, data, hyper, fg_enabled,
                              _select_tree(is_poison_seg, benign_mom, mom), fg,
                              metrics, batch_loss, batch_dist)
 
-    return client_step
+    return jax.vmap(client_step)
 
 
 @pytest.fixture(scope="module", params=[0, 8], ids=["one_device", "mesh8"])
@@ -152,7 +168,10 @@ def _feed(exp, case):
     exp.select_rng = random.Random(7)
     exp.plan_rng = np.random.RandomState(7)
     tasks_seq, idx_seq, mask_seq, ns, lane = exp.build_static_round_inputs(1)
-    mask = np.array(mask_seq)
+    idx, mask = np.array(idx_seq), np.array(mask_seq)
+    steps = mask[0].any(axis=-1).sum(axis=-1)      # [C, E] steps an epoch
+    adv, wide = steps.sum(axis=1).argmax(), steps[:, 0].argmax()
+    assert adv != wide and steps[adv, -1] < steps[wide, 0]
     if case == "all_full":
         mask[:] = True
     elif case == "empty_client":
@@ -160,7 +179,13 @@ def _feed(exp, case):
     elif case.startswith("check_k"):
         mask[:, :, 1:] = False
         mask[:, :, 0, int(case[-1]):] = False
-    mask_seq = jnp.asarray(mask)
+    elif case == "solo_lane":
+        mask[:, np.arange(mask.shape[1]) != adv] = False
+    elif case in ("two_tails", "two_jobs"):
+        n_ep = mask.shape[2] if case == "two_tails" else 3
+        idx[:, wide, :n_ep] = idx[:, wide, :1]
+        mask[:, wide, :n_ep] = mask[:, wide, :1]
+    idx_seq, mask_seq = jnp.asarray(idx), jnp.asarray(mask)
     if exp.mesh is not None:
         from dba_mod_tpu.parallel.mesh import shard_round_inputs
         tasks_seq, idx_seq, mask_seq, ns = shard_round_inputs(
@@ -168,17 +193,35 @@ def _feed(exp, case):
     return tasks_seq, idx_seq, mask_seq, ns, lane, mask
 
 
-def _assert_trees_bit_equal(got, want):
+def _assert_trees_bit_equal(got, want, job_lanes=()):
+    """Bit-equal, leaf for leaf — but for the one thing XLA:CPU computes
+    otherwise at width 1: it sums a step's batch-mean loss in another order
+    than under `vmap`, so the loss a job's step records may come out a unit
+    in the last place off (the gradient, and with it every state the round
+    produces, does not). A leaf may differ only if it is a per-lane record
+    ([1, C, n] float32: `loss_sum`, `batch_loss` and the payload's copies),
+    only in `job_lanes`, and by at most 2 ulp."""
     got_l, tree_g = jax.tree_util.tree_flatten(got)
     want_l, tree_w = jax.tree_util.tree_flatten(want)
     assert tree_g == tree_w
+    C = CFG["no_models"]
     for g, w in zip(got_l, want_l):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        g, w = np.asarray(g), np.asarray(w)
+        if (len(job_lanes) and g.dtype == np.float32 and g.ndim == 3
+                and g.shape[:2] == (1, C)):
+            others = np.setdiff1d(np.arange(C), job_lanes)
+            np.testing.assert_array_equal(g[:, others], w[:, others])
+            np.testing.assert_array_max_ulp(g, w, maxulp=2)
+        else:
+            np.testing.assert_array_equal(g, w)
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_round_is_bit_equal_to_the_full_length_loop(pair, case):
     exp, ref = pair
+    if case == "two_jobs" and exp.mesh is not None:
+        pytest.skip("the mesh builds no job loop: two_tails is this feed's "
+                    "shape there, and a virtual-device round takes a minute")
     rng_t, rng_a = jax.random.split(jax.random.key(11))
     out = {}
     for name, e in (("exp", exp), ("ref", ref)):
@@ -199,15 +242,31 @@ def test_round_is_bit_equal_to_the_full_length_loop(pair, case):
                 rounds_mod.nbt_client_deltas(mask_seq, tasks_seq.scale))
             rest = (agg.new_vars, agg.new_fg_state, agg.wv)
         out[name] = (train, rest)
-    counts = plan_step_counts([mask[0]])
+    counts = plan_step_counts([mask[0]], STEP_CHUNK, exp.engine.narrow_tail)
     if case == "all_full":
         assert counts["steps_run"] == counts["steps_plan"]
     elif case == "heavy_tail":
-        # the adversary's 10 epochs against the benign lanes' 2
+        # the adversary's 6 epochs against the benign lanes' 2
         assert counts["steps_run"] < counts["steps_plan"]
         assert counts["lane_steps_real"] < counts["steps_run"] * counts["lanes"]
     elif case.startswith("check_k"):
         assert counts["steps_run"] == int(case[-1])
+    job_lanes = ()
+    if exp.engine.narrow_tail:
+        split = split_steps(jnp.asarray(mask[0]))
+        job_lanes = np.asarray(split.job_lanes[:int(split.n_jobs)])
+        assert len(job_lanes) == {"heavy_tail": 1, "empty_client": 1,
+                                  "solo_lane": 1, "two_tails": 1,
+                                  "two_jobs": 2}.get(case, 0)
+        if case == "solo_lane":
+            assert counts["steps_wide"] == 0
+            assert counts["lane_steps_narrow"] == counts["lane_steps_real"]
+        elif case == "two_tails":   # the full-width loop reaches the last epoch
+            assert counts["steps_wide"] > counts["steps_plan"] - 2 * STEP_CHUNK
+    else:
+        assert counts["lane_steps_narrow"] == 0
+        assert counts["steps_wide"] == -(-counts["steps_run"]
+                                         // STEP_CHUNK) * STEP_CHUNK
     train, ref_train = out["exp"][0], out["ref"][0]
     # something was trained, and tracked per batch, in every case
     assert float(jnp.max(train.delta_norms)) > 0
@@ -218,8 +277,8 @@ def test_round_is_bit_equal_to_the_full_length_loop(pair, case):
     # deltas, FoolsGold sums and feature, ClientMetrics, delta norms,
     # batch_loss / batch_dist; then the new global state, FoolsGold memory
     # and (heavy_tail) the payload the host fetches
-    _assert_trees_bit_equal(train, ref_train)
-    _assert_trees_bit_equal(out["exp"][1], out["ref"][1])
+    _assert_trees_bit_equal(train, ref_train, job_lanes)
+    _assert_trees_bit_equal(out["exp"][1], out["ref"][1], job_lanes)
 
 
 def _eqns(jaxpr, primitive):
@@ -230,58 +289,99 @@ def _eqns(jaxpr, primitive):
             yield from _eqns(sub, primitive)
 
 
-def test_train_phase_is_one_while_with_an_unbatched_predicate(pair):
-    exp, _ = pair
-    tasks_seq, idx_seq, mask_seq, ns, lane, _ = _feed(exp, "heavy_tail")
-    jaxpr = jax.make_jaxpr(exp.engine.train_fn)(
-        exp.global_vars, tasks_seq, idx_seq, mask_seq, lane,
-        jax.random.key(0)).jaxpr
-    loops = list(_eqns(jaxpr, "while"))
-    assert len(loops) == 1
-    cond = loops[0].params["cond_jaxpr"].jaxpr
-    # `j < n_chunks` on scalars: a predicate some lane batched would read [C]
-    # values and reduce them, and the body would select every carry by it
+def _assert_scalar_lt(loop):
+    """`j < n` on scalars: a predicate some lane batched would read [C]
+    values and reduce them, and the body would select every carry by it."""
+    cond = loop.params["cond_jaxpr"].jaxpr
     assert [e.primitive.name for e in cond.eqns] == ["lt"]
     lt, = cond.eqns
     assert all(v.aval.shape == () for v in lt.invars + lt.outvars)
+
+
+def _carried(loop):
+    return [v.aval.shape for v in loop.params["body_jaxpr"].jaxpr.outvars]
+
+
+def test_train_phase_is_a_wide_while_then_a_width_1_job_loop(pair):
+    exp, _ = pair
+    tasks_seq, idx_seq, mask_seq, ns, lane, _ = _feed(exp, "heavy_tail")
+    key = jax.random.key(0)
+    jaxpr = jax.make_jaxpr(exp.engine.train_fn)(
+        exp.global_vars, tasks_seq, idx_seq, mask_seq, lane, key).jaxpr
+    loops = list(_eqns(jaxpr, "while"))
     C = idx_seq.shape[1]
-    body = loops[0].params["body_jaxpr"].jaxpr
-    carried = [v.aval.shape for v in body.outvars]
-    assert carried[0] == () and any(s[:1] == (C,) for s in carried)
-    # inside: one loop of static length, the chunk; no other loop anywhere
-    chunk, = _eqns(body, "scan")
+    # first the full-width loop: a C-wide carry around one loop of static
+    # length, the chunk
+    wide = loops[0]
+    _assert_scalar_lt(wide)
+    lanes_carry = [s for s in _carried(wide) if s[:1] == (C,)]
+    assert _carried(wide)[0] == () and lanes_carry
+    chunk, = _eqns(wide.params["body_jaxpr"].jaxpr, "scan")
     assert chunk.params["length"] == STEP_CHUNK
-    assert len(list(_eqns(jaxpr, "scan"))) == 1
-    # and it is the only `while` of the whole round program (`local_eval`
+    if exp.mesh is None:
+        # then the job loop: it carries the same stack, and its one inner
+        # `while` (a lane's chunks) carries one lane's row of it around
+        # the same static chunk: no C-wide step in a job
+        wide_, jobs, lane_chunks = loops
+        assert wide_ is wide
+        _assert_scalar_lt(jobs)
+        assert ([s for s in _carried(jobs) if s[:1] == (C,)] == lanes_carry)
+        inner, = _eqns(jobs.params["body_jaxpr"].jaxpr, "while")
+        assert inner is lane_chunks
+        _assert_scalar_lt(lane_chunks)
+        assert (sorted(s for s in _carried(lane_chunks) if s)
+                == sorted(s[1:] for s in lanes_carry))
+        chunk, = _eqns(lane_chunks.params["body_jaxpr"].jaxpr, "scan")
+        assert chunk.params["length"] == STEP_CHUNK
+        # one lane alone builds no job loop (sequential_debug's calls)
+        one = jax.tree_util.tree_map(lambda l: l[:, :1],
+                                     (tasks_seq, idx_seq, mask_seq))
+        loops_1 = list(_eqns(jax.make_jaxpr(exp.engine.train_fn)(
+            exp.global_vars, *one, lane[:1], key).jaxpr, "while"))
+        assert len(loops_1) == 1
+    else:
+        assert not exp.engine.narrow_tail and len(loops) == 1
+    assert len(list(_eqns(jaxpr, "scan"))) == (2 if exp.mesh is None else 1)
+    # and they are the only `while`s of the whole round program (`local_eval`
     # is off here: the local battery's job loop is the other one,
     # tests/test_local_battery_jobs.py)
-    key = jax.random.key(0)
     round_jaxpr = jax.make_jaxpr(exp.engine.round_fn)(
         exp.global_vars, exp.fg_state, tasks_seq, idx_seq, mask_seq, lane,
         ns, key, key).jaxpr
-    assert len(list(_eqns(round_jaxpr, "while"))) == 1
+    assert len(list(_eqns(round_jaxpr, "while"))) == len(loops)
 
 
 def test_one_program_for_every_trip_count_and_the_host_counts_it(pair):
-    """Rounds of different n_run share one compiled round program, and the
-    host's `steps_run` (the `round/plan` span's counts), in chunks, is the
-    trip count the program reads from the same mask."""
+    """Rounds of different trip counts — chunks of the full-width loop, jobs,
+    a job's chunks — share one compiled round program, and the host's counts
+    (the `round/plan` span's) are what the program reads from the same
+    mask."""
     exp, _ = pair
     rf = exp.engine.round_fn
+    tail = exp.engine.narrow_tail
     rng_t, rng_a = jax.random.split(jax.random.key(3))
-    trip_counts = set()
+    trip_counts, job_counts = set(), set()
     # (the mesh's steps are slow on virtual devices: two trip counts there)
     for case in CASES if exp.mesh is None else ("heavy_tail", "check_k1"):
         tasks_seq, idx_seq, mask_seq, ns, lane, mask = _feed(exp, case)
         jax.block_until_ready(rf(exp.global_vars, exp.fg_state, tasks_seq,
                                  idx_seq, mask_seq, lane, ns, rng_t, rng_a))
         order, n_chunks = active_steps(mask_seq[0])
-        n_run = plan_step_counts([mask[0]])["steps_run"]
+        counts = plan_step_counts([mask[0]], STEP_CHUNK, tail)
+        n_run = counts["steps_run"]
         assert int(n_chunks) == -(-n_run // STEP_CHUNK)
         active = np.flatnonzero(mask[0].any(axis=(0, 3)).reshape(-1))
         np.testing.assert_array_equal(np.asarray(order)[:n_run], active)
+        if tail:
+            split = split_steps(mask_seq[0])
+            np.testing.assert_array_equal(split.order, order)
+            n_chunks = split.n_wide
+            assert int(jnp.sum(split.n_tail)) == counts["lane_steps_narrow"]
+            job_counts.add(int(split.n_jobs))
+        assert int(n_chunks) * STEP_CHUNK == counts["steps_wide"]
         trip_counts.add(int(n_chunks))
     assert len(trip_counts) >= 2 and 1 in trip_counts
+    assert job_counts == ({0, 1, 2} if tail else set())
     assert rf._cache_size() == 1
 
     n0 = len(tel.spans())
@@ -291,11 +391,14 @@ def test_one_program_for_every_trip_count_and_the_host_counts_it(pair):
     program_chunks = sum(int(active_steps(jnp.asarray(m))[1])
                          for m in fl.mask_list)
     assert -(-plan.counts["steps_run"] // STEP_CHUNK) == program_chunks
-    steps = plan_step_counts(fl.mask_list)
+    steps = plan_step_counts(fl.mask_list, STEP_CHUNK, tail)
     assert {k: plan.counts[k] for k in steps} == steps
     assert 0 < plan.counts["steps_run"] <= plan.counts["steps_plan"]
     assert (plan.counts["lane_steps_real"]
             <= plan.counts["steps_run"] * plan.counts["lanes"])
+    assert (plan.counts["lane_steps_real"] <= plan.counts["lane_steps_narrow"]
+            + plan.counts["steps_wide"] * plan.counts["lanes"])
+    assert (plan.counts["lane_steps_narrow"] > 0) == tail
     assert rf._cache_size() + (
         exp.engine.round_fn_donated._cache_size()
         if exp.engine.round_fn_donated is not None else 0) == 1
@@ -305,10 +408,116 @@ def test_plan_step_counts_by_hand():
     m = np.zeros((3, 2, 4, 5), bool)     # C=3, E=2, S=4, B=5
     m[0, :, :3, 0] = True                # lane 0: 3 steps in both epochs
     m[1, 0, :1, :2] = True               # lane 1: 1 step of epoch 0
-    assert plan_step_counts([m]) == {"steps_plan": 8, "steps_run": 6,
-                                     "lane_steps_real": 7, "lanes": 3}
-    assert plan_step_counts([m, np.zeros_like(m)]) == {
-        "steps_plan": 16, "steps_run": 6, "lane_steps_real": 7, "lanes": 3}
+    plan = {"steps_plan": 8, "steps_run": 6, "lane_steps_real": 7, "lanes": 3}
+    # one full-width loop: six steps, in chunks of four
+    assert plan_step_counts([m], 4, False) == dict(
+        plan, steps_wide=8, lane_steps_narrow=0)
+    # two loops: the lanes share step 0 alone, so the full-width loop runs
+    # one chunk (lane 0's steps 1, 2 and 4 with it) and lane 0 two steps
+    assert plan_step_counts([m], 4, True) == dict(
+        plan, steps_wide=4, lane_steps_narrow=2)
+    assert plan_step_counts([m, np.zeros_like(m)], 4, True) == dict(
+        plan, steps_plan=16, steps_wide=4, lane_steps_narrow=2)
     order, n_chunks = active_steps(jnp.asarray(m))
-    assert int(n_chunks) == 2   # six steps, in chunks of four
+    assert int(n_chunks) == 2
     assert list(np.asarray(order)) == [0, 1, 2, 4, 5, 6, 3, 7]
+    split = split_steps(jnp.asarray(m))
+    assert (int(split.n_wide), int(split.n_jobs)) == (1, 1)
+    assert list(np.asarray(split.n_tail)) == [2, 0, 0]
+    assert int(split.job_lanes[0]) == 0
+    assert list(np.asarray(split.lane_order[0, :2])) == [5, 6]
+
+
+def _lanes_mask(steps_by_lane, E=4, S=5):
+    """[C, E, S, 1] from, a lane, the steps it holds in each of its epochs."""
+    m = np.zeros((len(steps_by_lane), E, S, 1), bool)
+    for c, per_epoch in enumerate(steps_by_lane):
+        for e, n in enumerate(per_epoch):
+            m[c, e, :n] = True
+    return m
+
+
+@pytest.mark.parametrize("name, steps_by_lane, want", [
+    # (steps_wide, lane_steps_narrow, the jobs as {lane: its step ids})
+    ("all_masked", [[], []], (0, 0, {})),
+    ("equal_split", [[2, 2], [2, 2], [2, 2]], (4, 0, {})),
+    ("one_lane_of_two", [[3, 3, 3], []], (0, 9, {0: [0, 1, 2, 5, 6, 7,
+                                                     10, 11, 12]})),
+    # positions 0-4 are shared (ids 0, 1, 5, 6, 10): the boundary rounds up
+    # to 8 and takes lane 0's ids 11, 12, 13 with it; 14 is left
+    ("rounding_takes_tail_steps", [[2, 2, 5], [2, 2, 1]],
+     (8, 1, {0: [14]})),
+    # five shared positions and nothing after them: the last chunk runs
+    # three positions past the steps that run
+    ("rounding_past_the_end", [[1, 1, 1], [1, 1, 1], [2, 2]], (8, 0, {})),
+    # lane 1 outlasts by steps of the last shared epoch, lane 0 by epochs
+    ("two_jobs", [[1, 1, 1], [1, 5]], (4, 3, {0: [10], 1: [8, 9]})),
+    # a tail whose steps two lanes hold in turn, never together
+    ("interleaved", [[1, 0, 4, 0], [1, 4, 0, 4]],
+     (4, 9, {0: [10, 11, 12, 13], 1: [8, 15, 16, 17, 18]})),
+])
+def test_split_rule_by_hand(name, steps_by_lane, want):
+    """fl/client.py::split_steps (what the program reads) against
+    data/batching.py::plan_step_counts (what the host counts) and against
+    the boundary and the jobs worked out by hand; and every real lane-step
+    runs exactly once, in its lane's own order."""
+    m = _lanes_mask(steps_by_lane)
+    C = m.shape[0]
+    split = jax.tree_util.tree_map(np.asarray, split_steps(jnp.asarray(m)))
+    counts = plan_step_counts([m], STEP_CHUNK, True)
+    n_wide = int(split.n_wide) * STEP_CHUNK
+    jobs = {int(c): list(split.lane_order[c, :split.n_tail[c]])
+            for c in split.job_lanes[:split.n_jobs]}
+    assert (n_wide, int(split.n_tail.sum()), jobs) == want
+    assert (counts["steps_wide"], counts["lane_steps_narrow"]) == want[:2]
+    assert sorted(jobs) == list(np.flatnonzero(split.n_tail))
+    real = m.any(axis=-1).reshape(C, -1)
+    ran = np.zeros(real.shape, int)
+    wide_ids = split.order[:min(n_wide, real.shape[1])]
+    ran[:, wide_ids] += real[:, wide_ids]
+    for c, ids in jobs.items():
+        assert ids == sorted(ids)
+        ran[c, ids] += 1
+    np.testing.assert_array_equal(ran, real)
+    # without the job loop the full-width loop runs every step that runs
+    flat = plan_step_counts([m], STEP_CHUNK, False)
+    assert flat["lane_steps_narrow"] == 0
+    assert flat["steps_wide"] == -(-flat["steps_run"] // STEP_CHUNK) * STEP_CHUNK
+
+
+@pytest.mark.parametrize("records", ["counted", "uncounted", "bare", "none"])
+@pytest.mark.parametrize("name", ["train_narrow_steps_pct",
+                                  "train_slot_fill_pct"])
+def test_two_loop_readers(name, records):
+    """chipbench/metrics/train_narrow_steps_pct.py and train_slot_fill_pct.py,
+    found by name as the harness finds them: sums over the window's rounds;
+    nothing (not zero, no exception) from a program whose plan spans do not
+    count the two loops."""
+    import json
+    from chipbench import run as harness
+    from chipbench import selfcheck_steps as sc
+    bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+    (m, mod), = [(m, mod) for m, mod in harness.load_readers(
+        bench, "tiny_dba_attack") if m["name"] == name]
+    assert (mod.LAYER, mod.UNIT, mod.MOVES) == (m["layer"], m["unit"],
+                                                m["moves"])
+    if records == "counted":
+        # a check round of set-up (not read), then window rounds 1-3 of the
+        # cell: two clean rounds and the one with adversary 0's 224-step tail
+        made = [sc.Span("round/plan", i, i + 1, None, i, {
+            "steps_plan": 370, "steps_run": run, "lane_steps_real": real,
+            "lanes": 10, "steps_wide": wide, "lane_steps_narrow": narrow})
+            for i, (run, real, wide, narrow) in enumerate(
+                ((1, 10, 4, 0), (48, 330, 44, 5), (56, 368, 56, 0),
+                 (280, 534, 56, 224)))]
+        want = {"train_narrow_steps_pct": 100 * 229 / 1232,
+                "train_slot_fill_pct": 100 * 1232 / (1560 + 229)}[name]
+        assert mod.read(sc.context(made, 3)) == pytest.approx(want)
+    elif records == "uncounted":   # the parent's records: one loop's counts
+        assert mod.read(sc.context(sc.synthetic_records(), 3)) is None
+    elif records == "bare":
+        bare = [sc.BareSpan(*r[:5]) for r in sc.synthetic_records()]
+        assert mod.read(sc.context(bare, 3)) is None
+    else:
+        assert mod.read(sc.context(None, 0)) is None
+        assert mod.read(sc.context([], 3)) is None

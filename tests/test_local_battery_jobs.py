@@ -290,17 +290,18 @@ def test_one_program_runs_zero_jobs_or_the_jobs_the_host_counts(pair):
     jaxpr = jax.make_jaxpr(exp.engine.round_fn)(
         exp.global_vars, exp.fg_state, tasks_seq, idx_seq, mask_seq, lane,
         ns, key, key).jaxpr
-    # the train loop's `while` (tests/test_client_step_trip_count.py) and
-    # the job loop's, both on scalars: `i < n`
+    # the train phase's three `while`s (the full-width loop, its job loop
+    # and a job's chunks: tests/test_client_step_trip_count.py), then the
+    # battery's job loop, all on scalars: `i < n`
     loops = list(_eqns(jaxpr, "while"))
-    assert len(loops) == 2
+    assert len(loops) == 4
     for loop in loops:
         cond = loop.params["cond_jaxpr"].jaxpr
         assert [e.primitive.name for e in cond.eqns] == ["lt"]
         lt, = cond.eqns
         assert all(v.aval.shape == () for v in lt.invars + lt.outvars)
     # inside a job: one scan of static length over the poison plan
-    body = loops[1].params["body_jaxpr"].jaxpr
+    body = loops[3].params["body_jaxpr"].jaxpr
     scan, = _eqns(body, "scan")
     assert scan.params["length"] == exp.eval_plans.poison_idx.shape[0]
     C = idx_seq.shape[1]
