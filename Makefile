@@ -1,18 +1,13 @@
-# Developer/CI entry points. `make tier1` is the ROADMAP.md tier-1 verify
-# command: the fast CPU suite (slow-marked rehearsals deselected) on the
-# 8-virtual-device platform tests/conftest.py sets up.
+# Developer/CI entry points. `make tier1` is the driver's tier-1 command
+# (scripts/tier1.sh: six xdist workers, `--dist loadfile`, 1,470 s, the
+# junit count): the fast CPU suite (slow-marked rehearsals deselected) on
+# the 8-virtual-device platform tests/conftest.py sets up.
 SHELL := /bin/bash
 .PHONY: tier1 test-slow trace crash-smoke elastic-smoke forensics-smoke \
   async-smoke chaos-soak chaos-smoke overlap-smoke
 
 tier1:
-	set -o pipefail; rm -f /tmp/_t1.log; \
-	timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-	  -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-	  -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; \
-	rc=$${PIPESTATUS[0]}; \
-	echo DOTS_PASSED=$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c); \
-	exit $$rc
+	bash scripts/tier1.sh
 
 test-slow:
 	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m slow \

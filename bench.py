@@ -50,15 +50,14 @@ BENCH_CONFIG = dict(
     # TPU-native settings (all semantics-preserving; see config.py):
     # bf16 MXU compute (f32 params/aggregation — backdoor efficacy validated
     # in tests/test_fl_integration.py); fat eval batches (eval sums are
-    # batch-size invariant); per-round step buckets (padding steps are
-    # fully-masked no-ops); round pipelining (recording lags one round);
+    # batch-size invariant); round pipelining (recording lags one round);
     # overlap_eval splits the fused round so round N's eval batteries +
     # host sync run behind round N+1's train/aggregate dispatch — recorded
     # metrics stay bit-identical (tests/test_overlap.py), only the
     # schedule changes. The headline measures the knob ON; the JSON's
     # "overlap" sub-object carries the off/on A/B on the same workload.
     compute_dtype="bfloat16", eval_batch_size=2048,
-    dynamic_steps=True, pipeline_rounds=True, overlap_eval=True)
+    pipeline_rounds=True, overlap_eval=True)
 
 
 # --poison-cost lane (VERDICT Weak #5): the SAME headline workload with the
@@ -216,15 +215,14 @@ def measure_multihost(timed_rounds: int) -> dict:
 
 def _make_experiment(config=None):
     import jax
-    # persistent compile cache: the 5 step-bucket shapes + eval programs
-    # compile once per machine, not once per bench run
+    # persistent compile cache: the round + eval programs compile once per
+    # machine, not once per bench run
     from dba_mod_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
     from dba_mod_tpu.config import Params
     from dba_mod_tpu.fl.experiment import Experiment
     exp = Experiment(Params.from_dict(config or BENCH_CONFIG),
                      save_results=False)
-    exp.warm_step_buckets()   # compile every dynamic-steps shape up front
     exp.run_round(1)          # compile eval/aggregate programs
     exp.telemetry.mark_warm()  # further XLA compiles are regressions
     return exp
@@ -530,9 +528,7 @@ def main() -> int:
                     / PEAK_BF16, 4),
                 "peak_bf16_flops": PEAK_BF16,
                 "note": "useful-work FLOPs (padding excluded) / phase "
-                        "device-time; phase times at the STATIC plan shape "
-                        "(worst case), headline rounds/sec uses dynamic "
-                        "buckets"}
+                        "device-time; phase times at the STATIC plan shape"}
         except Exception as e:  # noqa: BLE001 — diagnostics must not
             out["phases_error"] = str(e)  # break the headline number
 

@@ -58,6 +58,7 @@ gaps; tests/test_parity_ab.py asserts the tolerances in CI.
 from __future__ import annotations
 
 import collections
+import functools
 from typing import Dict, List
 
 import numpy as np
@@ -125,9 +126,10 @@ def _torch_block_cls():
     return Block
 
 
-def build_torch_cifar():
+def build_torch_cifar(widths=(32, 64, 128, 256)):
     """Reference narrow CIFAR ResNet-18 (models/resnet_cifar.py:70-116):
-    3×3 stem, widths 32/64/128/256, BasicBlock [2,2,2,2], 4×4 avg pool."""
+    3×3 stem, widths 32/64/128/256, BasicBlock [2,2,2,2], 4×4 avg pool.
+    `widths` is the tests' alone (tests/conftest.py::narrow_resnets)."""
     import torch
     import torch.nn as nn
     import torch.nn.functional as F
@@ -137,23 +139,23 @@ def build_torch_cifar():
     class Net(nn.Module):
         def __init__(self):
             super().__init__()
-            self.stem_conv = nn.Conv2d(3, 32, 3, 1, 1, bias=False)
-            self.stem_bn = nn.BatchNorm2d(32)
+            self.stem_conv = nn.Conv2d(3, widths[0], 3, 1, 1, bias=False)
+            self.stem_bn = nn.BatchNorm2d(widths[0])
             blocks = []
-            in_p = 32
-            for stage, p in enumerate([32, 64, 128, 256]):
+            in_p = widths[0]
+            for stage, p in enumerate(widths):
                 for i in range(2):
                     stride = 2 if (stage > 0 and i == 0) else 1
                     blocks.append(Block(in_p, p, stride))
                     in_p = p
             self.blocks = nn.ModuleList(blocks)
-            self.fc = nn.Linear(256, 10)
+            self.fc = nn.Linear(widths[-1], 10)
 
         def forward(self, x):
             x = F.relu(self.stem_bn(self.stem_conv(x)))
             for b in self.blocks:
                 x = b(x)
-            x = F.avg_pool2d(x, 4).view(-1, 256)
+            x = F.avg_pool2d(x, 4).view(-1, widths[-1])
             return self.fc(x)
 
     return Net()
@@ -217,12 +219,13 @@ def cifar_state_to_torch(mv) -> Dict[str, np.ndarray]:
     return out
 
 
-def build_torch_tiny():
+def build_torch_tiny(widths=(64, 128, 256, 512)):
     """Reference Tiny-ImageNet ResNet-18 (models/resnet_tinyimagenet.py:40-238):
     torchvision-style — 7×7/stride-2 stem, 3×3/stride-2 max pool, standard
     64/128/256/512 BasicBlock [2,2,2,2], global average pool, 200-class head.
     Reuses the CIFAR twin's Block; module names mirror the flax tree so
-    `cifar_state_to_torch` maps both variants."""
+    `cifar_state_to_torch` maps both variants. `widths` as in
+    `build_torch_cifar`."""
     import torch
     import torch.nn as nn
     import torch.nn.functional as F
@@ -232,17 +235,17 @@ def build_torch_tiny():
     class Net(nn.Module):
         def __init__(self):
             super().__init__()
-            self.stem_conv = nn.Conv2d(3, 64, 7, 2, 3, bias=False)
-            self.stem_bn = nn.BatchNorm2d(64)
+            self.stem_conv = nn.Conv2d(3, widths[0], 7, 2, 3, bias=False)
+            self.stem_bn = nn.BatchNorm2d(widths[0])
             blocks = []
-            in_p = 64
-            for stage, p in enumerate([64, 128, 256, 512]):
+            in_p = widths[0]
+            for stage, p in enumerate(widths):
                 for i in range(2):
                     stride = 2 if (stage > 0 and i == 0) else 1
                     blocks.append(Block(in_p, p, stride))
                     in_p = p
             self.blocks = nn.ModuleList(blocks)
-            self.fc = nn.Linear(512, 200)
+            self.fc = nn.Linear(widths[-1], 200)
 
         def forward(self, x):
             x = F.relu(self.stem_bn(self.stem_conv(x)))
@@ -891,8 +894,10 @@ def build_round_plans(exp, params, agent_names, seg_epochs):
     return tasks_list, np.stack(idx_list), np.stack(mask_list), num_samples
 
 
-def run_ab(overrides: dict, n_rounds: int) -> dict:
-    """Run n_rounds through both frameworks; return the comparison report."""
+def run_ab(overrides: dict, n_rounds: int, widths=None) -> dict:
+    """Run n_rounds through both frameworks; return the comparison report.
+    `widths` builds the torch ResNet twin at other widths than the published
+    ones — for a test that has narrowed the flax side the same way."""
     import jax
     import jax.numpy as jnp
 
@@ -905,6 +910,8 @@ def run_ab(overrides: dict, n_rounds: int) -> dict:
     params = Params.from_dict(overrides)
     exp = Experiment(params, save_results=False)
     ctor, to_torch = CONVERTERS[params.type]
+    if widths is not None:
+        ctor = functools.partial(ctor, widths=tuple(widths))
     data = exp.image_data
     h, w = data.train_images.shape[1:3]
     bank = build_pixel_pattern_bank(params, h, w)

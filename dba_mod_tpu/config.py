@@ -43,6 +43,8 @@ AGGR_ALL = (AGGR_MEAN, AGGR_GEO_MED, AGGR_FOOLSGOLD, AGGR_KRUM,
 
 _REQUIRED_KEYS = ("type", "lr", "batch_size", "epochs", "no_models",
                   "number_of_total_participants", "eta", "aggregation_methods")
+# forks deleted with their code (PR 29); from_dict refuses them by name
+_REMOVED_KEYS = ("grouped_clients", "dynamic_steps")
 
 _DEFAULTS: Dict[str, Any] = {
     "test_batch_size": 64,
@@ -139,11 +141,6 @@ _DEFAULTS: Dict[str, Any] = {
                                    # n > 1 = the first n
     "run_dir": "./runs",
     "checkpoint_dir": "saved_models",  # root for resume/pretrain checkpoints
-    "dynamic_steps": False,        # size each round's batch plan to the
-                                   # round's own max client (bucketed to limit
-                                   # recompiles) instead of the global max;
-                                   # identical numerics (padding steps are
-                                   # fully-masked no-ops)
     "pipeline_rounds": False,      # overlap round N's host fetch with round
                                    # N+1's device compute in Experiment.run
     "overlap_eval": False,         # split the fused round program and overlap
@@ -159,10 +156,6 @@ _DEFAULTS: Dict[str, Any] = {
                                    # auto = on for unsharded TPU runs
     "fused_interpret": False,      # run the fused kernels in pallas
                                    # interpret mode (CPU testing)
-    "grouped_clients": False,      # grouped-layout client execution
-                                   # (models/grouped.py); measured
-                                   # perf-neutral vs the vmapped path —
-                                   # TRAIN_FLOOR.md round-5 section
     # --- wider defense grid (ops/aggregation.py; ROADMAP item 3) ---
     "krum_m": 1,                   # multi-Krum selection count (1 = classic
                                    # Krum): the m lowest-scoring clients are
@@ -359,6 +352,13 @@ class Params:
     def from_dict(cls, raw: Dict[str, Any]) -> "Params":
         merged = copy.deepcopy(_DEFAULTS)
         merged.update(raw or {})
+        removed = [k for k in _REMOVED_KEYS if k in merged]
+        if removed:
+            # unknown keys pass through silently, so an old YAML naming a
+            # deleted fork would otherwise run the default path without a word
+            raise ValueError(
+                f"config names removed options {removed}: the code paths "
+                "are gone (CHANGES.md PR 29); delete the keys")
         missing = [k for k in _REQUIRED_KEYS if k not in merged]
         if missing:
             raise ValueError(f"config missing required keys: {missing}")

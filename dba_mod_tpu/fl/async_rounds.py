@@ -641,18 +641,10 @@ class AsyncDriver:
                              np.int64)
             tasks = build_client_tasks(p, agent_names, epoch, slots,
                                        exp.epochs_max, backdoor_acc)
-            if exp.dynamic_steps:
-                b = int(p["batch_size"])
-                round_max = max((len(exp.client_indices[n])
-                                 for n in agent_names), default=1)
-                min_steps = exp._bucket_steps(
-                    max(1, int(np.ceil(round_max / b))))
-            else:
-                min_steps = exp.steps_per_epoch
             plan = build_batch_plan(
                 [exp.client_indices[n] for n in agent_names],
                 [int(e) for e in tasks.num_epochs], int(p["batch_size"]),
-                exp.plan_rng, min_steps=min_steps,
+                exp.plan_rng, min_steps=exp.steps_per_epoch,
                 min_epochs=exp.epochs_max)
             tasks_seq = jax.tree_util.tree_map(
                 lambda l: jnp.asarray(l[None]), tasks)

@@ -196,9 +196,8 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
             x, y, sel = data.stamp(x, y, task.adv_index,
                                    task.poisoning_per_batch)
             # derive from (epoch, step-within-epoch), NOT the flat index:
-            # the flat index depends on the plan width S, and dynamic_steps
-            # (experiment.py) shrinks S per round — dropout streams must not
-            # change with the padding
+            # the flat index depends on the plan width S — dropout streams
+            # must not change with the padding
             step_rng = jax.random.fold_in(
                 jax.random.fold_in(rng, e), step_i - e * S)
 

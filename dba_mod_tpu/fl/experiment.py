@@ -411,17 +411,6 @@ class Experiment:
         self.stale_poison_probe = bool(params.get("stale_poison_probe",
                                                   False))
         self.last_backdoor_acc: Optional[float] = None
-        # Per-round step-count bucketing: the static plan pads every client to
-        # the GLOBAL max client size; a round of 10 sampled clients usually
-        # needs far fewer steps. dynamic_steps sizes the plan to the round's
-        # own max, quantized to multiples of _STEP_BUCKET so the jitted round
-        # compiles a handful of shapes instead of one-per-round. Identical
-        # numerics: dropped steps were fully-masked no-ops
-        # (tests/test_fl_integration.py) — which the client step's loop no
-        # longer runs at any plan width (fl/client.py::active_steps), so the
-        # knob buys nothing now and is queued for removal (ROADMAP D5).
-        self.dynamic_steps = bool(params.get("dynamic_steps", False))
-        self._warmed_buckets: set = set()
         self._apply_resume_aux()
 
     def _apply_resume_aux(self):
@@ -559,75 +548,6 @@ class Experiment:
             poison_mask=jnp.asarray(plan.mask))
 
     # ----------------------------------------------------------------- round
-    _STEP_BUCKET = 2       # quantum of the per-round step-count buckets
-    _STEP_BUCKET_MIN = 8   # floor: tiny rounds share one shape
-
-    def _bucket_steps(self, s: int) -> int:
-        b = self._STEP_BUCKET
-        s = max(((s + b - 1) // b) * b, self._STEP_BUCKET_MIN)
-        return min(s, max(self.steps_per_epoch, 1))
-
-    def warm_step_buckets(self) -> List[int]:
-        """Pre-compile the round program for every step bucket (all-masked
-        zero plans → the compile is shape-driven only). Keeps dynamic_steps
-        rounds from hitting a fresh XLA compile mid-run."""
-        if not self.dynamic_steps:
-            return []
-        buckets = sorted({self._bucket_steps(s) for s in
-                          range(1, self.steps_per_epoch + 1)})
-        names = self.participants[:int(self.params["no_models"])]
-        slots = np.array([self.client_slots[n] for n in names], np.int64)
-        tasks = build_client_tasks(self.params, names, 1, slots,
-                                   self.epochs_max, None)
-        C, E, B = len(names), self.epochs_max, int(self.params["batch_size"])
-        if self.mesh is not None:
-            # match dispatch_round's inert-client padding, or the warm
-            # shapes won't be the shapes real rounds compile
-            from dba_mod_tpu.parallel.mesh import pad_clients
-            c_pad = pad_clients(C, self.mesh)
-            if c_pad != C:
-                tasks = _pad_tasks(tasks, c_pad - C, self.params.aggregation)
-                C = c_pad
-        I = self.interval  # real rounds stack one segment per interval epoch
-        tasks_stacked = jax.tree_util.tree_map(
-            lambda l: jnp.asarray(np.stack([l] * I)), tasks)
-        lane = jnp.arange(C, dtype=jnp.int32)
-        rng_t, rng_a = jax.random.split(jax.random.key(0))
-        robust_args = self._robust_round_args(1, C)
-        for s in buckets:
-            idx = jnp.zeros((I, C, E, s, B), jnp.int32)
-            mask = jnp.zeros((I, C, E, s, B), bool)
-            ns = jnp.zeros((C,), jnp.float32)
-            tasks_seq = tasks_stacked
-            if self.mesh is not None:
-                # identical placement to dispatch_round — the warm shapes
-                # AND shardings must be the ones real rounds compile
-                from dba_mod_tpu.parallel.mesh import shard_round_inputs
-                tasks_seq, idx, mask, ns = shard_round_inputs(
-                    self.mesh, tasks_seq, idx, mask, ns)
-            # warm the program real rounds run: the fused round, or the
-            # overlap scheduler's round core. The donated twin is warmed on
-            # COPIES: donation
-            # consumes the input buffers, and these are the live
-            # model/defense state. A compile failure propagates.
-            if self._overlap and not self.sequential_debug:
-                self.engine.core_fn(self.global_vars, self.fg_state,
-                                    tasks_seq, idx, mask, lane, ns,
-                                    rng_t, rng_a, *robust_args)
-            elif self._use_donated_round:
-                gv = jax.tree_util.tree_map(lambda x: x.copy(),
-                                            self.global_vars)
-                fg = jax.tree_util.tree_map(lambda x: x.copy(),
-                                            self.fg_state)
-                self.engine.round_fn_donated(
-                    gv, fg, tasks_seq, idx, mask, lane, ns, rng_t, rng_a)
-            else:
-                self.engine.round_fn(self.global_vars, self.fg_state,
-                                     tasks_seq, idx, mask, lane, ns,
-                                     rng_t, rng_a, *robust_args)
-            self._warmed_buckets.add(s)
-        return buckets
-
     def build_static_round_inputs(self, epoch: int):
         """Device-ready train_fn inputs at the STATIC plan shape — for
         diagnostics that call the engine directly (bench.py's phase probe).
@@ -723,22 +643,6 @@ class Experiment:
             # (image_train.py:50: the local model trains continuously across
             # the interval; the server applies the summed update once)
             seg_epochs = list(range(epoch, epoch + self.interval))
-            if self.dynamic_steps:
-                b = int(params["batch_size"])
-                round_max = max((len(self.client_indices[n])
-                                 for n in agent_names), default=1)
-                min_steps = self._bucket_steps(
-                    max(1, int(np.ceil(round_max / b))))
-                if (self._warmed_buckets
-                        and min_steps not in self._warmed_buckets):
-                    # warm shapes drifting from real round shapes is exactly
-                    # the failure warm_step_buckets exists to prevent
-                    logger.warning(
-                        "dispatch_round: step bucket S=%d was not pre-warmed "
-                        "(warmed: %s); this round pays a fresh XLA compile",
-                        min_steps, sorted(self._warmed_buckets))
-            else:
-                min_steps = self.steps_per_epoch
             tasks_list, idx_list, mask_list = [], [], []
             num_samples_np = None
             for ep in seg_epochs:
@@ -748,7 +652,8 @@ class Experiment:
                     [self.client_indices[n] for n in agent_names],
                     [int(e) for e in tasks_s.num_epochs],
                     int(params["batch_size"]), self.plan_rng,
-                    min_steps=min_steps, min_epochs=self.epochs_max)
+                    min_steps=self.steps_per_epoch,
+                    min_epochs=self.epochs_max)
                 if num_samples_np is None:
                     num_samples_np = plan.num_samples.astype(np.float32)
                 tasks_list.append(tasks_s)
@@ -1730,13 +1635,6 @@ class Experiment:
         last: Dict[str, Any] = {}
         end = epochs if epochs is not None else int(self.params["epochs"])
         profile_dir = str(self.params.get("profile_dir", "") or "")
-        if self.telemetry.enabled and not self.sequential_debug:
-            # compile every dynamic-steps bucket up front: mark_warm() fires
-            # after the first full round, and a later round landing in a
-            # fresh bucket would otherwise count its legitimate first
-            # compile as a retrace regression
-            with telemetry.span("engine/warm_buckets"):
-                self.warm_step_buckets()
         # pipeline_rounds: overlap round N's host fetch/record with round
         # N+1's device compute (depth 1). Checkpoints ride orbax async saves
         # — save_model(fl=...) uses the state captured at dispatch, and

@@ -422,27 +422,6 @@ class RoundEngine:
             model_def, data, hyper, fg_enabled, fused_pallas=fused_pallas,
             fused_interpret=self.fused_interpret,
             narrow_tail=self.narrow_tail)
-        # grouped-layout client execution (models/grouped.py): holds the
-        # grouped layout vmap's conv batching re-derives per conv. The one
-        # A/B on record (benchmarks/grouped_ab.py, TRAIN_FLOOR.md round-5
-        # section) was inside its noise band: the layout moves live inside
-        # XLA's grouped-conv lowering, not in the vmap program. Not measured
-        # on the current installation. Kept flag-gated (default OFF:
-        # no measured win, and a second lowering to keep numerically
-        # audited); requires a BasicBlock ResNet and an unsharded clients
-        # axis (GSPMD shards the stacked axis; grouped layout folds it into
-        # features).
-        from dba_mod_tpu.models.grouped import supports_grouped
-        self.use_grouped = bool(params.get("grouped_clients", False))
-        if self.use_grouped and not (supports_grouped(model_def)
-                                     and mesh is None):
-            raise ValueError(
-                "grouped_clients=true requires a BasicBlock-ResNet "
-                "model and an unsharded clients axis")
-        if self.use_grouped:
-            from dba_mod_tpu.fl.grouped_client import make_grouped_client_step
-            segment_step = make_grouped_client_step(model_def, data, hyper,
-                                                    fg_enabled)
         eval_clean = make_eval_fn(model_def, data, poison=False)
         eval_poison = make_eval_fn(model_def, data, poison=True)
         is_poison_run = bool(params["is_poison"])

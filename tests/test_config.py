@@ -34,6 +34,17 @@ def test_unknown_aggregation_rejected():
         cfg.Params.from_dict(bad)
 
 
+@pytest.mark.parametrize("value", [True, False])
+@pytest.mark.parametrize("key", ["grouped_clients", "dynamic_steps"])
+def test_removed_options_are_refused_by_name(key, value):
+    """Unknown keys pass through `from_dict` silently, so an old YAML that
+    names a deleted fork would run the default path without a word. The key
+    is refused, not its value: `false` too names code that is gone."""
+    assert key not in cfg._DEFAULTS
+    with pytest.raises(ValueError, match=key):
+        cfg.Params.from_dict(dict(BASE, **{key: value}))
+
+
 def test_importing_experiment_initialises_no_backend():
     """jax.distributed.initialize() refuses to run once a backend exists, and
     on a chip machine the first process to initialise one owns the chip — so

@@ -63,11 +63,23 @@ def _launch(cfg_path, *extra):
         stderr=subprocess.STDOUT, text=True)
 
 
-def _run_to_completion(cfg_path, *extra, timeout=600):
-    proc = _launch(cfg_path, *extra)
-    out, _ = proc.communicate(timeout=timeout)
+# every wait on a child has a limit of its own, well inside tier-1's: a
+# leg of these tests takes 30-60 s on a loaded box (PR 29)
+LEG_TIMEOUT = 300
+
+
+def _finish(proc, timeout=LEG_TIMEOUT):
+    try:
+        out, _ = proc.communicate(timeout=timeout)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
     assert proc.returncode == 0, f"rc={proc.returncode}\n{out}"
     return out
+
+
+def _run_to_completion(cfg_path, *extra):
+    return _finish(_launch(cfg_path, *extra))
 
 
 def _rounds_recorded(run_dir: Path) -> int:
@@ -77,7 +89,8 @@ def _rounds_recorded(run_dir: Path) -> int:
     return rows
 
 
-def _wait_for_rounds(proc, run_dir: Path, n: int, timeout=300) -> int:
+def _wait_for_rounds(proc, run_dir: Path, n: int,
+                     timeout=LEG_TIMEOUT) -> int:
     """Poll until >= n data rows are committed (or the process exits)."""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
@@ -103,26 +116,31 @@ def test_kill9_then_auto_resume_bit_identical_trajectory(tmp_path):
     base_path, base_cfg = _write_cfg(tmp_path, "base")
     crash_path, crash_cfg = _write_cfg(tmp_path, "crash")
 
-    # uninterrupted reference run (same seed, separate run_dir)
-    _run_to_completion(base_path)
+    # uninterrupted reference run (same seed, separate run_dir), beside
+    # the crash run: the two share nothing but the compile cache
+    ref_proc = _launch(base_path)
+    try:
+        # crash run: SIGKILL once >= 2 rounds have committed
+        proc = _launch(crash_path)
+        run_dir = Path(crash_cfg["run_dir"])
+        done = _wait_for_rounds(proc, run_dir, 2)
+        if proc.poll() is not None:  # pragma: no cover — box far too fast
+            pytest.skip("run finished before the kill landed")
+        proc.kill()  # SIGKILL: no handlers, no cleanup, no atexit
+        proc.wait(timeout=60)
+        assert proc.returncode == -signal.SIGKILL
+        assert done >= 2
+
+        # auto-resume: same config + --resume auto must finish the job
+        out = _run_to_completion(crash_path, "--resume", "auto")
+        assert "final: epoch=8" in out
+
+        _finish(ref_proc)
+    finally:
+        if ref_proc.poll() is None:
+            ref_proc.kill()
     ref_rows = _metrics_rows(Path(base_cfg["run_dir"]))
     assert [r["epoch"] for r in ref_rows] == list(range(1, 9))
-
-    # crash run: SIGKILL once >= 2 rounds have committed
-    proc = _launch(crash_path)
-    run_dir = Path(crash_cfg["run_dir"])
-    done = _wait_for_rounds(proc, run_dir, 2)
-    if proc.poll() is not None:  # pragma: no cover — box far too fast
-        pytest.skip("run finished before the kill landed")
-    proc.kill()  # SIGKILL: no handlers, no cleanup, no atexit
-    proc.wait(timeout=60)
-    assert proc.returncode == -signal.SIGKILL
-    assert done >= 2
-
-    # auto-resume: same config + --resume auto must finish the job
-    out = _run_to_completion(crash_path, "--resume", "auto")
-    assert "final: epoch=8" in out
-
     rows = _metrics_rows(run_dir)  # one folder: the killed run's, reused
     assert [r["epoch"] for r in rows] == list(range(1, 9))  # no dup rounds
     for ref, got in zip(ref_rows, rows):

@@ -1,5 +1,7 @@
 """Model parity tests: parameter counts and output shapes match the reference
 architectures (rebuilt independently in torch from their documented structure)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -129,6 +131,70 @@ def test_similarity_param_is_final_dense_kernel(type_name, twin, in_shape, n_cla
     mv = mdef.init_vars(jax.random.key(0))
     p = mdef.similarity_param(mv.params)
     assert p.ndim == 2 and p.shape[1] == n_classes
+
+
+@pytest.mark.parametrize("type_name", ["cifar", "tiny-imagenet-200"])
+def test_narrow_fixture_keeps_the_full_models_tree(type_name, narrow_resnets):
+    """What lets a narrow test stand for the wide model: the fixture's
+    ResNet has the full model's tree paths (flax auto-names included), the
+    same BN leaves, the same stem/pool/head — only channel counts differ."""
+    import dba_mod_tpu.fl.experiment as experiment
+    import dba_mod_tpu.models as models
+    assert experiment.build_model is models.build_model is not build_model
+    full, narrow = build_model(_params(type_name)), models.build_model(
+        _params(type_name))
+    assert tuple(narrow.module.widths) == tuple(narrow_resnets)
+    assert tuple(narrow.module.widths) != tuple(full.module.widths)
+    for field in ("num_classes", "num_blocks", "bottleneck", "stem", "pool",
+                  "kernel_init", "head_init", "dtype"):
+        assert getattr(narrow.module, field) == getattr(full.module, field)
+    assert dataclasses.replace(narrow, module=full.module) == full
+    mv_f = jax.eval_shape(full.init_vars, jax.random.key(0))
+    mv_n = jax.eval_shape(narrow.init_vars, jax.random.key(0))
+    leaves_f, tree_f = jax.tree_util.tree_flatten_with_path(mv_f)
+    leaves_n, tree_n = jax.tree_util.tree_flatten_with_path(mv_n)
+    assert tree_f == tree_n          # same paths, auto-names included
+    assert jax.tree_util.tree_leaves(mv_n.batch_stats)
+    for (path, f), (_, n) in zip(leaves_f, leaves_n):
+        assert (f.ndim, f.dtype) == (n.ndim, n.dtype), path
+        # kernels keep their spatial window, the head its classes
+        assert f.shape[:-2] == n.shape[:-2], path
+    head = narrow.similarity_param(mv_n.params)
+    assert head.shape == (narrow_resnets[-1], narrow.num_classes)
+    # and it runs: train mode moves every BN leaf
+    mv = narrow.init_vars(jax.random.key(0))
+    x = jax.random.normal(jax.random.key(1), (4,) + narrow.input_shape)
+    logits, new_bn = narrow.apply(mv, x, train=True)
+    assert logits.shape == (4, narrow.num_classes)
+    assert all(bool(jnp.any(a != b)) for a, b in zip(
+        jax.tree_util.tree_leaves(new_bn),
+        jax.tree_util.tree_leaves(mv.batch_stats)))
+
+
+@pytest.mark.parametrize("type_name, twin_name, full_twin", [
+    ("cifar", "build_torch_cifar", torch_cifar_resnet18),
+    ("tiny-imagenet-200", "build_torch_tiny", torch_tiny_resnet18)])
+def test_parity_twins_narrow_with_the_fixture(type_name, twin_name, full_twin,
+                                              narrow_resnets):
+    """The cross-framework rounds (tests/test_parity_ab.py) narrow both sides
+    together: benchmarks/parity_ab.py's torch twin at the fixture's widths
+    holds what the fixture's flax model holds, and at its default widths
+    what this file's own full-width twin holds."""
+    import benchmarks.parity_ab as ab
+    import dba_mod_tpu.models as models
+
+    def sizes(tm):
+        return (sum(p.numel() for p in tm.parameters()),
+                sum(b.numel() for name, b in tm.named_buffers()
+                    if "num_batches_tracked" not in name))
+
+    build = getattr(ab, twin_name)
+    assert sizes(build()) == sizes(full_twin())
+    mv = jax.eval_shape(models.build_model(_params(type_name)).init_vars,
+                        jax.random.key(0))
+    narrow = sizes(build(widths=narrow_resnets))
+    assert narrow == (n_params(mv.params), n_params(mv.batch_stats))
+    assert narrow != sizes(build())
 
 
 def test_mnist_output_is_log_softmax():

@@ -96,7 +96,7 @@ def _fake_cifar(root, n_train=144, n_test=64):
     return np.concatenate(all_imgs), np.array(all_labels), te, np.array(te_l)
 
 
-def test_cifar_pickle_loader_and_round(tmp_path):
+def test_cifar_pickle_loader_and_round(tmp_path, narrow_resnets):
     tr, tr_y, te, te_y = _fake_cifar(tmp_path)
     data = ds.load_cifar10(str(tmp_path))
     assert data is not None
@@ -116,7 +116,7 @@ def test_cifar_pickle_loader_and_round(tmp_path):
 
 
 # -------------------------------------------------------------- Tiny-ImageNet
-def test_tiny_folder_loader_and_round(tmp_path):
+def test_tiny_folder_loader_and_round(tmp_path, narrow_resnets):
     PIL = pytest.importorskip("PIL.Image")
     rng = np.random.RandomState(2)
     root = tmp_path / "tiny-imagenet-200"

@@ -126,6 +126,9 @@ def _round_args(exp, sharding):
     return _abstract(args, sharding)
 
 
+@pytest.mark.slow  # PR 29: a ResNet round compiled with the cache off, from
+# before there was a chip; chipbench/program.py::check_engine_is_the_chips
+# now asserts on the real chip, every run, that this program is the one run
 def test_cifar_donated_round_compiles_for_v5e(one_chip, no_persistent_cache,
                                               cifar_engine):
     """The donated twin only: it is what an unsharded CLI run dispatches on
