@@ -9,8 +9,9 @@ only these small int32 plans each round. The plan's shape is static (one
 compiled round program); the client step's full-width loop runs only the
 steps in which some client's mask holds a real row (fl/client.py::
 active_steps), so a step padded in every client costs nothing, and stops
-after the last step two clients share: what one client alone still needs
-runs as a job at width 1 (fl/client.py::split_steps). `plan_step_counts`
+after the last step that `wide_from` clients share: what a client still
+needs after it runs as a job at width 1 (fl/client.py::split_steps; with
+`wide_from` above the number of clients, all of it). `plan_step_counts`
 counts all of it from the same masks.
 
 Shuffling uses per-client numpy RNG rather than the reference's global torch
@@ -77,27 +78,29 @@ def build_batch_plan(client_indices: Sequence[Sequence[int]],
 
 
 def plan_step_counts(masks: Sequence[np.ndarray], chunk: int,
-                     narrow_tail: bool) -> Dict[str, int]:
+                     wide_from: int) -> Dict[str, int]:
     """What a round's plan asks of the steps loops, from its masks (one
     [C, E, S, B] per segment). The plan: `steps_plan` the loops' static
     length over the segments (E x S each), `steps_run` the steps in which ANY
     lane holds a real batch, `lane_steps_real` the real client-steps, and
     `lanes` (C). What the program runs of it, by fl/client.py's rule in
-    numpy (`chunk` its STEP_CHUNK, `narrow_tail` whether the engine builds
-    the job loop): `steps_wide` the positions the full-width loop runs —
-    the steps that run up to the last one two or more lanes share, or all of
-    them without the job loop, rounded up to the chunk — and
+    numpy (`chunk` its STEP_CHUNK, `wide_from` the engine's: the live lanes
+    from which a step runs at full width; 1, or one lane, is the full-width
+    loop alone): `steps_wide` the positions the full-width loop runs — the
+    steps that run up to the last one at least `wide_from` lanes share,
+    rounded up to the chunk, none with `wide_from` above C — and
     `lane_steps_narrow` the real client-steps past that boundary, run one
-    lane at a time. `steps_wide x lanes + lane_steps_narrow -
-    lane_steps_real` slots still run masked: what packing lanes could win."""
+    lane at a time as jobs. `steps_wide x lanes + lane_steps_narrow -
+    lane_steps_real` slots still run masked: what packing lanes could win
+    (a job's up to `chunk - 1` padding steps are not slots of the plan)."""
     real = np.stack([np.asarray(m).any(axis=-1) for m in masks])  # [I,C,E,S]
     n_seg, lanes = real.shape[:2]
     live = real.reshape(n_seg, lanes, -1).sum(axis=1)             # [I, E*S]
-    tail = narrow_tail and lanes > 1
+    wide_from = wide_from if lanes > 1 else 1
     steps_wide = lane_steps_narrow = 0
     for seg in live:
         seg = seg[seg > 0]                # by position of the loops' order
-        last = np.flatnonzero(seg >= 2 if tail else seg > 0)
+        last = np.flatnonzero(seg >= wide_from)
         n_wide = -(-(last[-1] + 1 if len(last) else 0) // chunk) * chunk
         steps_wide += int(n_wide)
         lane_steps_narrow += int(seg[n_wide:].sum())

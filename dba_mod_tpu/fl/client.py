@@ -2,12 +2,13 @@
 by two loops whose trip counts are read from the round's mask. The
 full-width loop runs every lane (`vmap` over the clients axis) through the
 steps in which SOME lane has a real batch (`active_steps`), in their
-original order, STEP_CHUNK at a time, up to the last step that two or more
-lanes need; what is left holds at most one live lane a step (an adversary's
-extra epochs beside benign lanes), and a job loop runs it one lane at a
-time at width 1 (`split_steps`): nine masked lanes cost nine lanes' time.
-An engine with a sharded clients axis, or one lane, runs the full-width
-loop alone.
+original order, STEP_CHUNK at a time, up to the last step that at least
+`wide_from` lanes need; every real step after it is part of its lane's job,
+and a job loop runs the jobs one lane at a time at width 1 (`split_steps`).
+`wide_from` is the engine's (fl/rounds.py::wide_from_of): 2 finishes one
+lane's tail alone (an adversary's extra epochs beside benign lanes), C + 1
+makes every lane with data one job and the full-width loop runs nothing,
+1 (a sharded clients axis), or one lane, is the full-width loop alone.
 
 Capability parity with the reference client loop (image_train.py:21-315,
 loan_train.py:17-261), re-expressed as data-dependent selects so benign and
@@ -110,24 +111,28 @@ class StepSplit(NamedTuple):
     n_tail: jax.Array      # [C] how many those are
 
 
-def split_steps(mask) -> StepSplit:
+def split_steps(mask, wide_from: int) -> StepSplit:
     """mask [C, E, S, B] of one segment -> where the full-width loop stops
     and what each lane still has to run alone. The full-width loop runs the
-    positions of `order` up to and including the last one at which two or
-    more lanes hold a real batch, rounded up to STEP_CHUNK; every position
-    after that holds at most one live lane, and a lane with a real step
-    there is one job: its own real steps past the boundary, in their order.
-    Lanes are independent within a segment, so no lane's sequence of steps
-    changes. A width-1 step never costs more than a full-width one, so
-    there is nothing to tune: an equal-split or all-benign round has no job
-    and runs what `active_steps` says. data/batching.py::plan_step_counts
-    is the same rule in numpy."""
+    positions of `order` up to and including the last one at which at least
+    `wide_from` lanes hold a real batch, rounded up to STEP_CHUNK; a lane
+    with a real step after that is one job: its own real steps past the
+    boundary, in their order. Lanes are independent within a segment, so no
+    lane's sequence of steps changes. `wide_from` (a static Python integer)
+    is the number of live lanes from which a full-width step is the cheaper
+    one, ceil(full-width step / width-1 step) on the chip: 13.6 for the
+    Tiny-ImageNet ResNet-18 at C = 10 (20.13 ms against 1.483 ms, and 1.11
+    ms a job; PERF.md, PRs 28 and 31), so there every step is a job's. With
+    2 an equal-split or all-benign round has no job and runs what
+    `active_steps` says; with more than C the full-width loop's trip count
+    is 0 in every round. data/batching.py::plan_step_counts is the same
+    rule in numpy."""
     C = mask.shape[0]
     real = jnp.any(mask, axis=3).reshape(C, -1)            # [C, E*S] by id
     order, _ = active_steps(mask)
     real = real[:, order]                                  # by position
     pos = jnp.arange(real.shape[1], dtype=jnp.int32)
-    shared = jnp.sum(real, axis=0, dtype=jnp.int32) >= 2
+    shared = jnp.sum(real, axis=0, dtype=jnp.int32) >= wide_from
     n_wide = (jnp.max(jnp.where(shared, pos + 1, 0))
               + STEP_CHUNK - 1) // STEP_CHUNK
     tail = real & (pos >= n_wide * STEP_CHUNK)
@@ -141,16 +146,18 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
                      hyper: RoundHyper, fg_enabled: bool,
                      fused_pallas: bool = False,
                      fused_interpret: bool = False,
-                     narrow_tail: bool = True):
+                     wide_from: int = 2):
     """Returns segment_step(start_vars, benign_mom, tasks, idx[C,E,S,B],
     mask[C,E,S,B], rngs[C]) -> SegmentResult, every argument and result
     stacked [C, ...]: one segment of every lane. The single client's step
     body is written once and run by the full-width loop (`vmap` over the
     lanes; one `while` whose predicate no lane batches around STEP_CHUNK
-    steps at a time) and, with `narrow_tail` and more than one lane, by the
-    job loop that finishes a lane's tail at width 1 (`split_steps`). The
-    engine clears `narrow_tail` where the clients axis is sharded: taking
-    one lane out of a sharded stack is a collective.
+    steps at a time) and, with `wide_from` above 1 and more than one lane,
+    by the job loop that runs at width 1 what the full-width loop leaves
+    (`split_steps`). The engine gives `wide_from` (fl/rounds.py::
+    wide_from_of): 1 where the clients axis is sharded — taking one lane
+    out of a sharded stack is a collective — and the full-width loop then
+    runs every step that runs.
     `fused_pallas` routes the full-width loop's per-step state update
     through the fused multi-tensor kernel (ops/fused_update.py) when the
     engine runs unsharded on TPU; the math is identical either way."""
@@ -274,7 +281,8 @@ def make_client_step(model_def: ModelDef, data: DeviceData,
                      tasks: ClientTask, idx, mask, rngs) -> SegmentResult:
         C, E, S, _ = idx.shape
         feed = (start_vars.params, tasks, idx, mask, rngs)
-        split = split_steps(mask) if narrow_tail and C > 1 else None
+        split = (split_steps(mask, wide_from) if wide_from > 1 and C > 1
+                 else None)
         order, n_wide = (active_steps(mask) if split is None
                          else (split.order, split.n_wide))
         # with two loops reading the dataset, one traced value for both:

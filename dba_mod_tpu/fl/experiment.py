@@ -682,7 +682,7 @@ class Experiment:
             plan_span.count(
                 **plan_step_counts(
                     mask_list, STEP_CHUNK,
-                    self.engine.narrow_tail and not self.sequential_debug),
+                    1 if self.sequential_debug else self.engine.wide_from),
                 **(evaluation.battery_eval_counts(
                     tasks_list, self.is_poison_run, bool(params["baseline"]),
                     self.engine.forensics) if self.local_eval else {}))

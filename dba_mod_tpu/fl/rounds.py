@@ -362,6 +362,32 @@ def make_local_battery(model_def: ModelDef, data: DeviceData,
     return battery
 
 
+def wide_from_of(model_def: ModelDef, mesh, lanes: int) -> int:
+    """The client step's `wide_from` (fl/client.py::split_steps): the number
+    of live lanes from which a step runs at full width. Set here, at build,
+    from the model's parameter shapes and the mesh alone — no knob, and
+    nothing timed: a job's steps and the full-width loop's are not bit-equal
+    on the chip, so a program chosen by a stopwatch would move a run's
+    numerics with the machine's noise.
+
+    - A sharded clients axis: 1, the full-width loop alone. A lane a device
+      is the point there, and taking one lane out of a sharded stack is a
+      collective nobody has priced.
+    - Unsharded, a model with a convolution (some parameter leaf is a rank-4
+      kernel): `lanes + 1`, every lane a job. `vmap` over the lanes turns
+      each convolution into one with `lanes` sets of weights plus layout
+      copies between the lanes and batch axes, and a full-width step costs
+      more than `lanes` width-1 steps (PERF.md section 7, PR 31's table).
+    - Unsharded, dense layers only: 2. Stacked lanes make a batched matmul
+      the chip runs well; only one lane's tail leaves the full-width loop."""
+    if mesh is not None:
+        return 1
+    shapes = jax.eval_shape(lambda: model_def.init_vars(jax.random.key(0)))
+    has_conv = any(l.ndim == 4
+                   for l in jax.tree_util.tree_leaves(shapes.params))
+    return lanes + 1 if has_conv else 2
+
+
 class RoundEngine:
     """Holds the jitted round + eval computations for one experiment config.
 
@@ -414,14 +440,12 @@ class RoundEngine:
             mesh is None and jax.default_backend() == "tpu")
         self.fused_pallas = fused_pallas
         self.fused_interpret = bool(params.get("fused_interpret", False))
-        # one lane's tail at width 1 (fl/client.py::split_steps): taking a
-        # lane out of a sharded stack is a collective nobody has priced, so
-        # the mesh path keeps the full-width loop alone
-        self.narrow_tail = mesh is None
+        # from how many live lanes a step runs at full width
+        # (fl/client.py::split_steps); what runs below it is a lane's job
+        self.wide_from = wide_from_of(model_def, mesh, hyper.no_models)
         segment_step = make_client_step(
             model_def, data, hyper, fg_enabled, fused_pallas=fused_pallas,
-            fused_interpret=self.fused_interpret,
-            narrow_tail=self.narrow_tail)
+            fused_interpret=self.fused_interpret, wide_from=self.wide_from)
         eval_clean = make_eval_fn(model_def, data, poison=False)
         eval_poison = make_eval_fn(model_def, data, poison=True)
         is_poison_run = bool(params["is_poison"])
@@ -446,9 +470,10 @@ class RoundEngine:
                 rngs = jax.vmap(
                     lambda i: jax.random.fold_in(seg_rng, i))(lane)
                 tasks_s = jax.tree_util.tree_map(lambda l: l[s], tasks_seq)
-                # the steps loops run only the steps some lane needs, and
-                # one lane's tail at width 1: their trip counts come from
-                # the mask, inside the program (fl/client.py)
+                # the steps loops run only the steps some lane needs, below
+                # `wide_from` live lanes as jobs at width 1: their trip
+                # counts come from the mask, inside the program
+                # (fl/client.py)
                 res = segment_step(start, benign_mom, tasks_s, idx_seq[s],
                                    mask_seq[s], rngs)
                 start = res.end_vars

@@ -1,7 +1,8 @@
 """tests/test_client_step_trip_count.py's checks on the 8-virtual-device
-`clients` mesh, where the engine builds no job loop: every feed runs the
-full-width loop to its last step. A file of its own so that `--dist
-loadfile` gives the mesh a worker beside the one-device cases."""
+`clients` mesh, where the engine's rule gives `wide_from` 1 and builds no
+job loop: every feed runs the full-width loop to its last step. A file of
+its own so that `--dist loadfile` gives the mesh a worker beside the
+one-device cases."""
 import pytest
 
 import trip_count_cases as tc
@@ -15,7 +16,9 @@ MESH_CASES = ("heavy_tail", "all_full", "empty_client", "check_k1",
 
 @pytest.fixture(scope="module")
 def pair():
-    return tc.make_pair(8)
+    exp = tc.make_experiment(8)
+    assert exp.engine.wide_from == 1
+    return exp, tc.make_experiment(8, full_length=True)
 
 
 @pytest.mark.parametrize("case", MESH_CASES)

@@ -17,6 +17,7 @@ import pytest
 
 import jax
 
+import trip_count_cases as tc
 from dba_mod_tpu.config import Params
 from dba_mod_tpu.fl.experiment import Experiment
 
@@ -61,11 +62,25 @@ LOAN8 = dict(
        "0_poison_epochs": [1, 2], "1_poison_epochs": [2]})
 
 
+def _one_device(cfg, wide_from=None):
+    """The single-device Experiment; `wide_from` in the place of the
+    engine's rule (fl/rounds.py::wide_from_of) where given."""
+    return tc.make_experiment(0, wide_from=wide_from, cfg=cfg)
+
+
 def _pair(cfg, devices=8):
-    e1 = Experiment(Params.from_dict(cfg), save_results=False)
+    """The subject here is the sharding, so both sides run the client step's
+    full-width loop: the mesh has no other, and the single device is built
+    with `wide_from = 2` (no lane of these equal-split rounds has a tail).
+    Left to its rule the single-device engine runs a convolutional model's
+    lanes as width-1 jobs (PR 31), and a width-1 ResNet step is a plain
+    convolution where the stacked one is grouped: the two round differently
+    on XLA:CPU (test_every_lane_a_job_matches_the_full_width_loop below)."""
+    e1 = _one_device(cfg, wide_from=2)
     e8 = Experiment(Params.from_dict(dict(cfg, num_devices=devices)),
                     save_results=False)
     assert e8.mesh is not None and e8.mesh.devices.size == devices
+    assert (e1.engine.wide_from, e8.engine.wide_from) == (2, 1)
     return e1, e8
 
 
@@ -102,6 +117,31 @@ def test_cifar_bn_round_on_mesh_matches_single_device(narrow_resnets):
     assert abs(r1["backdoor_acc"] - r8["backdoor_acc"]) < 1.0
     # the sharded local battery produced rows for every client
     assert len({row[0] for row in e8.recorder.test_result
+                if row[0] != "global"}) == 8
+
+
+def test_every_lane_a_job_matches_the_full_width_loop(narrow_resnets):
+    """The BN ResNet's round with every lane a width-1 job (what the
+    engine's rule gives a convolutional model on one device) against the
+    same round through the full-width loop, on one device. Not to the bit,
+    as LeNet's is (tests/test_client_step_trip_count.py): XLA:CPU compiles
+    a plain convolution at width 1 and a grouped one for the stacked lanes,
+    their f32 sums differ at ~1e-6 a step and ReLU gates flip — the envelope
+    the one-client-a-device mesh had (above). Measured here (PR 31): params
+    6.8e-5, batch_stats 6.0e-8, accuracies equal."""
+    jobs, wide = _one_device(CIFAR8), _one_device(CIFAR8, wide_from=2)
+    assert (jobs.engine.wide_from, wide.engine.wide_from) == (9, 2)
+    rj, rw = jobs.run_round(1), wide.run_round(1)
+    dp = np.abs(_flat(jobs.global_vars.params)
+                - _flat(wide.global_vars.params)).max()
+    db = np.abs(_flat(jobs.global_vars.batch_stats)
+                - _flat(wide.global_vars.batch_stats)).max()
+    print(f"every lane a job against the full-width loop: params {dp:.3g}, "
+          f"batch_stats {db:.3g}")
+    assert 0 < dp < 1e-3 and db < 1e-5
+    assert abs(rj["global_acc"] - rw["global_acc"]) < 1.0
+    assert abs(rj["backdoor_acc"] - rw["backdoor_acc"]) < 1.0
+    assert len({row[0] for row in jobs.recorder.test_result
                 if row[0] != "global"}) == 8
 
 

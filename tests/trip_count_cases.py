@@ -1,9 +1,9 @@
-"""What tests/test_client_step_trip_count.py (one device) and
-tests/test_client_step_trip_count_mesh.py (the 8-virtual-device `clients`
-mesh) share: the full-length reference step, the pair of engines, the feeds
-and the three checks. Two files so that the scheduler (`--dist loadfile`)
-can give each device layout a worker of its own; the account of the cases
-is in the first file's docstring."""
+"""What tests/test_client_step_trip_count.py (one device, at `wide_from` 2
+and C + 1) and tests/test_client_step_trip_count_mesh.py (the
+8-virtual-device `clients` mesh) share: the full-length reference step, the
+engines, the feeds and the three checks. Two files so that the scheduler
+(`--dist loadfile`) can give each device layout a worker of its own; the
+account of the cases is in the first file's docstring."""
 
 from typing import Any
 
@@ -44,9 +44,9 @@ CASES = ("heavy_tail", "all_full", "empty_client", "check_k1", "check_k3",
 
 def make_full_length_client_step(model_def, data, hyper, fg_enabled,
                                  fused_pallas=False, fused_interpret=False,
-                                 narrow_tail=True):
+                                 wide_from=2):
     """The steps loop before the trip counts: every lane through one
-    `lax.scan` over every one of the E x S plan steps (`narrow_tail` is
+    `lax.scan` over every one of the E x S plan steps (`wide_from` is
     taken and ignored: there is one loop, at full width)."""
     fused_update = make_fused_step_update(
         hyper.momentum, hyper.weight_decay, fg_enabled,
@@ -120,19 +120,23 @@ def make_full_length_client_step(model_def, data, hyper, fg_enabled,
     return jax.vmap(client_step)
 
 
-def make_pair(num_devices):
-    """(the program's Experiment, one whose engine runs the full-length
-    loop), on one device (0) or on the clients mesh (8)."""
-    cfg = dict(CFG, num_devices=num_devices)
-    exp = Experiment(Params.from_dict(cfg), save_results=False)
+def make_experiment(num_devices, wide_from=None, full_length=False, cfg=CFG):
+    """The program's Experiment on one device (0) or on the clients mesh
+    (8): as the engine's own rule builds it, or with `wide_from` in the
+    rule's place; `full_length`: an engine that runs the full-length loop."""
     mp = pytest.MonkeyPatch()
-    mp.setattr(rounds_mod, "make_client_step", make_full_length_client_step)
+    if wide_from is not None:
+        mp.setattr(rounds_mod, "wide_from_of", lambda *_: wide_from)
+    if full_length:
+        mp.setattr(rounds_mod, "make_client_step",
+                   make_full_length_client_step)
     try:
-        ref = Experiment(Params.from_dict(cfg), save_results=False)
+        exp = Experiment(Params.from_dict(dict(cfg, num_devices=num_devices)),
+                         save_results=False)
     finally:
         mp.undo()
     assert (exp.mesh is not None) == bool(num_devices)
-    return exp, ref
+    return exp
 
 
 def _feed(exp, case):
@@ -213,7 +217,8 @@ def check_round_is_bit_equal_to_the_full_length_loop(pair, case):
                 rounds_mod.nbt_client_deltas(mask_seq, tasks_seq.scale))
             rest = (agg.new_vars, agg.new_fg_state, agg.wv)
         out[name] = (train, rest)
-    counts = plan_step_counts([mask[0]], STEP_CHUNK, exp.engine.narrow_tail)
+    wide_from = exp.engine.wide_from
+    counts = plan_step_counts([mask[0]], STEP_CHUNK, wide_from)
     if case == "all_full":
         assert counts["steps_run"] == counts["steps_plan"]
     elif case == "heavy_tail":
@@ -223,9 +228,10 @@ def check_round_is_bit_equal_to_the_full_length_loop(pair, case):
     elif case.startswith("check_k"):
         assert counts["steps_run"] == int(case[-1])
     job_lanes = ()
-    if exp.engine.narrow_tail:
-        split = split_steps(jnp.asarray(mask[0]))
+    if wide_from > 1:
+        split = split_steps(jnp.asarray(mask[0]), wide_from)
         job_lanes = np.asarray(split.job_lanes[:int(split.n_jobs)])
+    if wide_from == 2:
         assert len(job_lanes) == {"heavy_tail": 1, "empty_client": 1,
                                   "solo_lane": 1, "two_tails": 1,
                                   "two_jobs": 2}.get(case, 0)
@@ -234,7 +240,16 @@ def check_round_is_bit_equal_to_the_full_length_loop(pair, case):
             assert counts["lane_steps_narrow"] == counts["lane_steps_real"]
         elif case == "two_tails":   # the full-width loop reaches the last epoch
             assert counts["steps_wide"] > counts["steps_plan"] - 2 * STEP_CHUNK
+    elif wide_from > counts["lanes"]:
+        # every lane with data is one job, and the full-width loop runs nothing
+        has_data = mask[0].any(axis=(1, 2, 3))
+        np.testing.assert_array_equal(job_lanes, np.flatnonzero(has_data))
+        assert len(job_lanes) == {"empty_client": has_data.size - 1,
+                                  "solo_lane": 1}.get(case, has_data.size)
+        assert counts["steps_wide"] == 0
+        assert counts["lane_steps_narrow"] == counts["lane_steps_real"]
     else:
+        assert wide_from == 1
         assert counts["lane_steps_narrow"] == 0
         assert counts["steps_wide"] == -(-counts["steps_run"]
                                          // STEP_CHUNK) * STEP_CHUNK
@@ -304,6 +319,13 @@ def check_train_phase_is_a_wide_while_then_a_width_1_job_loop(pair):
                 == sorted(s[1:] for s in lanes_carry))
         chunk, = _eqns(lane_chunks.params["body_jaxpr"].jaxpr, "scan")
         assert chunk.params["length"] == STEP_CHUNK
+        # the same three loops at `wide_from` 2 and C + 1: where the
+        # full-width loop stops is a value read from the mask, and above C
+        # it is 0 for every mask, the fullest included
+        full = jnp.ones(mask_seq.shape[1:], bool)
+        n_wide = int(split_steps(full, exp.engine.wide_from).n_wide)
+        assert n_wide == (0 if exp.engine.wide_from > C
+                          else -(-full[0, ..., 0].size // STEP_CHUNK))
         # one lane alone builds no job loop (sequential_debug's calls)
         one = jax.tree_util.tree_map(lambda l: l[:, :1],
                                      (tasks_seq, idx_seq, mask_seq))
@@ -311,7 +333,7 @@ def check_train_phase_is_a_wide_while_then_a_width_1_job_loop(pair):
             exp.global_vars, *one, lane[:1], key).jaxpr, "while"))
         assert len(loops_1) == 1
     else:
-        assert not exp.engine.narrow_tail and len(loops) == 1
+        assert exp.engine.wide_from == 1 and len(loops) == 1
     assert len(list(_eqns(jaxpr, "scan"))) == (2 if exp.mesh is None else 1)
     # and they are the only `while`s of the whole round program (`local_eval`
     # is off here: the local battery's job loop is the other one,
@@ -329,7 +351,8 @@ def check_one_program_for_every_trip_count_and_the_host_counts_it(pair):
     mask."""
     exp, _ = pair
     rf = exp.engine.round_fn
-    tail = exp.engine.narrow_tail
+    wide_from = exp.engine.wide_from
+    tail = wide_from > 1
     rng_t, rng_a = jax.random.split(jax.random.key(3))
     trip_counts, job_counts = set(), set()
     # (the mesh's steps are slow on virtual devices: two trip counts there)
@@ -338,21 +361,25 @@ def check_one_program_for_every_trip_count_and_the_host_counts_it(pair):
         jax.block_until_ready(rf(exp.global_vars, exp.fg_state, tasks_seq,
                                  idx_seq, mask_seq, lane, ns, rng_t, rng_a))
         order, n_chunks = active_steps(mask_seq[0])
-        counts = plan_step_counts([mask[0]], STEP_CHUNK, tail)
+        counts = plan_step_counts([mask[0]], STEP_CHUNK, wide_from)
         n_run = counts["steps_run"]
         assert int(n_chunks) == -(-n_run // STEP_CHUNK)
         active = np.flatnonzero(mask[0].any(axis=(0, 3)).reshape(-1))
         np.testing.assert_array_equal(np.asarray(order)[:n_run], active)
         if tail:
-            split = split_steps(mask_seq[0])
+            split = split_steps(mask_seq[0], wide_from)
             np.testing.assert_array_equal(split.order, order)
             n_chunks = split.n_wide
             assert int(jnp.sum(split.n_tail)) == counts["lane_steps_narrow"]
             job_counts.add(int(split.n_jobs))
         assert int(n_chunks) * STEP_CHUNK == counts["steps_wide"]
         trip_counts.add(int(n_chunks))
-    assert len(trip_counts) >= 2 and 1 in trip_counts
-    assert job_counts == ({0, 1, 2} if tail else set())
+    if wide_from > idx_seq.shape[1]:   # every lane a job: 1 to C of them
+        assert trip_counts == {0}
+        assert len(job_counts) >= 2 and min(job_counts) == 1
+    else:
+        assert len(trip_counts) >= 2 and 1 in trip_counts
+        assert job_counts == ({0, 1, 2} if tail else set())
     assert rf._cache_size() == 1
 
     n0 = len(tel.spans())
@@ -362,7 +389,7 @@ def check_one_program_for_every_trip_count_and_the_host_counts_it(pair):
     program_chunks = sum(int(active_steps(jnp.asarray(m))[1])
                          for m in fl.mask_list)
     assert -(-plan.counts["steps_run"] // STEP_CHUNK) == program_chunks
-    steps = plan_step_counts(fl.mask_list, STEP_CHUNK, tail)
+    steps = plan_step_counts(fl.mask_list, STEP_CHUNK, wide_from)
     assert {k: plan.counts[k] for k in steps} == steps
     assert 0 < plan.counts["steps_run"] <= plan.counts["steps_plan"]
     assert (plan.counts["lane_steps_real"]
