@@ -4,7 +4,7 @@ memory: two builds of the Tiny-ImageNet population and two 4 GB executables
 met the machine's 40 GiB in PR 23):
 
     python -m chipbench.calibrate --workload <cell> --seeds 11,12,... \
-        --control-seeds 21,22,23
+        --control-seeds 21,22,23 [--benchmark-file <file>]
 
 For each seed, the cell's two check rounds through the program's compiled
 round program against the plain reference at the device's default matmul
@@ -24,10 +24,12 @@ import sys
 from chipbench import run as harness
 
 
-def readings(cell_name, seeds, overrides, label, precisions):
+def readings(cell_name, seeds, overrides, label, precisions,
+             benchmark_file=None):
     import jax
-    from chipbench import program
-    bench, cell, config, traffic = harness.load_cell(cell_name)
+    from chipbench import families, program
+    bench, cell, config, traffic = harness.load_cell(cell_name, benchmark_file)
+    family = families.of(config)
     out_dir = harness.HERE / "_out" / f"calibrate.{cell_name}.{label}"
     shutil.rmtree(out_dir, ignore_errors=True)
     out_dir.mkdir(parents=True)
@@ -40,13 +42,13 @@ def readings(cell_name, seeds, overrides, label, precisions):
     events = harness.CompileEvents()
     rows = []
     for seed in seeds:
-        state0, checks = harness.seeded_check_rounds(exp, config, traffic, seed,
-                                                     first, events)
+        state0, checks = harness.seeded_check_rounds(
+            exp, family, config, traffic, seed, first, events)
         row = {"label": label, "seed": seed}
         for precision in precisions:
             row[precision] = {r["number"]: r["value"] for r in harness.judge(
-                raw, config["model"]["variant"], state0,
-                harness.population_of(exp), checks, {}, precision, every=True)}
+                family, raw, config["model"], state0,
+                family.population_of(exp), checks, {}, precision, every=True)}
         harness.emit(**row, memory=harness.device_memory(jax.devices()[:1]))
         rows.append(row)
     shutil.rmtree(out_dir, ignore_errors=True)
@@ -59,6 +61,9 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control-seeds", default="")
+    ap.add_argument("--benchmark-file", default=None,
+                    help="another file of BENCHMARK.json's form (a cell that "
+                         "is none of the benchmark's)")
     args = ap.parse_args()
     import jax
     if jax.devices()[0].platform != "tpu":
@@ -68,9 +73,10 @@ def main() -> int:
     seeds = [int(s) for s in args.seeds.split(",") if s]
     control = [int(s) for s in args.control_seeds.split(",") if s]
     sound = readings(args.workload, seeds, None, "sound",
-                     ("default", "highest")) if seeds else []
+                     ("default", "highest"), args.benchmark_file) if seeds else []
     ctrl = readings(args.workload, control, {"compute_dtype": "bfloat16"},
-                    "control_bfloat16", ("default",)) if control else []
+                    "control_bfloat16", ("default",),
+                    args.benchmark_file) if control else []
     summary = {}
     for number in (sound or ctrl)[0]["default"]:
         summary[number] = {

@@ -339,8 +339,7 @@ def scope_device_ms(ctx, scope: str) -> Optional[float]:
     reduced, traced = run_phases(ctx), ctx.get("traced")
     if not reduced or not traced or not traced.get("rounds"):
         return None
-    s = (reduced["kernel_s"] if scope == KERNEL
-         else reduced["scope_s"].get(scope))
+    s = reduced["scope_s"].get(scope)
     return None if s is None else 1e3 * s / traced["rounds"]
 
 

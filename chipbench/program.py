@@ -1,4 +1,7 @@
-"""Everything the benchmark calls in the program under test — in this one file.
+"""Everything the benchmark calls in the program under test that does not
+depend on the model family — in this one file (a family's own file under
+`chipbench/families/` lists what it names beside these: the attribute that
+holds its population, the names of its model tree).
 
 A PR that renames one of these has to keep the benchmark running:
 
@@ -7,17 +10,15 @@ A PR that renames one of these has to keep the benchmark running:
   attributes `select_rng`, `plan_rng`, `rng_key`, `global_vars`, `fg_state`
   (assigned from `--seed` after the build; `select_rng` again from the
   population's seed at the start of every period of the window), `engine`,
-  `folder`,
-  `image_data`, `steps_per_epoch`, `epochs_max`, `_use_donated_round`,
+  `folder`, `mesh`, `steps_per_epoch`, `epochs_max`, `_use_donated_round`,
   `last_global_loss`
 - `Experiment.run_round`, `dispatch_round`, `finalize_round`, `save_model`
   (the program's own sequential loop) and `build_static_round_inputs` (the
   feed of the output check, at the window's own shape)
 - `engine.round_fn_donated` / `engine.round_fn` (the compiled round program the
-  window drives), `engine.fused_pallas`, `engine.fused_interpret`
+  window drives), `engine.fused_interpret`
 - `RoundInFlight.payload`, and the key `agents` of a finished round's result
-- `dba_mod_tpu.models.ModelVars`, and the flax auto-names of the ResNet tree
-  (`Conv_0`, `BatchNorm_0`, `BasicBlock_<i>`, `Dense_0`)
+- `dba_mod_tpu.models.ModelVars` (collections `params` and `batch_stats`)
 - `dba_mod_tpu.utils.compile_cache.enable_compile_cache`
 - the payload order `(locals, globals, metrics, delta_norms, ...)` of the round
 """
@@ -82,41 +83,15 @@ def build_experiment(params):
 
 
 # ------------------------------------------------- reference names <-> tree
-def _path_of(name: str):
-    """torch-style reference name -> (collection, module path, leaf)."""
-    parts = name.split(".")
-    leaf = parts[-1]
-    if name == "conv1":
-        return "params", ("Conv_0",), "kernel"
-    if name.startswith("fc."):
-        return "params", ("Dense_0",), {"weight": "kernel", "bias": "bias"}[leaf]
-    if parts[0] == "bn1":
-        mod: tuple = ("BatchNorm_0",)
-    else:
-        block = f"BasicBlock_{2 * (int(parts[0][5:]) - 1) + int(parts[1])}"
-        sub = parts[2:]
-        if sub[0] == "shortcut":
-            sub_mod = {"conv": "Conv_2", "bn": "BatchNorm_2"}[sub[1]]
-        else:
-            sub_mod = {"conv1": "Conv_0", "bn1": "BatchNorm_0",
-                       "conv2": "Conv_1", "bn2": "BatchNorm_1"}[sub[0]]
-        mod = (block, sub_mod)
-        if sub_mod.startswith("Conv"):
-            return "params", mod, "kernel"
-    coll, leaf = {"weight": ("params", "scale"), "bias": ("params", "bias"),
-                  "running_mean": ("batch_stats", "mean"),
-                  "running_var": ("batch_stats", "var")}[leaf]
-    return coll, mod, leaf
-
-
-def to_program(shapes, state: Dict[str, Any]):
+def to_program(shapes, state: Dict[str, Any], path_of):
     """The benchmark's weights (host arrays), placed on the device in the
-    program's tree. Refuses a tree whose structure or shapes (`shapes`: those
-    of the program's own `global_vars`) are not the reference's."""
+    program's tree; `path_of(name)` -> (collection, module path, leaf) is the
+    family's. Refuses a tree whose structure or shapes (`shapes`: those of the
+    program's own `global_vars`) are not the reference's."""
     from dba_mod_tpu.models import ModelVars
     tree: dict = {"params": {}, "batch_stats": {}}
     for name, value in state.items():
-        coll, mod, leaf = _path_of(name)
+        coll, mod, leaf = path_of(name)
         node = tree[coll]
         for m in mod:
             node = node.setdefault(m, {})
@@ -134,12 +109,12 @@ def tree_shapes(model_vars):
     return jax.tree_util.tree_map(lambda l: (l.shape, str(l.dtype)), model_vars)
 
 
-def from_program(model_vars, names) -> Dict[str, np.ndarray]:
+def from_program(model_vars, names, path_of) -> Dict[str, np.ndarray]:
     """The program's state on the host, under the reference's names."""
     host = jax.device_get(model_vars)
     out = {}
     for name in names:
-        coll, mod, leaf = _path_of(name)
+        coll, mod, leaf = path_of(name)
         node = host.params if coll == "params" else host.batch_stats
         for m in mod:
             node = node[m]
@@ -152,8 +127,9 @@ def seed_selection(exp, seed: int) -> None:
     exp.select_rng = random.Random(int(seed))
 
 
-def seed_state(exp, seed: int, state: Dict[str, Any]) -> None:
-    """`--seed` drives what cannot move a round's time, on top of the fixed
+def seed_state(exp, seed: int, state: Dict[str, Any], to_tree) -> None:
+    """`state` goes into the program through the family's `to_tree(shapes,
+    state)`. `--seed` drives what cannot move a round's time, on top of the fixed
     population: initial weights, batch order, device RNG; and the client
     selection of set-up's rounds (the two check rounds, the warm round). The
     window's selection is not `--seed`'s: `run.run_window` sets it from the
@@ -163,7 +139,7 @@ def seed_state(exp, seed: int, state: Dict[str, Any]) -> None:
     seed_selection(exp, seed)
     exp.plan_rng = np.random.RandomState(int(seed) % (2 ** 32))
     exp.rng_key = jax.random.key(int(seed) % (2 ** 31 - 1))
-    exp.global_vars = to_program(shapes, state)
+    exp.global_vars = to_tree(shapes, state)
 
 
 # ------------------------------------------------------------- the round program
@@ -173,10 +149,12 @@ def round_program(exp):
             else exp.engine.round_fn)
 
 
-def engine_report(exp, on_tpu: bool) -> Dict[str, Any]:
-    """chip_smoke.check_engine_is_the_chips, copied: the donated round program
-    is built and is the one dispatched; unsharded, with the compiled (not
-    interpreted) fused Pallas update."""
+def engine_report(exp, on_tpu: bool,
+                  family_conditions: Dict[str, bool]) -> Dict[str, Any]:
+    """The program that ran (chip_smoke.check_engine_is_the_chips, copied): the
+    donated round program is built, is the one dispatched, was compiled once,
+    and nothing in it is interpreted; beside these the conditions the
+    configuration's family sets on its engine (`{name: bool}`)."""
     eng = exp.engine
     donated = eng.round_fn_donated
     seen = {"round_fn_donated_built": donated is not None,
@@ -184,19 +162,19 @@ def engine_report(exp, on_tpu: bool) -> Dict[str, Any]:
             "donated_programs_compiled":
                 donated._cache_size() if donated is not None else 0,
             "undonated_programs_compiled": eng.round_fn._cache_size(),
-            "fused_pallas": bool(eng.fused_pallas),
             "fused_interpret": bool(eng.fused_interpret),
-            "mesh": exp.mesh is not None}
+            "mesh": exp.mesh is not None,
+            **family_conditions}
     if on_tpu:
-        seen["ok"] = bool(
+        ok = bool(
             seen["round_fn_donated_built"] and seen["use_donated_round"]
             and seen["donated_programs_compiled"] == 1
             and seen["undonated_programs_compiled"] == 0
-            and seen["fused_pallas"] == (exp.mesh is None)
             and not seen["fused_interpret"])
     else:  # a rehearsal's engine is the CPU's: one program, whichever it is
-        seen["ok"] = (seen["donated_programs_compiled"]
-                      + seen["undonated_programs_compiled"]) == 1
+        ok = (seen["donated_programs_compiled"]
+              + seen["undonated_programs_compiled"]) == 1
+    seen["ok"] = ok and all(family_conditions.values())
     return seen
 
 

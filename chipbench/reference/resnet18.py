@@ -1,19 +1,19 @@
 """Plain reference: the DBA reference repo's two ResNet-18 variants in
-straightforward jax.numpy float32 — forward, masked cross-entropy, gradient,
-torch-SGD step, K local steps of one client and FedAvg (`chipbench/check.py`
-drives them and evaluates the new global model over the test set).
+straightforward jax.numpy float32 — the state_dict's layout, weights from a
+seed and the forward pass with torch's BatchNorm2d (`federated.py` holds the
+round: loss, gradient, torch-SGD steps, FedAvg; `images.py` the pixel trigger).
 
 Written from the reference repo's model files (`models/resnet_cifar.py`:
 3x3 stem, BasicBlock [2,2,2,2] at 32/64/128/256, 4x4 average pool, linear
 head; `models/resnet_tinyimagenet.py`: torchvision ResNet-18, 7x7 stride-2
 stem, 3x3 stride-2 max pool, 64/128/256/512, global average pool) and from
-torch's documented BatchNorm2d and SGD semantics — NOT from
+torch's documented BatchNorm2d semantics — NOT from
 `dba_mod_tpu/models/resnet.py`. It imports nothing of the program and takes
 nothing the program made: the weights come from `init_weights(seed)` below.
 
 Names are torch-style ("layer2.0.conv1", "fc.weight"); kernels are HWIO and
-images NHWC so that no transposes hide in the comparison. `chipbench/program.py`
-maps these names onto the program's tree.
+images NHWC so that no transposes hide in the comparison.
+`chipbench/families/resnet18.py` maps these names onto the program's tree.
 """
 from __future__ import annotations
 
@@ -21,6 +21,8 @@ from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from chipbench.reference import images
 
 VARIANTS = {
     # variant: (widths, stem kernel, stem stride, max pool after stem, pool, conv init)
@@ -108,9 +110,9 @@ def with_batch_statistics(state, images_u8, variant: str):
     default 0/1 statistics, a x100 model replacement drives the aggregated
     variances negative and the global model to NaN (PERF.md, PR 23)."""
     @jax.jit
-    def run(state, images):
-        return forward(state, images.astype(jnp.float32) / 255.0, variant,
-                       True, momentum=1.0)[1]
+    def run(state, batch):
+        return forward(state, images.scaled(batch), variant, True,
+                       momentum=1.0)[1]
     return {**state, **run(state, images_u8)}
 
 
@@ -169,62 +171,3 @@ def forward(state, x, variant: str, train: bool, momentum=BN_MOMENTUM):
         y = y.mean(axis=(1, 2), keepdims=True)
     y = y.reshape(y.shape[0], -1)
     return y @ state["fc.weight"] + state["fc.bias"], stats
-
-
-def nll(logits, labels):
-    logp = logits - jax.scipy.special.logsumexp(logits, axis=-1, keepdims=True)
-    return -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
-
-
-def stamp(x, y, pixels, swap_label: int, first_k: int):
-    """DBA training poison: the first `first_k` images of the batch get the
-    trigger pixels set to 1.0 in every channel and the label `swap_label`."""
-    if first_k <= 0:
-        return x, y
-    rows = jnp.asarray([p[0] for p in pixels])
-    cols = jnp.asarray([p[1] for p in pixels])
-    x = x.at[:first_k, rows, cols, :].set(1.0)
-    return x, y.at[:first_k].set(swap_label)
-
-
-def client_steps(state, images_u8, labels, masks, lr, variant, *, momentum,
-                 decay, pixels=(), swap_label=0, first_k=0, scale=1.0):
-    """K torch-SGD steps of one client from `state` (a fresh optimizer:
-    momentum buffers start at zero). images_u8 [K,B,H,W,3] uint8, labels
-    [K,B], masks [K,B] (padding rows count for BatchNorm, not for the loss).
-    Returns (delta of the full state after model-replacement scaling, the K
-    batch losses)."""
-    weights = {k: v for k, v in state.items() if not is_stat(k)}
-    stats = {k: v for k, v in state.items() if is_stat(k)}
-    buf = {k: jnp.zeros_like(v) for k, v in weights.items()}
-    losses = []
-    for k in range(images_u8.shape[0]):
-        x = images_u8[k].astype(jnp.float32) / 255.0
-        x, y = stamp(x, labels[k], pixels, swap_label, first_k)
-        m = masks[k].astype(jnp.float32)
-
-        def loss_fn(w):
-            logits, new_stats = forward({**w, **stats}, x, variant, True)
-            return jnp.sum(nll(logits, y) * m) / jnp.maximum(jnp.sum(m), 1.0), new_stats
-
-        (loss, new_stats), g = jax.value_and_grad(loss_fn, has_aux=True)(weights)
-        # a batch with no valid row is padding of the plan, not a step: the
-        # DataLoader it stands for had already ended
-        real = jnp.sum(m) > 0
-        for name in weights:  # torch.optim.SGD, dampening 0, no nesterov
-            d = g[name] + decay * weights[name]
-            b = momentum * buf[name] + d
-            buf[name] = jnp.where(real, b, buf[name])
-            weights[name] = jnp.where(real, weights[name] - lr * b, weights[name])
-        stats = {n: jnp.where(real, new_stats[n], stats[n]) for n in stats}
-        losses.append(jnp.where(real, loss, 0.0))
-    end = {**weights, **stats}
-    delta = {k: scale * (end[k] - state[k]) for k in state}
-    return delta, jnp.stack(losses)
-
-
-def fedavg(state, deltas, eta: float, no_models: int):
-    """helper.py average_shrink_models: global += eta / no_models * sum(deltas),
-    over the whole state_dict (running statistics included)."""
-    return {k: state[k] + (eta / no_models) * sum(d[k] for d in deltas)
-            for k in state}

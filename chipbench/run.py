@@ -3,19 +3,20 @@
     python -m chipbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Fails at once without a TPU or with fewer devices than the cell's `chips`;
-builds the configuration's `Experiment`; seeds the weights, the batch order
-and the check rounds' selection from `--seed`; compiles and warms the cell's
-one round program (the two check rounds and one whole round); then measures
-the program's own sequential loop over **whole periods of the traffic's
-schedule** (`period_rounds` in the traffic file): periods are started until
-`--seconds` have passed and the one in flight is finished, and at the start of
-every period the selection RNG is set from the configuration's
-`population_seed`, so every period of every run at every seed selects the same
-clients and times the same job. With `--trace 1` the window rounds the traffic
-names (`trace_window_rounds`) are traced and the window ends with the last of
-them. After the window the check rounds are compared with the plain reference;
-findings go out as JSON lines and, last, the result object of the benchmark's
-contract.
+finds the configuration's model family by the name its file gives
+(`chipbench/families/`); builds the configuration's `Experiment`; seeds the
+weights, the batch order and the check rounds' selection from `--seed`;
+compiles and warms the cell's one round program (the two check rounds and one
+whole round); then measures the program's own sequential loop over **whole
+periods of the traffic's schedule** (`period_rounds` in the traffic file):
+periods are started until `--seconds` have passed and the one in flight is
+finished, and at the start of every period the selection RNG is set from the
+configuration's `population_seed`, so every period of every run at every seed
+selects the same clients and times the same job. With `--trace 1` the window
+rounds the traffic names (`trace_window_rounds`) are traced and the window
+ends with the last of them. After the window the check rounds are compared
+with the plain reference; findings go out as JSON lines and, last, the result
+object of the benchmark's contract.
 
 `--rehearse` walks the same control flow on whatever backend is there at tiny
 sizes (Pallas interpreted): it prints no metric and is never `correct`.
@@ -112,44 +113,42 @@ def device_memory(devices) -> list:
             for d in devices]
 
 
-def seeded_check_rounds(exp, config, traffic, seed, first_window_epoch, events):
+def seeded_check_rounds(exp, family, config, traffic, seed, first_window_epoch,
+                        events):
     """Weights and traffic from `seed`, then the two check rounds through the
     window's own round program; leaves `exp` seeded afresh for the window."""
     import jax
     from chipbench import program
-    from chipbench.reference import resnet18 as ref
-    model = config["model"]
     # one jitted call on the device; kept on the host from here on, so that
     # the device's peak stays the program's
-    state0 = jax.device_get(
-        ref.init_weights(seed, model["variant"], model["num_classes"]))
-    program.seed_state(exp, seed, state0)
+    state0 = jax.device_get(family.init_weights(seed, config["model"]))
+    program.seed_state(exp, seed, state0, family.to_program)
     checks = []
     for i, steps in enumerate(CHECK_STEPS):
         # the first check round is at the epoch the traffic poisons first
         rounds = traffic.get("poison_window_rounds") or []
         epoch = (first_window_epoch - 1 + rounds[0]
                  if (i == 0 and traffic["is_poison"] and rounds) else i + 1)
-        got = program.check_round(exp, epoch, steps)
-        got["state"] = program.from_program(got.pop("new_vars"), list(state0))
+        got = family.check_round(exp, epoch, steps)
+        got["state"] = family.from_program(got.pop("new_vars"), list(state0))
         checks.append(got)
         emit(phase="check_round", index=i, epoch=epoch, real_steps=steps,
              seconds=got["seconds"], compile=events.snapshot())
-        program.seed_state(exp, seed, state0)
+        program.seed_state(exp, seed, state0, family.to_program)
     return state0, checks
 
 
-def judge(raw, variant, state0, population, checks, lim, precision="default",
-          every=False):
+def judge(family, raw, model, state0, population, checks, lim,
+          precision="default", every=False):
     """Each number compared, beside its limit (`lim`; `every`: also those
-    without one). `raw`: the parameters as run, `variant`: the model variant."""
+    without one). `raw`: the parameters as run, `model`: the configuration's."""
     from chipbench import check
     compared = []
     for got in checks:
-        want = check.reference_round(raw, variant, state0, population, got,
-                                     precision,
-                                     eval_rows=len(population["test_labels"]))
-        for name, value in check.compare(state0, got["state"], got, want).items():
+        want = family.reference_round(raw, model, state0, population, got,
+                                      precision)
+        for name, value in check.compare(state0, got["state"], got, want,
+                                         family.is_stat).items():
             key = f"{name}.k{got['real_steps']}"
             row = {"number": key, "value": value}
             if key in lim:
@@ -158,15 +157,6 @@ def judge(raw, variant, state0, population, checks, lim, precision="default",
             if key in lim or every:
                 compared.append(row)
     return compared
-
-
-def population_of(exp):
-    import numpy as np
-    data = exp.image_data
-    return {"train_images": data.train_images,
-            "train_labels": np.asarray(data.train_labels, np.int32),
-            "test_images": data.test_images,
-            "test_labels": np.asarray(data.test_labels, np.int32)}
 
 
 def end_to_end(rounds_s, failed, no_models, window_s, peak_bytes, setup_s):
@@ -283,7 +273,8 @@ def run_cell(args, sabotage=None) -> dict:
                          f"JAX sees {len(devices)}")
     devices = devices[:cell["chips"]]
 
-    from chipbench import check, program, steps, trace as trace_mod
+    from chipbench import check, families, program, steps, trace as trace_mod
+    family = families.of(config)
     events = CompileEvents()
     cache_dir = program.enable_cache()
     out_dir = HERE / "_out" / f"{args.workload}.{args.seed}.{args.trace}"
@@ -295,8 +286,10 @@ def run_cell(args, sabotage=None) -> dict:
     cut = None
     if args.rehearse:
         rehearsal = json.loads((HERE / "rehearsal.json").read_text())
-        cut = {**rehearsal["cut"],
-               **rehearsal["by_type"].get(config["params"]["type"], {})}
+        # a configuration may carry its own cut of size; the others share one
+        cut = (config.get("rehearsal") or {}).get("cut") or {
+            **rehearsal["cut"],
+            **rehearsal["by_type"].get(config["params"]["type"], {})}
         traffic = {**traffic, **rehearsal["by_traffic"].get(cell["traffic"], {})}
     first_window_epoch = FIRST_WINDOW_EPOCH
     overrides = dict(getattr(args, "overrides", None) or {})
@@ -316,14 +309,14 @@ def run_cell(args, sabotage=None) -> dict:
 
     # ---- set-up: traffic from --seed; the check rounds compile and warm the
     # window's one round program
-    state0, checks = seeded_check_rounds(exp, config, traffic, args.seed,
-                                         first_window_epoch, events)
-    # the window starts from the same weights with the running statistics a
-    # trained model would carry (those of the population's first 256 images)
-    from chipbench.reference import resnet18 as ref
-    warm_state = jax.device_get(ref.with_batch_statistics(
-        state0, exp.image_data.train_images[:256], config["model"]["variant"]))
-    program.seed_state(exp, args.seed, warm_state)
+    state0, checks = seeded_check_rounds(exp, family, config, traffic,
+                                         args.seed, first_window_epoch, events)
+    # the window starts from the same weights as a trained model would carry
+    # them (the family's rule: running statistics of the population's own)
+    population = family.population_of(exp)
+    warm_state = jax.device_get(
+        family.window_state(state0, population, config["model"]))
+    program.seed_state(exp, args.seed, warm_state, family.to_program)
     del warm_state
     spans["first_round"] = [checks[0]["seconds"]]
     spans["steady_round"] = [c["seconds"] for c in checks[1:]]
@@ -351,7 +344,8 @@ def run_cell(args, sabotage=None) -> dict:
         failed = max(failed, 1)
     rows = program.recorded_rows(exp)
     window_rows = [r for r in rows if r["epoch"] >= first_window_epoch]
-    engine = program.engine_report(exp, dev.platform == "tpu")
+    engine = program.engine_report(exp, dev.platform == "tpu",
+                                   family.engine_conditions(exp))
     agents = [[str(a) for a in r["agents"]] for r in results]
     emit(phase="window", window_s=window_s, rounds_s=rounds_s,
          global_acc=[r["global_acc"] for r in results],
@@ -365,8 +359,8 @@ def run_cell(args, sabotage=None) -> dict:
     # check rounds' feeds on the same rows of the population
     t0 = time.perf_counter()
     lim = check.limits(cell["config"], cell["traffic"])
-    compared = judge(raw, config["model"]["variant"], state0, population_of(exp),
-                     checks, lim)
+    compared = judge(family, raw, config["model"], state0, population, checks,
+                     lim)
     check_ok = bool(compared) and all(row["ok"] for row in compared)
     emit(phase="check", seconds=time.perf_counter() - t0, compared=compared)
 

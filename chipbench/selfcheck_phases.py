@@ -156,7 +156,7 @@ def main() -> int:
     want = {"data_build_s": 80.0, "lower_s": 25.0, "cache_load_s": 70.0,
             "plan_ms": 12.0, "stage_ms": 2.0, "fetch_ms": 4.0,
             "record_ms": 6.0, "train_device_ms": 3000.0,
-            "fused_update_device_ms": 375.0, "aggregate_device_ms": 50.0,
+            "aggregate_device_ms": 50.0,
             "local_battery_device_ms": 500.0,
             "global_battery_device_ms": 200.0,
             "idle_attributed_pct": 100 * 0.7 / 2.4}

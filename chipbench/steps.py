@@ -12,7 +12,8 @@ carries `.counts`, a dict with
   loop runs (in chunks of a few steps), read by the round program from the
   same mask,
 - `lane_steps_real`: the real client-steps (lane x step pairs with a batch),
-- `lanes`: C, the width every step that runs is executed at.
+- `lanes`: C, the lanes of the plan: the width of the full-width loop (a job
+  of the width-1 loop runs one of them).
 
 A program whose records carry no counts (the parent of the PR that added this
 file) gives `None` for every number here, never 0 and never an exception.
