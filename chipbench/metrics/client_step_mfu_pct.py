@@ -1,0 +1,13 @@
+"""client_step_mfu_pct — client step: operations the traced rounds' steps
+needed (forward + backward, counted on the plain reference, the experts'
+from the program's counter) over their `phase/train` device time times the
+chip's bf16 peak: a share of the whole step."""
+from chipbench import lfm2_layers
+
+LAYER = "client step"
+UNIT = "%"
+MOVES = "client_updates_per_s"
+
+
+def read(ctx):
+    return lfm2_layers.step_mfu_pct(ctx)

@@ -25,8 +25,12 @@ TYPE_CIFAR = "cifar"
 TYPE_MNIST = "mnist"
 TYPE_TINYIMAGENET = "tiny-imagenet-200"
 TYPE_LOAN = "loan"
+# no reference counterpart: a sparse-expert decoder on packed token sequences
+# (models/lfm2.py; its architecture is the nested `lfm2` key)
+TYPE_LFM2 = "lfm2_moe"
 
 IMAGE_TYPES = (TYPE_CIFAR, TYPE_MNIST, TYPE_TINYIMAGENET)
+TOKEN_TYPES = (TYPE_LFM2,)
 
 # Aggregation method names (reference config.py:4-6).
 AGGR_MEAN = "mean"
@@ -90,6 +94,16 @@ _DEFAULTS: Dict[str, Any] = {
     "results_json": True,
     "random_seed": 1,
     # framework-specific knobs (not in the reference schema)
+    # token workloads (data/tokens.py, ops/triggers.py::build_phrase_bank);
+    # the model's architecture is the nested `lfm2` key (models/lfm2.py)
+    "seq_len": 2048,               # tokens a packed row
+    "sequences_per_client": 4,     # rows a participant holds
+    "test_sequences": 8,           # held-out rows (the global battery)
+    "local_test_sequences": 1,     # of them, rows a local battery reads
+    "token_sources": 20,           # seeded unigram-bigram sources (topics)
+    "doc_len_median": 300,         # log-normal document lengths, cut at a row
+    "trigger_positions": [64],     # where in a row the phrase is written
+    "poison_continuation": [],     # the target tokens behind the phrase
     "compute_dtype": "float32",    # "bfloat16" runs fwd/bwd on the MXU in
                                    # bf16; params/optimizer/aggregation stay
                                    # float32
@@ -365,7 +379,7 @@ class Params:
         if merged["aggregation_methods"] not in AGGR_ALL:
             raise ValueError(
                 f"unknown aggregation_methods: {merged['aggregation_methods']!r}")
-        if merged["type"] not in IMAGE_TYPES + (TYPE_LOAN,):
+        if merged["type"] not in IMAGE_TYPES + (TYPE_LOAN,) + TOKEN_TYPES:
             raise ValueError(f"unknown workload type: {merged['type']!r}")
         if merged["screen_updates"] not in ("auto", True, False):
             raise ValueError(
@@ -502,6 +516,10 @@ class Params:
         return self.type in IMAGE_TYPES
 
     @property
+    def is_tokens(self) -> bool:
+        return self.type in TOKEN_TYPES
+
+    @property
     def aggregation(self) -> str:
         return self.raw["aggregation_methods"]
 
@@ -570,7 +588,9 @@ class Params:
 
     def poison_pattern_for(self, adv_index: int) -> List[List[int]]:
         """Pixel trigger for adversary slot; -1 = union of all sub-patterns
-        (reference image_helper.py:328-335)."""
+        (reference image_helper.py:328-335). For a token workload the same
+        keys hold each adversary's sub-span of the trigger phrase, a list of
+        token ids (ops/triggers.py::build_phrase_bank)."""
         if adv_index == -1:
             pattern: List[List[int]] = []
             for i in range(int(self.raw["trigger_num"])):

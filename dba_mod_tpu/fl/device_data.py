@@ -19,6 +19,7 @@ import numpy as np
 from dba_mod_tpu import config as cfg
 from dba_mod_tpu.data.batching import stack_ragged
 from dba_mod_tpu.data.datasets import ImageData, LoanData
+from dba_mod_tpu.data.tokens import TokenData
 from dba_mod_tpu.ops import triggers
 from dba_mod_tpu.utils import telemetry
 
@@ -112,3 +113,34 @@ def make_loan_device_data(data: LoanData, params: cfg.Params,
                       num_test=sum(len(y) for y in data.test_y),
                       compute_dtype=compute_dtype,
                       train_source=(train_x, train_y))
+
+
+def make_token_device_data(data: TokenData, params: cfg.Params,
+                           compute_dtype=jnp.float32) -> DeviceData:
+    """Token rows: a fetch hands out (rows [B, T] int32, their next-token
+    labels); `stamp` writes the trigger phrase and the target continuation
+    over the rows it poisons and derives the labels again
+    (ops/triggers.py::poison_batch_tokens)."""
+    with telemetry.span("setup/device_put"):
+        train = jnp.asarray(data.train_tokens)
+        test = jnp.asarray(data.test_tokens)
+        bank = tuple(jnp.asarray(a) for a in triggers.build_phrase_bank(
+            params, data.train_tokens.shape[1]))
+        jax.block_until_ready((train, test, bank))
+
+    def fetch_train(slot, idx, source=(train,)):
+        rows = source[0][idx]
+        return rows, triggers.next_token_labels(rows)
+
+    def fetch_test(slot, idx):
+        rows = test[idx]
+        return rows, triggers.next_token_labels(rows)
+
+    def stamp(x, y, adv_index, k, poison_all=False):
+        return triggers.poison_batch_tokens(x, *bank, adv_index, k,
+                                            poison_all)
+
+    return DeviceData(fetch_train, fetch_test, stamp,
+                      num_train=len(data.train_tokens),
+                      num_test=len(data.test_tokens),
+                      compute_dtype=compute_dtype, train_source=(train,))

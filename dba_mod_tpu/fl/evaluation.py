@@ -29,7 +29,7 @@ import numpy as np
 
 from dba_mod_tpu.models import ModelDef, ModelVars
 from dba_mod_tpu.fl.device_data import DeviceData
-from dba_mod_tpu.ops.losses import cross_entropy_sum
+from dba_mod_tpu.ops.losses import batch_scores, cross_entropy_sum
 from dba_mod_tpu.utils import telemetry
 
 
@@ -98,12 +98,8 @@ def make_eval_fn(model_def: ModelDef, data: DeviceData, poison: bool):
             if poison:
                 x, y, _ = data.stamp(x, y, adv_index, 0, poison_all=True)
             logits, _ = model_def.apply(model_vars, x, train=False)
-            bmaskf = bmask.astype(jnp.float32)
-            loss_sum += cross_entropy_sum(logits, y, bmask)
-            preds = jnp.argmax(logits, axis=-1)
-            correct += jnp.sum((preds == y) * bmaskf)
-            count += jnp.sum(bmaskf)
-            return (loss_sum, correct, count), None
+            dl, dc, dn = batch_scores(logits, y, bmask)
+            return (loss_sum + dl, correct + dc, count + dn), None
 
         (loss_sum, correct, count), _ = jax.lax.scan(
             body, (jnp.float32(0), jnp.float32(0), jnp.float32(0)),

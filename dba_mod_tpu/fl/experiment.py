@@ -32,7 +32,8 @@ from dba_mod_tpu.data.partition import (equal_split_indices,
 from dba_mod_tpu.fl import evaluation
 from dba_mod_tpu.fl.client import STEP_CHUNK
 from dba_mod_tpu.fl.device_data import (make_image_device_data,
-                                        make_loan_device_data)
+                                        make_loan_device_data,
+                                        make_token_device_data)
 from dba_mod_tpu.fl.rounds import EvalPlans, RoundEngine
 from dba_mod_tpu.fl.selection import select_agents
 from dba_mod_tpu.fl.state import build_client_tasks
@@ -202,7 +203,8 @@ class Experiment:
         self.plan_rng = np.random.RandomState(seed)
         self.rng_key = jax.random.key(seed)
 
-        self._load_data_and_partition(seed)
+        self.local_eval_plans = None  # a workload may give the local
+        self._load_data_and_partition(seed)  # batteries a shorter plan
 
         # Fixed plan shape across rounds → the jitted round compiles once.
         max_client = max((len(v) for v in self.client_indices.values()),
@@ -332,7 +334,8 @@ class Experiment:
             self.mesh = None
         self.engine = RoundEngine(params, self.model_def, self.device_data,
                                   self.eval_plans, mesh=self.mesh,
-                                  num_segments=self.interval)
+                                  num_segments=self.interval,
+                                  local_plans=self.local_eval_plans)
         # fault-tolerance layer (fl/faults.py; README "Fault model"): the
         # robust round program screens payloads into a survivor mask and the
         # host retries/degrades rounds below. Sequential-debug runs the
@@ -385,7 +388,8 @@ class Experiment:
         # stale replay is faithful; only sidecar-less resumes (pretrain /
         # model-only checkpoints) fall back to the zero delta here.
         self._prev_deltas = None
-        grad_len = int(np.prod(
+        # (a streamed model's round refuses FoolsGold: no memory to keep)
+        grad_len = 0 if self.engine.streamed else int(np.prod(
             self.model_def.similarity_param(self.global_vars.params).shape))
         self.fg_state = foolsgold_init(self.num_participants, grad_len)
         if self.mesh is not None:
@@ -396,6 +400,10 @@ class Experiment:
             self.global_vars = replicate_for_mesh(self.mesh,
                                                   self.global_vars)
             self.fg_state = replicate_for_mesh(self.mesh, self.fg_state)
+        if self.engine.streamed:
+            # allocated once, here; every round takes it and hands it back
+            jax.block_until_ready(
+                self.engine.round_workspace(self.global_vars))
         self.local_eval = bool(params.get("local_eval", True))
         self.last_is_updated = True  # set per-round in finalize_round
         self.last_global_loss = float("inf")  # feeds the best-val checkpoint
@@ -461,6 +469,15 @@ class Experiment:
                                                       compute_dtype=cdtype)
             with telemetry.span("setup/partition"):
                 self._partition_images(data, seed, eb)
+        elif params.is_tokens:
+            from dba_mod_tpu.data.tokens import load_token_dataset
+            with telemetry.span("setup/data"):
+                data = self.token_data = load_token_dataset(
+                    params, self.model_def.vocab_size)
+            self.device_data = make_token_device_data(data, params,
+                                                      compute_dtype=cdtype)
+            with telemetry.span("setup/partition"):
+                self._partition_tokens(data, eb)
         else:
             with telemetry.span("setup/data"):
                 data = self.loan_data = load_loan_dataset(params)
@@ -506,6 +523,32 @@ class Experiment:
             poison_idx=jnp.asarray(poison.idx),
             poison_slots=jnp.zeros_like(jnp.asarray(poison.idx)),
             poison_mask=jnp.asarray(poison.mask))
+
+    def _partition_tokens(self, data, eb: int):
+        """A participant holds its own packed rows (data/tokens.py drew them
+        from its topic mixture). Both global tests read every held-out row
+        (a backdoor test scores the target continuation, so no row is
+        dropped for its label); a local battery reads the first
+        `local_test_sequences` of them."""
+        params = self.params
+        self.client_indices = data.client_rows
+        self.client_slots = {name: 0 for name in data.client_rows}
+        self.num_participants = int(params["number_of_total_participants"])
+        self.participants = (list(range(self.num_participants))
+                             if params["is_random_namelist"]
+                             else list(params["participants_namelist"]))
+        self.benign_names = sorted(
+            set(self.participants) - set(params.adversary_list))
+
+        def plans(rows: int) -> EvalPlans:
+            plan = build_eval_plan(np.arange(rows), eb)
+            idx, mask = jnp.asarray(plan.idx), jnp.asarray(plan.mask)
+            return EvalPlans(idx, jnp.zeros_like(idx), mask,
+                             idx, jnp.zeros_like(idx), mask)
+
+        self.eval_plans = plans(len(data.test_tokens))
+        self.local_eval_plans = plans(
+            min(int(params["local_test_sequences"]), len(data.test_tokens)))
 
     def _partition_loan(self, data, eb: int):
         params = self.params
@@ -679,6 +722,14 @@ class Experiment:
                     mask_list = [np.pad(m, ((0, pad),) + ((0, 0),) * 3)
                                  for m in mask_list]
                     num_samples_np = np.pad(num_samples_np, (0, pad))
+            if self.engine.streamed:
+                # what a streamed step is made of: its rows' positions, and
+                # the client-steps the round's one-after-another loop runs
+                steps = int(sum(m.any(axis=-1).sum() for m in mask_list))
+                plan_span.count(
+                    tokens_step=int(np.prod(self.model_def.input_shape)
+                                    * int(params["batch_size"])),
+                    client_steps=steps)
             plan_span.count(
                 **plan_step_counts(
                     mask_list, STEP_CHUNK,
@@ -731,9 +782,17 @@ class Experiment:
             # reuse the consumed state buffers in place)
             rf = (self.engine.round_fn_donated if self._use_donated_round
                   else self.engine.round_fn)
-            new_vars, new_fg, payload = rf(
-                self.global_vars, self.fg_state, tasks_seq, idx_seq,
-                mask_seq, lane, ns_dev, rng_train, rng_agg)
+            if self.engine.streamed:
+                (new_vars, new_fg, self.engine.workspace,
+                 payload) = rf(
+                    self.global_vars, self.fg_state,
+                    self.engine.round_workspace(self.global_vars), tasks_seq,
+                    idx_seq, mask_seq, lane, ns_dev, rng_train, rng_agg,
+                    self.device_data.train_source)
+            else:
+                new_vars, new_fg, payload = rf(
+                    self.global_vars, self.fg_state, tasks_seq, idx_seq,
+                    mask_seq, lane, ns_dev, rng_train, rng_agg)
             rolled = False
             if self._sentinel is not None:
                 new_vars, payload, rolled = self._health_gate(
@@ -1128,9 +1187,11 @@ class Experiment:
         # zone (run_guard.py)
         with self.guard.watch("round/finalize"), \
                 telemetry.span("round/fetch", round=fl.epoch):
+            payload = jax.device_get(fl.payload)
             (locals_, globals_, metrics, delta_norms, wv, alpha,
-             batches, is_updated, seg_locals, rstats,
-             fstats) = jax.device_get(fl.payload)
+             batches, is_updated, seg_locals, rstats, fstats) = payload[:11]
+            # a streamed round appends what its model counted of its own work
+            counts = payload[11] if len(payload) > 11 else None
         finalize_time = time.perf_counter() - t_fin
         # perf_counter durations (the old time.time() delta could jump under
         # clock adjustments); under pipeline_rounds round_time spans the
@@ -1172,7 +1233,13 @@ class Experiment:
             robust["n_dropped"] = int(rstats.n_dropped)
             robust["degraded"] = (bool(rstats.degraded)
                                   or bool(fl.forced_degraded))
-        with telemetry.span("round/record", round=fl.epoch):
+        with telemetry.span("round/record", round=fl.epoch) as record_span:
+            if counts is not None and int(counts.cells):
+                record_span.count(
+                    expert_tokens_held=int(counts.held),
+                    expert_tokens_max=int(counts.max),
+                    expert_tokens_mean=float(counts.held)
+                    / int(counts.cells))
             self._record(fl.epoch, fl.seg_epochs, fl.agent_names,
                          fl.adv_names, fl.tasks_list, metrics, locals_,
                          globals_, delta_norms, wv, alpha, times, batches,

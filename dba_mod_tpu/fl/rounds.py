@@ -365,11 +365,17 @@ def make_local_battery(model_def: ModelDef, data: DeviceData,
 def wide_from_of(model_def: ModelDef, mesh, lanes: int) -> int:
     """The client step's `wide_from` (fl/client.py::split_steps): the number
     of live lanes from which a step runs at full width. Set here, at build,
-    from the model's parameter shapes and the mesh alone — no knob, and
-    nothing timed: a job's steps and the full-width loop's are not bit-equal
-    on the chip, so a program chosen by a stopwatch would move a run's
-    numerics with the machine's noise.
+    from the model's kind, its parameter shapes and the mesh alone — no knob,
+    and nothing timed: a job's steps and the full-width loop's are not
+    bit-equal on the chip, so a program chosen by a stopwatch would move a
+    run's numerics with the machine's noise.
 
+    - A streamed model (`ModelDef.streamed`, fl/streamed.py): `lanes + 1`.
+      Its round has no lanes to be wide over: the clients run one after
+      another at width 1 by construction, and the number only tells the
+      plan's counts that every real step is a client's own. The shapes are
+      not asked: the rank-4 test below never sees such a model (a short
+      convolution's depthwise kernel is rank 2), and need not.
     - A sharded clients axis: 1, the full-width loop alone. A lane a device
       is the point there, and taking one lane out of a sharded stack is a
       collective nobody has priced.
@@ -380,6 +386,8 @@ def wide_from_of(model_def: ModelDef, mesh, lanes: int) -> int:
       more than `lanes` width-1 steps (PERF.md section 7, PR 31's table).
     - Unsharded, dense layers only: 2. Stacked lanes make a batched matmul
       the chip runs well; only one lane's tail leaves the full-width loop."""
+    if model_def.streamed:
+        return lanes + 1
     if mesh is not None:
         return 1
     shapes = jax.eval_shape(lambda: model_def.init_vars(jax.random.key(0)))
@@ -391,23 +399,49 @@ def wide_from_of(model_def: ModelDef, mesh, lanes: int) -> int:
 class RoundEngine:
     """Holds the jitted round + eval computations for one experiment config.
 
+    Two forms of the round program, chosen by the model's kind alone: the
+    stacked round below (C clients at once on a clients axis), and for a
+    model too large to stack (`ModelDef.streamed`) the streamed round of
+    fl/streamed.py, whose feed adds the engine's `workspace` and the
+    population (`round_workspace`).
+
     With a mesh, the stacked clients axis is sharded across devices (GSPMD via
     jit in_shardings): each device trains its clients locally and the
     aggregation reductions lower to ICI collectives (SURVEY §2.2)."""
 
     def __init__(self, params: cfg.Params, model_def: ModelDef,
                  data: DeviceData, plans: EvalPlans, mesh=None,
-                 num_segments: int = 1):
+                 num_segments: int = 1,
+                 local_plans: Optional[EvalPlans] = None):
         # one span around the whole host-side build (tracing the jit
         # wrappers is free — XLA compiles lazily on first call; those
         # compiles land in the xla/compiles counter via the monitoring
         # listener, not here)
         with telemetry.span("engine/build"):
-            self._build(params, model_def, data, plans, mesh, num_segments)
+            self._build(params, model_def, data, plans, mesh, num_segments,
+                        local_plans or plans)
+
+    def round_workspace(self, global_vars: ModelVars):
+        """The streamed round's workspace: made at build, handed to every
+        round and taken back from it (`self.workspace`); made again after
+        `release_workspace`."""
+        if self.workspace is None:
+            from dba_mod_tpu.fl.streamed import make_workspace
+            self.workspace = jax.jit(make_workspace)(global_vars)
+        return self.workspace
+
+    def release_workspace(self) -> None:
+        """Give the workspace's device memory back (three copies of the
+        model) while no round is in flight; the next round makes it anew."""
+        if self.workspace is not None:
+            jax.block_until_ready(self.workspace)
+            for leaf in jax.tree_util.tree_leaves(self.workspace):
+                leaf.delete()
+            self.workspace = None
 
     def _build(self, params: cfg.Params, model_def: ModelDef,
                data: DeviceData, plans: EvalPlans, mesh,
-               num_segments: int):
+               num_segments: int, local_plans: EvalPlans):
         self.params = params
         self.hyper = RoundHyper.from_params(params)
         self.model_def = model_def
@@ -438,6 +472,15 @@ class RoundEngine:
         fu = params.get("fused_updates", "auto")
         fused_pallas = bool(fu) if fu != "auto" else (
             mesh is None and jax.default_backend() == "tpu")
+        # a streamed model's round (fl/streamed.py) has no stacked lanes
+        # for the kernel to fuse over
+        self.streamed = bool(model_def.streamed)
+        if self.streamed:
+            from dba_mod_tpu.fl import streamed
+            streamed.refuse(params, mesh, num_segments, self.robust,
+                            forensics_on, hyper.track_batches)
+            fused_pallas = False
+        self.workspace = None
         self.fused_pallas = fused_pallas
         self.fused_interpret = bool(params.get("fused_interpret", False))
         # from how many live lanes a step runs at full width
@@ -706,11 +749,15 @@ class RoundEngine:
                                      plans.poison_slots, plans.poison_mask,
                                      jnp.int32(-1))
                 if n_triggers > 0:
-                    per_trigger = jax.vmap(
-                        lambda t: eval_poison(model_vars, plans.poison_idx,
-                                              plans.poison_slots,
-                                              plans.poison_mask,
-                                              t))(trigger_ids)
+                    one = lambda t: eval_poison(
+                        model_vars, plans.poison_idx, plans.poison_slots,
+                        plans.poison_mask, t)
+                    # a streamed model's triggers one after another: under
+                    # `vmap` a `lax.cond` in the model (models/lfm2.py's
+                    # expert layer) becomes a select that runs both paths
+                    per_trigger = (jax.lax.map(one, trigger_ids)
+                                   if model_def.streamed
+                                   else jax.vmap(one)(trigger_ids))
                 else:
                     zero = EvalResult(*(jnp.float32(0),) * 4)
                     per_trigger = jax.tree_util.tree_map(
@@ -945,6 +992,21 @@ class RoundEngine:
             return _round(global_vars, fg_state, tasks_seq, idx_seq,
                           mask_seq, lane, num_samples, rng_t, rng_a,
                           rng_f, prev_deltas, norm_mult, with_evals=False)
+
+        if self.streamed:
+            # the same round for a model that cannot be stacked: the
+            # clients one after another inside one program, the workspace
+            # (argument 2) donated with the state
+            round_fn = streamed.make_streamed_round(
+                model_def, data, hyper, plans, local_plans, global_evals,
+                is_poison_run, bool(params["baseline"]), do_local_eval)
+            self.round_fn = jax.jit(round_fn)
+            self.core_fn = None
+            self.round_fn_donated = (
+                jax.jit(round_fn, donate_argnums=(0, 1, 2))
+                if jax.default_backend() != "cpu" else None)
+            self.forensic_fn = None
+            return
 
         if mesh is not None:
             from dba_mod_tpu.parallel.mesh import (client_sharding,

@@ -7,7 +7,9 @@ records the per-model metadata the framework needs:
   "second-to-last named parameter" (helper.py:537) — for every reference model
   that is the final linear layer's weight;
 - `has_batch_stats` / `has_dropout`: which extra variable collections / RNG
-  streams the train step must thread.
+  streams the train step must thread;
+- the *form* of a sample (`ModelDef.form`): what the data layer hands the
+  model and what the loss scores.
 
 Models are pure architectures; the reference's visdom-plotting mixin
 (models/simple.py:18-200) is deliberately not carried over (observability lives in
@@ -16,13 +18,14 @@ Models are pure architectures; the reference's visdom-plotting mixin
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from dba_mod_tpu import config as cfg
+from dba_mod_tpu.models.lfm2 import Lfm2Config, Lfm2Moe, seed_expert_bias
 from dba_mod_tpu.models.loan import LoanNet
 from dba_mod_tpu.models.mnist import MnistNet
 from dba_mod_tpu.models.resnet import cifar_resnet18, tiny_resnet18
@@ -39,21 +42,55 @@ class ModelVars(NamedTuple):
     batch_stats: Any  # empty dict for models without BN
 
 
+FORM_IMAGE = "image"      # float [B, H, W, C] (or [B, F] feature rows), one
+                          # class label a row out of `num_classes`
+FORM_TOKENS = "tokens"    # int32 [B, T] rows of token ids (negative: padding),
+                          # labels [B, T] the next tokens out of `vocab_size`,
+                          # -1 where a position is not scored
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelDef:
+    """One workload's model and what the framework has to know of it.
+
+    Two forms of a sample (`form`). The image form (the reference's four
+    workloads): `input_shape` is one sample, NHWC or a feature row, and the
+    model gives `num_classes` logits a row. The token form: `input_shape` is
+    `(seq_len,)`, a row holds token ids, the model gives `vocab_size` logits
+    a position, a row's labels are its next tokens, and `num_classes` is 0
+    (it is no class count; ops/losses.py scores both forms).
+
+    `streamed`: the state is too large to stack a copy a client; the round
+    engine then trains the round's clients one after another and accumulates
+    FedAvg in place (fl/streamed.py)."""
     name: str
     module: nn.Module
-    input_shape: Tuple[int, ...]   # one sample, NHWC / features
-    num_classes: int
+    input_shape: Tuple[int, ...]   # one sample: NHWC / features / (seq_len,)
+    num_classes: int               # image form; 0 for the token form
     similarity_path: Tuple[str, ...]
     has_batch_stats: bool
     has_dropout: bool
+    form: str = FORM_IMAGE
+    vocab_size: int = 0            # token form: logits a position
+    streamed: bool = False
+    # what the model's non-gradient state starts as, where zeros would not
+    # do: batch_stats tree, rng -> batch_stats tree
+    stats_init: Optional[Callable[[Any, jax.Array], Any]] = None
 
     def init_vars(self, rng: jax.Array) -> ModelVars:
-        dummy = jnp.zeros((1,) + self.input_shape, jnp.float32)
-        variables = self.module.init(rng, dummy, train=False)
-        return ModelVars(params=variables["params"],
-                         batch_stats=variables.get("batch_stats", {}))
+        def init(rng, dummy):
+            variables = self.module.init(rng, dummy, train=False)
+            stats = variables.get("batch_stats", {})
+            if self.stats_init is not None:
+                stats = self.stats_init(stats, jax.random.fold_in(rng, 1))
+            return ModelVars(params=variables["params"], batch_stats=stats)
+
+        if self.form == FORM_TOKENS:
+            # parameter shapes do not depend on the row's length: a short
+            # row, and one compiled call instead of an eager forward pass
+            dummy = jnp.zeros((1, min(self.input_shape[0], 8)), jnp.int32)
+            return jax.jit(init)(rng, dummy)
+        return init(rng, jnp.zeros((1,) + self.input_shape, jnp.float32))
 
     def apply(self, model_vars: ModelVars, x, train: bool,
               dropout_rng: jax.Array | None = None):
@@ -71,6 +108,22 @@ class ModelDef:
             return logits, updates["batch_stats"]
         logits = self.module.apply(variables, x, train=train, rngs=rngs)
         return logits, model_vars.batch_stats
+
+    def apply_counted(self, model_vars: ModelVars, x,
+                      dropout_rng: jax.Array | None = None):
+        """Train-mode forward that also hands out what the model counted of
+        its own work in the `counters` collection (lfm2: the tokens each held
+        expert was given, a layer a key): (logits, new_batch_stats, counters),
+        the last `{}` for a model that counts nothing."""
+        variables = {"params": model_vars.params}
+        if self.has_batch_stats:
+            variables["batch_stats"] = model_vars.batch_stats
+        rngs = {"dropout": dropout_rng} if self.has_dropout else None
+        logits, updates = self.module.apply(
+            variables, x, train=True, rngs=rngs,
+            mutable=["batch_stats", "counters"])
+        return (logits, updates.get("batch_stats", model_vars.batch_stats),
+                updates.get("counters", {}))
 
     def similarity_param(self, params) -> jax.Array:
         p = params
@@ -113,4 +166,14 @@ def build_model(params: cfg.Params) -> ModelDef:
                         input_shape=(91,), num_classes=9,
                         similarity_path=("Dense_2", "kernel"),
                         has_batch_stats=False, has_dropout=True)
+    if t == cfg.TYPE_LFM2:
+        arch = Lfm2Config.from_dict(params["lfm2"])
+        return ModelDef(name="Lfm2Moe", module=Lfm2Moe(arch, dtype=dtype),
+                        input_shape=(int(params["seq_len"]),), num_classes=0,
+                        similarity_path=("embedding",),
+                        has_batch_stats=arch.use_expert_bias
+                        and arch.num_expert_layers > 0,
+                        has_dropout=False, form=FORM_TOKENS,
+                        vocab_size=arch.vocab_size, streamed=True,
+                        stats_init=seed_expert_bias)
     raise ValueError(f"unknown workload type {t!r}")

@@ -5,6 +5,11 @@ Reference semantics preserved:
   default, image_train.py:85); with padded batches we mean over valid entries;
 - the anomaly-evading blended loss is α·CE + (1-α)·‖w - w_global‖₂
   (image_train.py:87-90; note: the L2 *norm*, not its square);
+- a token model's labels are [B, T] next tokens, -1 where a position is not
+  scored (the last of a row, padding, and in a backdoor test everything but
+  the target continuation): `batch_loss` and `batch_scores` take both forms,
+  a row of the image form counting as one prediction and a row of the token
+  form as its scored positions;
 - distance/global norms run over trainable parameters only — torch
   named_parameters excludes BN running stats but includes BN affine γ/β
   (helper.py:59-71, :110-123).
@@ -42,6 +47,42 @@ def cross_entropy_sum(logits: jax.Array, labels: jax.Array,
     if mask is not None:
         nll = nll * mask.astype(nll.dtype)
     return jnp.sum(nll)
+
+
+def token_nll(logits: jax.Array, labels: jax.Array):
+    """Token form: logits [B, T, V], labels [B, T] with -1 where a position
+    is not scored -> (nll [B, T], 0 where unscored; scored [B, T] float32)."""
+    scored = labels >= 0
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None],
+                               axis=-1)[..., 0]
+    return jnp.where(scored, nll, 0.0), scored.astype(jnp.float32)
+
+
+def batch_loss(logits: jax.Array, labels: jax.Array, mask: jax.Array):
+    """The training loss of one batch, either form: the mean over the valid
+    rows (image form: `cross_entropy`), or over the scored positions of the
+    valid rows (token form)."""
+    if labels.ndim == 1:
+        return cross_entropy(logits, labels, mask)
+    nll, scored = token_nll(logits, labels)
+    w = scored * mask[:, None].astype(jnp.float32)
+    return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
+
+
+def batch_scores(logits: jax.Array, labels: jax.Array, mask: jax.Array):
+    """What one batch adds to an accuracy count, either form: (summed loss,
+    predictions right, predictions counted) over the valid rows — rows of the
+    image form, scored positions of the token form."""
+    if labels.ndim == 1:
+        maskf = mask.astype(jnp.float32)
+        loss_sum = cross_entropy_sum(logits, labels, mask)
+        preds = jnp.argmax(logits, axis=-1)
+        return loss_sum, jnp.sum((preds == labels) * maskf), jnp.sum(maskf)
+    nll, scored = token_nll(logits, labels)
+    w = scored * mask[:, None].astype(jnp.float32)
+    preds = jnp.argmax(logits, axis=-1)
+    return jnp.sum(nll * w), jnp.sum((preds == labels) * w), jnp.sum(w)
 
 
 def tree_dist_norm(params: Any, target_params: Any):

@@ -15,6 +15,14 @@ The reference stamps pixel patterns per-sample in a Python loop
   `poisoning_per_batch` samples of each batch, evaluation poisons all
   (image_helper.py:306-319).
 
+- a *phrase bank* for token sequences, the DBA analogue for text: a trigger
+  phrase split into `trigger_num` sub-spans, adversary `i` writing only its
+  own span at its own place in the phrase and the test all of them; a fixed
+  target continuation right behind the phrase takes the place of the swapped
+  label. `[trigger_num + 1, T]` token values plus {0,1} masks over the
+  positions of a row, like the feature bank; the continuation has a row of
+  values and a mask of its own, stamped by every adversary.
+
 All functions take the bank + a traced `adv_index` so one jitted computation
 serves every adversary; index -1 (mapped to the last bank row) is the global
 pattern.
@@ -126,3 +134,72 @@ def poison_batch_features(rows: jax.Array, labels: jax.Array,
     new_rows = jnp.where(sel[:, None], stamped, rows)
     new_labels = jnp.where(sel, poison_label_swap, labels)
     return new_rows, new_labels, sel
+
+
+# ------------------------------------------------------------------ token rows
+def build_phrase_bank(params: cfg.Params, seq_len: int):
+    """Token sequences: (values [n + 1, T] int32, masks [n + 1, T] bool,
+    target_values [T] int32, target_mask [T] bool). The trigger phrase is the
+    sub-spans `<i>_poison_pattern` (lists of token ids) one behind the other;
+    it is written at every position of `trigger_positions`, the target
+    continuation `poison_continuation` right behind it. Row i holds adversary
+    i's span alone, row n the whole phrase."""
+    n = int(params["trigger_num"])
+    spans = [[int(t) for t in params.poison_pattern_for(i)] for i in range(n)]
+    target = [int(t) for t in params["poison_continuation"]]
+    phrase_len = sum(len(s) for s in spans)
+    values = np.zeros((n + 1, seq_len), np.int32)
+    masks = np.zeros((n + 1, seq_len), bool)
+    target_values = np.zeros((seq_len,), np.int32)
+    target_mask = np.zeros((seq_len,), bool)
+    for start in params["trigger_positions"]:
+        start = int(start)
+        if start < 0 or start + phrase_len + len(target) > seq_len:
+            raise ValueError(
+                f"trigger position {start}: phrase and continuation "
+                f"({phrase_len} + {len(target)} tokens) do not fit a row of "
+                f"{seq_len}")
+        at = start
+        for i, span in enumerate(spans):
+            for row in (i, n):
+                values[row, at:at + len(span)] = span
+                masks[row, at:at + len(span)] = True
+            at += len(span)
+        target_values[at:at + len(target)] = target
+        target_mask[at:at + len(target)] = True
+    return values, masks, target_values, target_mask
+
+
+def next_token_labels(rows: jax.Array, only=None) -> jax.Array:
+    """rows [..., T] token ids (negative: padding) -> labels [..., T]: the
+    next token, -1 (not scored) at a row's last position and where the next
+    token is padding; with `only` ([T] bool) also wherever the next position
+    is outside it."""
+    nxt = jnp.concatenate([rows[..., 1:], jnp.full_like(rows[..., :1], -1)],
+                          axis=-1)
+    if only is not None:
+        keep = jnp.concatenate([only[1:], jnp.zeros((1,), bool)])
+        nxt = jnp.where(keep, nxt, -1)
+    return jnp.where(nxt >= 0, nxt, -1)
+
+
+def poison_batch_tokens(rows: jax.Array, values: jax.Array, masks: jax.Array,
+                        target_values: jax.Array, target_mask: jax.Array,
+                        adv_index, poisoning_per_batch, poison_all=False):
+    """Token counterpart of :func:`poison_batch`: the first
+    `poisoning_per_batch` rows (all if `poison_all`) get trigger `adv_index`
+    and the target continuation written over their tokens; padding is never
+    written over. Labels are the next tokens of the rows as stamped: a
+    training row scores every position (the adversary trains on the whole
+    poisoned sequence), a test row (`poison_all`, a Python bool) only the
+    continuation.
+    Returns (rows, labels, per-row poisoned mask)."""
+    batch = rows.shape[0]
+    sel = jnp.where(poison_all, jnp.ones((batch,), bool),
+                    jnp.arange(batch) < poisoning_per_batch)
+    k = bank_row(adv_index, values.shape[0])
+    stamped = jnp.where(masks[k], values[k], rows)
+    stamped = jnp.where(target_mask, target_values, stamped)
+    new_rows = jnp.where(sel[:, None] & (rows >= 0), stamped, rows)
+    labels = next_token_labels(new_rows, target_mask if poison_all else None)
+    return new_rows, labels, sel
