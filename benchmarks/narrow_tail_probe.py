@@ -93,23 +93,40 @@ def load_config(path, workload):
             traffic, None)
 
 
+def rehearsal_cut(config):
+    """The benchmark's own cuts of size for a `--rehearse` on the CPU."""
+    from chipbench import run as harness
+    rehearsal = json.loads((harness.HERE / "rehearsal.json").read_text())
+    cut = {**rehearsal["cut"],
+           **rehearsal["by_type"].get(config["params"]["type"], {})}
+    if config["params"]["type"] == "loan":   # its clients are states
+        cut = {k: cut[k] for k in ("batch_size", "test_batch_size",
+                                   "no_models", "scale_weights_poison")}
+    return cut
+
+
+def engine_under(exp, rule: str, answer):
+    """An engine beside the experiment's own, on its data, built with
+    `fl/rounds.py::<rule>` answering `answer` whatever the model."""
+    import dba_mod_tpu.fl.rounds as rounds_mod
+    kept = getattr(rounds_mod, rule)
+    setattr(rounds_mod, rule, lambda *_: answer)
+    try:
+        return rounds_mod.RoundEngine(
+            exp.params, exp.model_def, exp.device_data, exp.eval_plans,
+            mesh=None, num_segments=exp.interval)
+    finally:
+        setattr(rounds_mod, rule, kept)
+
+
 def engines_of(exp):
     """{wide_from: engine} for 2 and C + 1 on the experiment's own data: the
     engine it was built with, and one built beside it under the other rule."""
-    import dba_mod_tpu.fl.rounds as rounds_mod
     C = exp.engine.hyper.no_models
     found = {exp.engine.wide_from: exp.engine}
-    rule = rounds_mod.wide_from_of
     for want in (2, C + 1):
-        if want in found:
-            continue
-        rounds_mod.wide_from_of = lambda *_, want=want: want
-        try:
-            found[want] = rounds_mod.RoundEngine(
-                exp.params, exp.model_def, exp.device_data, exp.eval_plans,
-                mesh=None, num_segments=exp.interval)
-        finally:
-            rounds_mod.wide_from_of = rule
+        if want not in found:
+            found[want] = engine_under(exp, "wide_from_of", want)
     return found[2], found[C + 1]
 
 
@@ -157,16 +174,10 @@ def probe(args, config, traffic, lim) -> bool:
     from dba_mod_tpu.data.batching import plan_step_counts
     from dba_mod_tpu.fl.client import STEP_CHUNK, split_steps
 
-    cut = None
-    if args.rehearse:
-        rehearsal = json.loads((harness.HERE / "rehearsal.json").read_text())
-        cut = {**rehearsal["cut"],
-               **rehearsal["by_type"].get(config["params"]["type"], {})}
-        if config["params"]["type"] == "loan":   # its clients are states
-            cut = {k: cut[k] for k in ("batch_size", "test_batch_size",
-                                       "no_models", "scale_weights_poison")}
     first = harness.FIRST_WINDOW_EPOCH
-    params, raw = program.make_params(config, traffic, OUT, first, cut)
+    params, raw = program.make_params(
+        config, traffic, OUT, first,
+        rehearsal_cut(config) if args.rehearse else None)
     exp, build_s = program.build_experiment(params)
     eng_2, eng_jobs = engines_of(exp)
     C = exp.engine.hyper.no_models

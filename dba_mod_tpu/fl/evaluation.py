@@ -14,10 +14,14 @@ images dropped (image_helper.py:148-172), expressed in the eval plan's index
 set; the LOAN branches iterate every state shard (test.py:13-24) — here the
 plan concatenates all shards with a per-row slot array.
 
-Local (per-client) clean evals vmap the kernel's forward pass over stacked
-client models — ten models' test passes in one XLA computation instead of the
-reference's sequential loop; the poisoned local evals run the kernel on one
-client model per recorded row (`local_battery_jobs`).
+Local (per-client) evals run the kernel on one client model per recorded row,
+one model after another inside the round program (fl/rounds.py::
+make_local_battery; which poisoned rows are recorded: `local_battery_jobs`).
+The clean rows of a dense model, and of any model on a sharded clients axis,
+instead vmap the kernel's forward pass over the stacked client models
+(`make_stacked_eval_fn`): stacked lanes are a batched matmul there, or a lane
+a device; stacked convolutions cost more than one model after another
+(fl/rounds.py::lanes_as_jobs).
 """
 from __future__ import annotations
 
@@ -121,9 +125,15 @@ def make_stacked_eval_fn(model_def: ModelDef, data: DeviceData):
     vmap: one gather per batch, shared by all C models; only the forward
     passes are batched over clients. Numerics are bit-identical to vmapping
     :func:`make_eval_fn` — same ops, same per-client accumulation order
-    (tests/test_eval_stacked.py). The poisoned tests of single client models
-    go through :func:`make_eval_fn` itself, one recorded row at a time
-    (:func:`local_battery_jobs`)."""
+    (tests/test_eval_stacked.py).
+
+    Called by fl/rounds.py::make_local_battery for the clean part where
+    fl/rounds.py::lanes_as_jobs says no: a model of dense layers only (its
+    stacked step is a batched matmul, 2.6 single-model steps at 10 lanes),
+    and any model on a sharded clients axis (a lane a device). A model with a
+    convolution on one device runs its clean tests, like every poisoned
+    test, through :func:`make_eval_fn` one model at a time: ten models
+    stacked cost 13-40 single-model steps there (PERF.md section 7)."""
 
     def evaluate_stacked(stacked_vars: ModelVars, idx, slots,
                          mask) -> EvalResult:
@@ -186,14 +196,17 @@ def job_order(wanted):
 
 
 def battery_eval_counts(tasks_list, is_poison_run: bool, baseline: bool,
-                        forensics: bool) -> Dict[str, int]:
+                        forensics: bool, clean_jobs: bool) -> Dict[str, int]:
     """What a round's tasks ask of the local batteries, counted on the host
     from the task rows the program reads (one ClientTask of [C] numpy leaves
     per segment; every segment runs a battery, the last one gating on the
     whole round's rows and alone serving `forensics`): `battery_evals_run`
     the single-model tests that run (the clean test of every lane plus
     :func:`local_battery_jobs`) over `battery_evals_plan`, all four parts
-    (the clean one alone outside an attack run) for every lane."""
+    (the clean one alone outside an attack run) for every lane. Of the
+    `battery_clean_evals` clean tests, `battery_clean_jobs` run as
+    single-model jobs, one model after another (all with the engine's
+    `clean_jobs`, none where the stacked `vmap` runs them)."""
     rows = [np.stack([getattr(t, f) for t in tasks_list]) for f in
             ("poisoning_per_batch", "adv_slot", "num_epochs")]
     n_seg, lanes = rows[0].shape
@@ -204,4 +217,6 @@ def battery_eval_counts(tasks_list, is_poison_run: bool, baseline: bool,
             *(r if last else r[s:s + 1] for r in rows), baseline,
             forensics and last))
     return {"battery_evals_run": run,
-            "battery_evals_plan": (4 if is_poison_run else 1) * n_seg * lanes}
+            "battery_evals_plan": (4 if is_poison_run else 1) * n_seg * lanes,
+            "battery_clean_evals": n_seg * lanes,
+            "battery_clean_jobs": n_seg * lanes if clean_jobs else 0}

@@ -736,7 +736,8 @@ class Experiment:
                     1 if self.sequential_debug else self.engine.wide_from),
                 **(evaluation.battery_eval_counts(
                     tasks_list, self.is_poison_run, bool(params["baseline"]),
-                    self.engine.forensics) if self.local_eval else {}))
+                    self.engine.forensics, self.engine.clean_jobs)
+                   if self.local_eval else {}))
 
         with telemetry.span("round/stage", round=epoch):
             tasks_seq = jax.tree_util.tree_map(

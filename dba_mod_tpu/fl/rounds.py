@@ -301,24 +301,37 @@ class EvalPlans:
 
 
 def make_local_battery(model_def: ModelDef, data: DeviceData,
-                       plans: EvalPlans, is_poison_run: bool, baseline: bool):
+                       plans: EvalPlans, is_poison_run: bool, baseline: bool,
+                       clean_jobs: bool):
     """battery(unscaled [C, ...], scaled [C, ...], tasks ([I, C] rows of the
     segments whose flags gate the poison parts), forensics) -> LocalEvals
     with [C] leaves: clean on the pre-scaling model (image_train.py:150-155,
     :268-271), poison pre on it (:157-164), poison post + per-agent trigger
     on the submitted one (:275-282, :291-295).
 
-    Clean part: every client's row is recorded, and the C models share ONE
-    eval plan, so the batch fetch is hoisted out of the model vmap (one
-    gather per batch instead of C). Poison parts: only a poisoning client's
-    model is tested on poisoned data, so they run as a list of single-model
-    jobs, one per row the recorder writes (`local_battery_jobs`), read from
-    the round's tasks inside the program: job id = part * C + lane, the
-    loop's trip count the number of jobs (0 in a clean round), a slot with no
-    job left at zeros (count 0). The eval inside a job is a scan of static
-    length over the poison plan."""
+    Clean part: every client's row is recorded. With `clean_jobs` (the
+    engine's `lanes_as_jobs`: an unsharded model with a convolution, where
+    a C-model `vmap` step costs more than C single-model steps) the C models
+    go one after another through the single-model test, a loop of static
+    length C around `make_eval_fn`'s scan over the clean plan. Without it
+    (dense layers, or a sharded clients axis) they share ONE eval plan under
+    `make_stacked_eval_fn`'s `vmap`, the batch fetch hoisted out of it (one
+    gather per batch instead of C). Either way one loop that reads the test
+    set, beside the poison parts': the executable carries the test images
+    once a loop, and a conditional between the two tests inside one job
+    loop carried them four times more (PR 36, on the chip).
+
+    Poison parts: only a poisoning client's model is tested on poisoned
+    data, so they run as a list of single-model jobs, one per row the
+    recorder writes (`local_battery_jobs`), read from the round's tasks
+    inside the program: job id = part * C + lane, the loop's trip count the
+    number of jobs (0 in a clean round), a slot with no job left at zeros
+    (count 0). The eval inside a job is a scan of static length over the
+    poison plan."""
     eval_clean_s = make_stacked_eval_fn(model_def, data)
+    eval_clean = make_eval_fn(model_def, data, poison=False)
     eval_poison = make_eval_fn(model_def, data, poison=True)
+    clean_plan = (plans.clean_idx, plans.clean_slots, plans.clean_mask)
 
     def poison_jobs(unscaled: ModelVars, scaled: ModelVars, adv_slots,
                     wanted):
@@ -345,8 +358,12 @@ def make_local_battery(model_def: ModelDef, data: DeviceData,
 
     def battery(unscaled: ModelVars, scaled: ModelVars, tasks: ClientTask,
                 forensics: bool) -> LocalEvals:
-        clean = eval_clean_s(unscaled, plans.clean_idx, plans.clean_slots,
-                             plans.clean_mask)
+        if clean_jobs:
+            clean = jax.lax.map(
+                lambda model: eval_clean(model, *clean_plan, jnp.int32(-1)),
+                unscaled)
+        else:
+            clean = eval_clean_s(unscaled, *clean_plan)
         if is_poison_run:
             pre, post, agent = poison_jobs(
                 unscaled, scaled, tasks.adv_slot[-1],
@@ -362,38 +379,51 @@ def make_local_battery(model_def: ModelDef, data: DeviceData,
     return battery
 
 
+def lanes_as_jobs(model_def: ModelDef, mesh) -> bool:
+    """Whether the stacked round runs each lane's work as a single-model
+    job and not under a `vmap` over the lanes: the one observation both the
+    client step (`wide_from_of`) and the local battery's clean part
+    (`make_local_battery`) adapt to. Read at build from the mesh and the
+    model's parameter shapes alone: no knob, no model's name, nothing timed.
+
+    - A sharded clients axis: no. A lane a device is the point there, and
+      taking one lane out of a sharded stack is a collective nobody has
+      priced.
+    - Unsharded, a model with a convolution (some parameter leaf is a rank-4
+      kernel): yes. `vmap` over the lanes turns each convolution into one
+      with `lanes` sets of weights plus layout copies between the lanes and
+      batch axes, and a full-width step costs more than `lanes` width-1
+      steps, training (13.6-19.4 of them at 10 lanes) and evaluating (PERF.md
+      section 7's tables).
+    - Unsharded, dense layers only: no. Stacked lanes make a batched matmul
+      the chip runs well (2.25 width-1 steps)."""
+    if mesh is not None:
+        return False
+    shapes = jax.eval_shape(lambda: model_def.init_vars(jax.random.key(0)))
+    return any(l.ndim == 4 for l in jax.tree_util.tree_leaves(shapes.params))
+
+
 def wide_from_of(model_def: ModelDef, mesh, lanes: int) -> int:
     """The client step's `wide_from` (fl/client.py::split_steps): the number
     of live lanes from which a step runs at full width. Set here, at build,
-    from the model's kind, its parameter shapes and the mesh alone — no knob,
-    and nothing timed: a job's steps and the full-width loop's are not
-    bit-equal on the chip, so a program chosen by a stopwatch would move a
-    run's numerics with the machine's noise.
+    from the model's kind and `lanes_as_jobs` alone — no knob, and nothing
+    timed: a job's steps and the full-width loop's are not bit-equal on the
+    chip, so a program chosen by a stopwatch would move a run's numerics
+    with the machine's noise.
 
     - A streamed model (`ModelDef.streamed`, fl/streamed.py): `lanes + 1`.
       Its round has no lanes to be wide over: the clients run one after
       another at width 1 by construction, and the number only tells the
       plan's counts that every real step is a client's own. The shapes are
-      not asked: the rank-4 test below never sees such a model (a short
+      not asked: the rank-4 test never sees such a model (a short
       convolution's depthwise kernel is rank 2), and need not.
-    - A sharded clients axis: 1, the full-width loop alone. A lane a device
-      is the point there, and taking one lane out of a sharded stack is a
-      collective nobody has priced.
-    - Unsharded, a model with a convolution (some parameter leaf is a rank-4
-      kernel): `lanes + 1`, every lane a job. `vmap` over the lanes turns
-      each convolution into one with `lanes` sets of weights plus layout
-      copies between the lanes and batch axes, and a full-width step costs
-      more than `lanes` width-1 steps (PERF.md section 7, PR 31's table).
-    - Unsharded, dense layers only: 2. Stacked lanes make a batched matmul
-      the chip runs well; only one lane's tail leaves the full-width loop."""
-    if model_def.streamed:
+    - `lanes_as_jobs`: `lanes + 1`, every lane a job.
+    - A sharded clients axis: 1, the full-width loop alone.
+    - Unsharded, dense layers only: 2. Only one lane's tail leaves the
+      full-width loop."""
+    if model_def.streamed or lanes_as_jobs(model_def, mesh):
         return lanes + 1
-    if mesh is not None:
-        return 1
-    shapes = jax.eval_shape(lambda: model_def.init_vars(jax.random.key(0)))
-    has_conv = any(l.ndim == 4
-                   for l in jax.tree_util.tree_leaves(shapes.params))
-    return lanes + 1 if has_conv else 2
+    return 1 if mesh is not None else 2
 
 
 class RoundEngine:
@@ -486,6 +516,10 @@ class RoundEngine:
         # from how many live lanes a step runs at full width
         # (fl/client.py::split_steps); what runs below it is a lane's job
         self.wide_from = wide_from_of(model_def, mesh, hyper.no_models)
+        # the same observation gives the local battery's clean part its
+        # form (the streamed round has a battery of its own)
+        self.clean_jobs = (not self.streamed
+                           and lanes_as_jobs(model_def, mesh))
         segment_step = make_client_step(
             model_def, data, hyper, fg_enabled, fused_pallas=fused_pallas,
             fused_interpret=self.fused_interpret, wide_from=self.wide_from)
@@ -646,7 +680,8 @@ class RoundEngine:
             self.aggregate_fn = jax.jit(aggregate_fn)
 
         battery = make_local_battery(model_def, data, plans, is_poison_run,
-                                     bool(params["baseline"]))
+                                     bool(params["baseline"]),
+                                     self.clean_jobs)
 
         def _bc(s, leaf):
             """[C] → [C, 1, ...] for per-client scalars against [C, ...]."""
@@ -784,10 +819,11 @@ class RoundEngine:
         # points (fl/evaluation.py:instrument_eval) — a passthrough while
         # telemetry is off, so the fused/pipelined paths keep their deferred
         # sync. `batches` counts the eval-plan scan steps known when the
-        # engine is built (= batch fetches; the stacked clean part shares one
-        # gather across the C client models). The local battery's poison jobs
-        # vary with the round: the `round/plan` span counts them
-        # (`battery_evals_run`).
+        # engine is built: for the local battery the clean plan's (stacked,
+        # the C client models share each step's gather; as jobs every lane's
+        # job walks them again). How many single-model jobs a round's local
+        # battery runs varies with the round: the `round/plan` span counts
+        # them (`battery_evals_run`, `battery_clean_jobs`).
         from dba_mod_tpu.fl.evaluation import instrument_eval
         clean_steps = int(plans.clean_idx.shape[0])
         poison_steps = int(plans.poison_idx.shape[0])

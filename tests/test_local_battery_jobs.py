@@ -2,7 +2,10 @@
 recorder writes (fl/rounds.py::make_local_battery: a list of single-model
 jobs read from the round's tasks, one `while` whose trip count is the number
 of jobs), and every recorded row is what the four-pass battery recorded, to
-the byte.
+the byte. Where `fl/rounds.py::lanes_as_jobs` says so (an unsharded model
+with a convolution: the LeNet of every run here but `mesh`) the clean part is
+single-model jobs too, a loop of static length over the lanes, and the
+reference below, which keeps the stacked clean part, proves those rows too.
 
 The reference kept here (`make_four_pass_battery`) is the battery as it was
 before: the clean part and then three passes over all C stacked models (poison
@@ -81,6 +84,9 @@ RUNS = {
         ("mesh_padding_forensics", 4, [0, 1, 2, 3, 4, 5], 1 + 6 + 2)]),
 }
 CASES = [(run, *case) for run, (_, cases) in RUNS.items() for case in cases]
+# not an attack run: no poison file, so not one of CASES
+PRETRAIN = {"pretrain": (dict(CFG, is_poison=False), [
+    ("pretrain_clean", 1, [2, 3, 4, 5], 0)])}
 
 
 def make_four_pass_stacked_eval_fn(model_def, data, poison,
@@ -126,9 +132,11 @@ def make_four_pass_stacked_eval_fn(model_def, data, poison,
     return evaluate_stacked
 
 
-def make_four_pass_battery(model_def, data, plans, is_poison_run, baseline):
-    """The local battery before the job list: every part over all C models.
-    Takes `tasks` for each lane's trigger and ignores every flag."""
+def make_four_pass_battery(model_def, data, plans, is_poison_run, baseline,
+                           clean_jobs=False):
+    """The local battery before the job list: every part over all C models,
+    the clean one too whatever `clean_jobs` says. Takes `tasks` for each
+    lane's trigger and ignores every flag."""
     eval_clean_s = make_four_pass_stacked_eval_fn(model_def, data, False)
     eval_poison_s = make_four_pass_stacked_eval_fn(model_def, data, True)
     eval_agent_s = make_four_pass_stacked_eval_fn(model_def, data, True,
@@ -171,7 +179,7 @@ def pair(request, tmp_path_factory):
     four-pass battery: (the program's Experiment, what `_drive` saw of it,
     the two folders' recorded outputs). Parametrised by the tests, by the
     run's name."""
-    cfg, cases = RUNS[request.param]
+    cfg, cases = {**RUNS, **PRETRAIN}[request.param]
     tmp = tmp_path_factory.mktemp(f"battery_{request.param}")
     exp = Experiment(Params.from_dict(dict(cfg, run_dir=str(tmp / "jobs"))))
     mp = pytest.MonkeyPatch()
@@ -185,7 +193,8 @@ def pair(request, tmp_path_factory):
     seen = _drive(exp, cases)
     for case in _drive(ref, cases).values():  # the reference skips nothing
         assert all(np.asarray(part.count).all()
-                   for ev in case["batteries"] for part in ev)
+                   for ev in case["batteries"]
+                   for part in (ev if cfg["is_poison"] else ev[:1]))
     return exp, seen, _outputs(exp), _outputs(ref)
 
 
@@ -275,9 +284,11 @@ def test_one_program_runs_zero_jobs_or_the_jobs_the_host_counts(pair):
         lanes = tasks.adv_slot.shape[1]
         assert (seen[case]["counts"]["battery_evals_run"]
                 == lanes + int(n_jobs))
-        assert battery_eval_counts(seen[case]["tasks"], True, False, False) \
+        assert battery_eval_counts(seen[case]["tasks"], True, False, False,
+                                   True) \
             == {"battery_evals_run": lanes + jobs,
-                "battery_evals_plan": 4 * lanes}
+                "battery_evals_plan": 4 * lanes,
+                "battery_clean_evals": lanes, "battery_clean_jobs": lanes}
         # job id = part * C + lane, the wanted ones first and in order
         flat = np.stack([np.asarray(w) for w in wanted]).reshape(-1)
         np.testing.assert_array_equal(np.asarray(order)[:jobs],
@@ -292,7 +303,8 @@ def test_one_program_runs_zero_jobs_or_the_jobs_the_host_counts(pair):
         ns, key, key).jaxpr
     # the train phase's three `while`s (the full-width loop, its job loop
     # and a job's chunks: tests/test_client_step_trip_count.py), then the
-    # battery's job loop, all on scalars: `i < n`
+    # battery's poison job loop, all on scalars: `i < n` (the clean jobs'
+    # loop has a static length: no `while`)
     loops = list(_eqns(jaxpr, "while"))
     assert len(loops) == 4
     for loop in loops:
@@ -307,6 +319,168 @@ def test_one_program_runs_zero_jobs_or_the_jobs_the_host_counts(pair):
     C = idx_seq.shape[1]
     carried = [v.aval.shape for v in body.outvars]
     assert carried[0] == () and carried.count((3 * C,)) == 4
+
+
+def _battery_jaxpr(exp, tasks_list):
+    """The local battery's own program (the closure the round program
+    calls) on a round's task rows as the round stacks them: (jaxpr, C)."""
+    tasks_seq = jax.tree_util.tree_map(
+        lambda *ls: jnp.asarray(np.stack(ls)), *tasks_list)
+    lanes = tasks_seq.adv_slot.shape[1]
+    deltas = jax.tree_util.tree_map(
+        lambda l: jnp.zeros((lanes,) + l.shape, l.dtype), exp.global_vars)
+    return jax.make_jaxpr(exp.engine.local_evals_fn.__wrapped__)(
+        exp.global_vars, deltas, tasks_seq, deltas).jaxpr, lanes
+
+
+def _stacked_clean_scans(jaxpr, lanes):
+    """The scans of `make_stacked_eval_fn` in a program: over the clean
+    plan, carrying three [C] sums."""
+    return [e for e in _eqns(jaxpr, "scan")
+            if e.params["num_carry"] == 3
+            and [v.aval.shape for v in e.outvars[:3]] == [(lanes,)] * 3]
+
+
+def _clean_job_loops(jaxpr, lanes, clean_steps):
+    """The loops of static length C that walk the stacked models one at a
+    time through the single-model clean test: a scan over the lanes around
+    exactly one scan, over the clean plan, carrying three scalar sums."""
+    found = []
+    for e in _eqns(jaxpr, "scan"):
+        inner = list(_eqns(e.params["jaxpr"].jaxpr, "scan"))
+        if e.params["length"] == lanes and len(inner) == 1:
+            test, = inner
+            assert test.params["length"] == clean_steps
+            assert [v.aval.shape for v in test.outvars[:3]] == [()] * 3
+            found.append(e)
+    return found
+
+
+@pytest.mark.parametrize("pair", ["attack", "mesh"], indirect=True)
+def test_one_program_runs_the_clean_jobs_the_host_counts(pair):
+    """The clean test of every lane: on one device (a model with a
+    convolution) as many single-model jobs as the host counts, a loop of
+    static length, and no stacked scan in the program; on the mesh the
+    stacked scan and no clean job."""
+    exp, seen, _, _ = pair
+    jobs_form = exp.mesh is None
+    assert exp.engine.clean_jobs == jobs_form
+    assert exp.engine.clean_jobs == rounds_mod.lanes_as_jobs(exp.model_def,
+                                                             exp.mesh)
+    for case in seen.values():
+        lanes = len(case["tasks"][0].adv_slot)
+        counts = case["counts"]
+        assert counts["battery_clean_evals"] == len(case["tasks"]) * lanes
+        assert counts["battery_clean_jobs"] == (
+            counts["battery_clean_evals"] if jobs_form else 0)
+        # every lane's clean row is of the whole test set, either form
+        for ev in case["batteries"]:
+            np.testing.assert_array_equal(
+                np.asarray(ev.clean.count),
+                float(exp.params["synthetic_test_size"]))
+    # the round-final battery's program, on the last case's tasks
+    jaxpr, lanes = _battery_jaxpr(exp, list(seen.values())[-1]["tasks"][-1:])
+    clean_steps = exp.eval_plans.clean_idx.shape[0]
+    assert len(_stacked_clean_scans(jaxpr, lanes)) == (0 if jobs_form else 1)
+    assert len(_clean_job_loops(jaxpr, lanes, clean_steps)) == (
+        1 if jobs_form else 0)
+    # the poison parts' job loop is as it was: three parts' rows
+    loop, = _eqns(jaxpr, "while")
+    body = loop.params["body_jaxpr"].jaxpr
+    assert [v.aval.shape for v in body.outvars].count((3 * lanes,)) == 4
+
+
+@pytest.mark.parametrize("pair", ["pretrain"], indirect=True)
+def test_pretraining_run_is_the_stacked_batterys(pair):
+    """`is_poison: false`: the clean part alone, C single-model jobs and no
+    poison job loop, records what the stacked battery records, to the
+    byte."""
+    exp, seen, got, want = pair
+    assert exp.engine.clean_jobs and not exp.is_poison_run
+    assert set(got) == set(want) and "test_result.csv" in got
+    for name in want:
+        assert got[name] == want[name], name
+    (case, epoch, names, _), = PRETRAIN["pretrain"][1]
+    lanes = len(names)
+    assert len(_local_rows(got, "test_result.csv", 1, {epoch})) == lanes
+    assert seen[case]["counts"] == {
+        **seen[case]["counts"], "battery_evals_run": lanes,
+        "battery_evals_plan": lanes, "battery_clean_evals": lanes,
+        "battery_clean_jobs": lanes}
+    ev, = seen[case]["batteries"]
+    assert np.count_nonzero(np.asarray(ev.clean.count)) == lanes
+    for part in (ev.poison_pre, ev.poison_post, ev.agent_trigger):
+        assert not any(np.asarray(leaf).any() for leaf in part)
+    jaxpr, _ = _battery_jaxpr(exp, seen[case]["tasks"])
+    assert not _stacked_clean_scans(jaxpr, lanes)
+    assert len(_clean_job_loops(jaxpr, lanes,
+                                exp.eval_plans.clean_idx.shape[0])) == 1
+    assert not list(_eqns(jaxpr, "while"))
+
+
+@pytest.mark.parametrize("longer", ["clean", "poison"])
+def test_clean_jobs_over_plans_of_unequal_length(longer):
+    """The clean jobs walk the clean plan and the poison jobs the poison
+    plan, whichever is the longer: every row, clean and poisoned, is to the
+    bit what the battery with the stacked clean part gives (`clean_jobs`
+    off: the form a dense model and the mesh keep)."""
+    from dba_mod_tpu.data import build_eval_plan, load_image_dataset
+    from dba_mod_tpu.fl.experiment import poison_test_indices
+    from dba_mod_tpu.fl.device_data import make_image_device_data
+    from dba_mod_tpu.fl.state import ClientTask
+    from dba_mod_tpu.models import build_model
+    params = Params.from_dict(CFG)
+    data = load_image_dataset(params)
+    dd = make_image_device_data(data, params)
+    mdef = build_model(params)
+    C = 3
+    u, s_ = (jax.vmap(mdef.init_vars)(jax.random.split(jax.random.key(k), C))
+             for k in (0, 1))
+    # 100 test images in batches of 16: 7 steps, the last masked to 4; the
+    # poison plan drops the target label's images: 6 steps
+    kept = poison_test_indices(data.test_labels, CFG["poison_label_swap"])
+    clean_ids = np.arange(100 if longer == "clean" else 40)
+    plans = []
+    for ids in (clean_ids, kept):
+        plan = build_eval_plan(ids, 16)
+        idx = jnp.asarray(plan.idx)
+        plans += [idx, jnp.zeros_like(idx), jnp.asarray(plan.mask)]
+    plans = rounds_mod.EvalPlans(*plans)
+    steps = plans.clean_idx.shape[0], plans.poison_idx.shape[0]
+    assert (steps[0] > steps[1]) == (longer == "clean") and steps[0] != steps[1]
+    tasks = ClientTask(*(None,) * 3, jnp.array([[0, -1, 1]]),
+                       jnp.array([[8, 0, 8]]), *(None,) * 3,
+                       jnp.array([[2, 1, 2]]))
+    got, want = (jax.jit(lambda *a, cj=cj: rounds_mod.make_local_battery(
+        mdef, dd, plans, True, False, cj)(*a, False))(u, s_, tasks)
+        for cj in (True, False))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got.clean.count), len(clean_ids))
+    np.testing.assert_array_equal(np.asarray(got.poison_pre.count),
+                                  [len(kept), 0, len(kept)])
+
+
+@pytest.mark.parametrize("workload, jobs", [
+    ("mnist", True), ("cifar", True), ("tiny-imagenet-200", True),
+    ("loan", False)])
+def test_lanes_as_jobs_by_hand(narrow_resnets, monkeypatch, workload, jobs):
+    """fl/rounds.py::lanes_as_jobs, the one rule the client step and the
+    local battery's clean part share: a model with a convolution on one
+    device runs lane by lane, the dense LOAN model stacked, anything on a
+    sharded clients axis stacked; `wide_from_of` reads it, not a second
+    test of the shapes."""
+    from dba_mod_tpu import models
+    model_def = models.build_model(
+        Params.from_dict(dict(CFG, type=workload)))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("clients",))
+    assert rounds_mod.lanes_as_jobs(model_def, None) == jobs
+    assert not rounds_mod.lanes_as_jobs(model_def, mesh)
+    assert rounds_mod.wide_from_of(model_def, None, 10) == (11 if jobs else 2)
+    assert rounds_mod.wide_from_of(model_def, mesh, 10) == 1
+    monkeypatch.setattr(rounds_mod, "lanes_as_jobs", lambda *_: not jobs)
+    assert rounds_mod.wide_from_of(model_def, None, 10) == (2 if jobs else 11)
 
 
 def test_local_battery_jobs_by_hand():
@@ -333,35 +507,41 @@ def test_local_battery_jobs_by_hand():
     from dba_mod_tpu.fl.state import ClientTask
     tasks_list = [ClientTask(*(None,) * 3, adv[s], ppb[s], *(None,) * 3,
                              eps[s]) for s in range(2)]
-    assert battery_eval_counts(tasks_list, True, False, True) == {
+    assert battery_eval_counts(tasks_list, True, False, True, True) == {
         "battery_evals_run": 10 + (1 + 1 + 3) + (2 + 4 + 3),
-        "battery_evals_plan": 40}
-    assert battery_eval_counts(tasks_list, False, False, False) == {
-        "battery_evals_run": 10, "battery_evals_plan": 10}
+        "battery_evals_plan": 40, "battery_clean_evals": 10,
+        "battery_clean_jobs": 10}
+    assert battery_eval_counts(tasks_list, False, False, False, False) == {
+        "battery_evals_run": 10, "battery_evals_plan": 10,
+        "battery_clean_evals": 10, "battery_clean_jobs": 0}
 
 
-@pytest.mark.parametrize("records", ["counted", "uncounted", "bare", "none"])
-def test_evals_run_reader(records):
-    """chipbench/metrics/local_battery_evals_run_pct.py, found by name as the
-    harness finds it: sums over the window's rounds; nothing (not zero, no
-    exception) from a program whose plan spans carry no battery counts."""
+def _reader(name):
+    """chipbench/metrics/<name>.py and its `per_layer` entry, found by name
+    as the harness finds them."""
     import json
     from chipbench import run as harness
-    from chipbench import selfcheck_steps as sc
     bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     (m, mod), = [(m, mod) for m, mod in harness.load_readers(
-        bench, "tiny_dba_attack") if m["name"] == "local_battery_evals_run_pct"]
+        bench, "tiny_dba_attack") if m["name"] == name]
     assert (mod.LAYER, mod.UNIT, mod.MOVES) == (m["layer"], m["unit"],
                                                 m["moves"])
-    if records == "counted":
-        # a check round of set-up (not read), then window rounds 1-3 of the
-        # cell: two clean rounds and one with one adversary
-        made = [sc.Span("round/plan", i, i + 1, None, i, {
-            "steps_plan": 370, "steps_run": 48, "lane_steps_real": 320,
-            "lanes": 10, "battery_evals_run": run, "battery_evals_plan": 40})
-            for i, run in enumerate((40, 10, 10, 13))]
-        assert mod.read(sc.context(made, 3)) == pytest.approx(27.5)
-    elif records == "uncounted":   # the parent's records: step counts only
+    assert m["workloads"] == ["tiny_dba_attack"]
+    return mod
+
+
+def _plan_span(i, **battery):
+    from chipbench import selfcheck_steps as sc
+    return sc.Span("round/plan", i, i + 1, None, i, {
+        "steps_plan": 370, "steps_run": 48, "lane_steps_real": 320,
+        "lanes": 10, **battery})
+
+
+def _reads_nothing(mod, records):
+    """Nothing (not zero, no exception) from records without the counts:
+    the parent's (step counts only), bare ones, none at all."""
+    from chipbench import selfcheck_steps as sc
+    if records == "uncounted":
         assert mod.read(sc.context(sc.synthetic_records(), 3)) is None
     elif records == "bare":
         bare = [sc.BareSpan(*r[:5]) for r in sc.synthetic_records()]
@@ -369,3 +549,44 @@ def test_evals_run_reader(records):
     else:
         assert mod.read(sc.context(None, 0)) is None
         assert mod.read(sc.context([], 3)) is None
+
+
+@pytest.mark.parametrize("records", ["counted", "uncounted", "bare", "none"])
+def test_evals_run_reader(records):
+    """chipbench/metrics/local_battery_evals_run_pct.py: sums over the
+    window's rounds; nothing from a program whose plan spans carry no
+    battery counts."""
+    from chipbench import selfcheck_steps as sc
+    mod = _reader("local_battery_evals_run_pct")
+    if records != "counted":
+        return _reads_nothing(mod, records)
+    # a check round of set-up (not read), then window rounds 1-3 of the
+    # cell: two clean rounds and one with one adversary
+    made = [_plan_span(i, battery_evals_run=run, battery_evals_plan=40)
+            for i, run in enumerate((40, 10, 10, 13))]
+    assert mod.read(sc.context(made, 3)) == pytest.approx(27.5)
+
+
+@pytest.mark.parametrize("records", ["counted", "uncounted", "bare", "none"])
+def test_clean_jobs_reader(records):
+    """chipbench/metrics/local_battery_clean_jobs_pct.py: the clean tests
+    that ran as jobs over the clean tests run, summed over the window's
+    rounds; nothing from a program whose plan spans do not count them."""
+    from chipbench import selfcheck_steps as sc
+    mod = _reader("local_battery_clean_jobs_pct")
+    old = dict(battery_evals_run=10, battery_evals_plan=40)
+    if records != "counted":
+        # PR 27's records too: battery counts, none of the clean part
+        assert mod.read(sc.context([_plan_span(i, **old) for i in range(4)],
+                                   3)) is None
+        return _reads_nothing(mod, records)
+    # a check round of set-up (not read), then three window rounds: all ten
+    # clean tests as jobs; a stacked engine's; an interval-2 round of which
+    # (no engine does this) one battery's ran as jobs
+    made = [_plan_span(i, **old, battery_clean_evals=evals,
+                       battery_clean_jobs=jobs)
+            for i, (evals, jobs) in enumerate(
+                ((10, 0), (10, 10), (10, 0), (20, 10)))]
+    assert mod.read(sc.context(made, 3)) == pytest.approx(50.0)
+    assert mod.read(sc.context(made[:2], 1)) == 100.0
+    assert mod.read(sc.context(made[:3], 1)) == 0.0
