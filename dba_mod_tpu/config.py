@@ -29,8 +29,12 @@ TYPE_LOAN = "loan"
 # (models/lfm2.py; its architecture is the nested `lfm2` key)
 TYPE_LFM2 = "lfm2_moe"
 
+# a softmax-routed sparse-expert decoder trained by block diffusion
+# (models/sdar.py; its architecture is the nested `sdar` key)
+TYPE_SDAR = "sdar_moe"
+
 IMAGE_TYPES = (TYPE_CIFAR, TYPE_MNIST, TYPE_TINYIMAGENET)
-TOKEN_TYPES = (TYPE_LFM2,)
+TOKEN_TYPES = (TYPE_LFM2, TYPE_SDAR)
 
 # Aggregation method names (reference config.py:4-6).
 AGGR_MEAN = "mean"
@@ -95,7 +99,8 @@ _DEFAULTS: Dict[str, Any] = {
     "random_seed": 1,
     # framework-specific knobs (not in the reference schema)
     # token workloads (data/tokens.py, ops/triggers.py::build_phrase_bank);
-    # the model's architecture is the nested `lfm2` key (models/lfm2.py)
+    # the model's architecture is the nested `lfm2` key (models/lfm2.py) or
+    # the nested `sdar` key (models/sdar.py)
     "seq_len": 2048,               # tokens a packed row
     "sequences_per_client": 4,     # rows a participant holds
     "test_sequences": 8,           # held-out rows (the global battery)

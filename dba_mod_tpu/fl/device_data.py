@@ -116,29 +116,33 @@ def make_loan_device_data(data: LoanData, params: cfg.Params,
 
 
 def make_token_device_data(data: TokenData, params: cfg.Params,
-                           compute_dtype=jnp.float32) -> DeviceData:
-    """Token rows: a fetch hands out (rows [B, T] int32, their next-token
-    labels); `stamp` writes the trigger phrase and the target continuation
-    over the rows it poisons and derives the labels again
+                           compute_dtype=jnp.float32,
+                           block_length: int = 0) -> DeviceData:
+    """Token rows: a fetch hands out (rows [B, T] int32, their labels: the
+    next tokens, or with `block_length` > 0, a block-diffusion model's, the
+    rows' own tokens); `stamp` writes the trigger phrase and the target
+    continuation over the rows it poisons and derives the labels again
     (ops/triggers.py::poison_batch_tokens)."""
+    labels_of = (triggers.own_token_labels if block_length
+                 else triggers.next_token_labels)
     with telemetry.span("setup/device_put"):
         train = jnp.asarray(data.train_tokens)
         test = jnp.asarray(data.test_tokens)
         bank = tuple(jnp.asarray(a) for a in triggers.build_phrase_bank(
-            params, data.train_tokens.shape[1]))
+            params, data.train_tokens.shape[1], block_length))
         jax.block_until_ready((train, test, bank))
 
     def fetch_train(slot, idx, source=(train,)):
         rows = source[0][idx]
-        return rows, triggers.next_token_labels(rows)
+        return rows, labels_of(rows)
 
     def fetch_test(slot, idx):
         rows = test[idx]
-        return rows, triggers.next_token_labels(rows)
+        return rows, labels_of(rows)
 
     def stamp(x, y, adv_index, k, poison_all=False):
         return triggers.poison_batch_tokens(x, *bank, adv_index, k,
-                                            poison_all)
+                                            poison_all, labels_of)
 
     return DeviceData(fetch_train, fetch_test, stamp,
                       num_train=len(data.train_tokens),

@@ -101,8 +101,9 @@ def make_eval_fn(model_def: ModelDef, data: DeviceData, poison: bool):
             x, y = data.fetch_test(bslot, bidx)
             if poison:
                 x, y, _ = data.stamp(x, y, adv_index, 0, poison_all=True)
-            logits, _ = model_def.apply(model_vars, x, train=False)
-            dl, dc, dn = batch_scores(logits, y, bmask)
+            out = model_def.run_batch(model_vars, x, y, bmask, None,
+                                      train=False)
+            dl, dc, dn = batch_scores(out.logits, out.labels, bmask)
             return (loss_sum + dl, correct + dc, count + dn), None
 
         (loss_sum, correct, count), _ = jax.lax.scan(

@@ -472,10 +472,14 @@ class Experiment:
         elif params.is_tokens:
             from dba_mod_tpu.data.tokens import load_token_dataset
             with telemetry.span("setup/data"):
+                # the ids a model keeps for itself (a diffusion model's
+                # MASK) are at the top of its vocabulary: no row holds one
                 data = self.token_data = load_token_dataset(
-                    params, self.model_def.vocab_size)
-            self.device_data = make_token_device_data(data, params,
-                                                      compute_dtype=cdtype)
+                    params, self.model_def.vocab_size
+                    - self.model_def.reserved_ids)
+            self.device_data = make_token_device_data(
+                data, params, compute_dtype=cdtype,
+                block_length=self.model_def.block_length)
             with telemetry.span("setup/partition"):
                 self._partition_tokens(data, eb)
         else:
@@ -723,13 +727,17 @@ class Experiment:
                                  for m in mask_list]
                     num_samples_np = np.pad(num_samples_np, (0, pad))
             if self.engine.streamed:
-                # what a streamed step is made of: its rows' positions, and
-                # the client-steps the round's one-after-another loop runs
+                # what a streamed step is made of: the positions it sends
+                # through the layers (every stream of its rows), and the
+                # client-steps the round's one-after-another loop runs
                 steps = int(sum(m.any(axis=-1).sum() for m in mask_list))
                 plan_span.count(
                     tokens_step=int(np.prod(self.model_def.input_shape)
-                                    * int(params["batch_size"])),
-                    client_steps=steps)
+                                    * int(params["batch_size"])
+                                    * self.model_def.streams),
+                    client_steps=steps,
+                    **({"block_length": self.model_def.block_length}
+                       if self.model_def.block_length else {}))
             plan_span.count(
                 **plan_step_counts(
                     mask_list, STEP_CHUNK,
@@ -1241,6 +1249,10 @@ class Experiment:
                     expert_tokens_max=int(counts.max),
                     expert_tokens_mean=float(counts.held)
                     / int(counts.cells))
+            if counts is not None and counts.tallies:
+                # what the model's objective tallied over the real steps
+                record_span.count(**{name: int(v) for name, v
+                                     in counts.tallies.items()})
             self._record(fl.epoch, fl.seg_epochs, fl.agent_names,
                          fl.adv_names, fl.tasks_list, metrics, locals_,
                          globals_, delta_norms, wv, alpha, times, batches,

@@ -46,7 +46,7 @@ from dba_mod_tpu.fl.rounds import LocalEvals
 from dba_mod_tpu.models import ModelDef, ModelVars
 from dba_mod_tpu.ops import aggregation as agg
 from dba_mod_tpu.ops.fused_update import make_fused_step_update
-from dba_mod_tpu.ops.losses import batch_loss, batch_scores
+from dba_mod_tpu.ops.losses import batch_scores
 
 
 class Workspace(NamedTuple):
@@ -57,11 +57,14 @@ class Workspace(NamedTuple):
 
 class ModelCounts(NamedTuple):
     """What the model counted of its own work over a round's real steps
-    (models/lfm2.py: tokens given to the held experts). All zero for a model
-    that counts nothing."""
+    (models/lfm2.py, models/sdar.py: tokens given to the held experts). All
+    zero for a model that counts nothing."""
     held: jax.Array   # sum over steps, layers and held experts
     max: jax.Array    # the most one held expert was given in one step
     cells: jax.Array  # (step, layer, held expert) cells counted
+    # {name: sum over the real steps} of what the model's objective tallies
+    # (`ModelDef.tallies`; models/sdar.py: positions masked and scored)
+    tallies: Any = ()
 
 
 def make_workspace(global_vars: ModelVars) -> Workspace:
@@ -145,19 +148,19 @@ def make_streamed_round(model_def: ModelDef, data, hyper, plans, local_plans,
                 jax.random.fold_in(rng, e), step_i - e * S)
 
             def loss_fn(p):
-                logits, new_bn, counted = model_def.apply_counted(
-                    ModelVars(p, bn), x, dropout_rng=step_rng)
-                return (batch_loss(logits, y, bmask),
-                        (logits, new_bn, counted))
+                out = model_def.run_batch(ModelVars(p, bn), x, y, bmask,
+                                          step_rng, train=True)
+                return out.loss, out
 
-            (loss, (logits, new_bn, counted)), grads = jax.value_and_grad(
+            (loss, out), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             valid = jnp.sum(bmask) > 0
             with jax.named_scope("optimizer"):
                 params, mom, _, bn = update(task.lr_row[e], valid, params,
-                                            grads, mom, (), new_bn, bn)
+                                            grads, mom, (), out.batch_stats,
+                                            bn)
             vf = valid.astype(jnp.float32)
-            _, right, seen = batch_scores(logits, y, bmask)
+            _, right, seen = batch_scores(out.logits, out.labels, bmask)
             rows = bmask.astype(jnp.float32)
             m = ClientMetrics(
                 loss_sum=m.loss_sum.at[e].add(vf * loss),
@@ -165,11 +168,17 @@ def make_streamed_round(model_def: ModelDef, data, hyper, plans, local_plans,
                 count=m.count.at[e].add(vf * seen),
                 poison_count=m.poison_count.at[e].add(
                     vf * jnp.sum(sel * rows)))
-            for n in jax.tree_util.tree_leaves(counted):
+            for n in jax.tree_util.tree_leaves(out.counted):
                 n = n * valid
-                counts = ModelCounts(counts.held + jnp.sum(n),
-                                     jnp.maximum(counts.max, jnp.max(n)),
-                                     counts.cells + n.size * valid)
+                counts = counts._replace(
+                    held=counts.held + jnp.sum(n),
+                    max=jnp.maximum(counts.max, jnp.max(n)),
+                    cells=counts.cells + n.size * valid)
+            if out.tallies:
+                counts = counts._replace(tallies={
+                    name: counts.tallies[name]
+                    + (out.tallies[name] * valid).astype(jnp.int32)
+                    for name in counts.tallies})
             return params, bn, mom, m, counts
 
         def chunk_of(j, carry):
@@ -250,7 +259,9 @@ def make_streamed_round(model_def: ModelDef, data, hyper, plans, local_plans,
                                for r, v in zip(rows["locals"], evals)]}
             return acc, end, mom, counts, rows
 
-        counts0 = ModelCounts(jnp.int32(0), jnp.int32(0), jnp.int32(0))
+        counts0 = ModelCounts(jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                              {name: jnp.int32(0)
+                               for name in model_def.tallies} or ())
         acc, live, mom, counts, rows = jax.lax.fori_loop(
             0, C, client,
             (work.acc, work.client, work.momentum, counts0, rows0))

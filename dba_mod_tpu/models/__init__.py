@@ -9,7 +9,8 @@ records the per-model metadata the framework needs:
 - `has_batch_stats` / `has_dropout`: which extra variable collections / RNG
   streams the train step must thread;
 - the *form* of a sample (`ModelDef.form`): what the data layer hands the
-  model and what the loss scores.
+  model and what the loss scores;
+- the *objective* (`ModelDef.run_batch`): what a step does with a batch.
 
 Models are pure architectures; the reference's visdom-plotting mixin
 (models/simple.py:18-200) is deliberately not carried over (observability lives in
@@ -29,6 +30,9 @@ from dba_mod_tpu.models.lfm2 import Lfm2Config, Lfm2Moe, seed_expert_bias
 from dba_mod_tpu.models.loan import LoanNet
 from dba_mod_tpu.models.mnist import MnistNet
 from dba_mod_tpu.models.resnet import cifar_resnet18, tiny_resnet18
+from dba_mod_tpu.models.sdar import (TALLIES, SdarConfig, SdarMoe,
+                                     block_diffusion)
+from dba_mod_tpu.ops.losses import BatchOut, batch_loss
 
 
 class ModelVars(NamedTuple):
@@ -47,6 +51,13 @@ FORM_IMAGE = "image"      # float [B, H, W, C] (or [B, F] feature rows), one
 FORM_TOKENS = "tokens"    # int32 [B, T] rows of token ids (negative: padding),
                           # labels [B, T] the next tokens out of `vocab_size`,
                           # -1 where a position is not scored
+FORM_MASKED_TOKENS = "masked_tokens"  # the same rows; labels [B, T] are the
+                          # row's OWN tokens (no shift: a block-diffusion
+                          # model predicts a masked position's token), -1 at
+                          # padding and, in a backdoor test, everywhere but
+                          # the target continuation. Which positions a step
+                          # masks, and so scores, is the objective's to draw
+TOKEN_FORMS = (FORM_TOKENS, FORM_MASKED_TOKENS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,9 +71,21 @@ class ModelDef:
     a position, a row's labels are its next tokens, and `num_classes` is 0
     (it is no class count; ops/losses.py scores both forms).
 
+    The masked-token form (a block-diffusion model): the same rows, a
+    row's labels are its own tokens, the trigger's phrase and continuation
+    lie on whole blocks of `block_length` positions (ops/triggers.py), and
+    the ids from `vocab_size - reserved_ids` up are the model's own (MASK):
+    the token streams draw none of them.
+
     `streamed`: the state is too large to stack a copy a client; the round
     engine then trains the round's clients one after another and accumulates
-    FedAvg in place (fl/streamed.py)."""
+    FedAvg in place (fl/streamed.py).
+
+    `objective`: what a step does with a batch, where it is not "labels in,
+    logits out" (`run_batch`, which the streamed round and the evaluation
+    call and which names no form). `streams`: the copies of a row the model
+    reads in one pass (a block-diffusion model: the noisy row and the clean
+    one), `tallies`: the names of what its objective counts."""
     name: str
     module: nn.Module
     input_shape: Tuple[int, ...]   # one sample: NHWC / features / (seq_len,)
@@ -71,8 +94,13 @@ class ModelDef:
     has_batch_stats: bool
     has_dropout: bool
     form: str = FORM_IMAGE
-    vocab_size: int = 0            # token form: logits a position
+    vocab_size: int = 0            # token forms: logits a position
     streamed: bool = False
+    objective: Optional[Callable[..., BatchOut]] = None
+    streams: int = 1
+    block_length: int = 0          # masked-token form: positions a block
+    reserved_ids: int = 0          # top ids of the vocabulary no row holds
+    tallies: Tuple[str, ...] = ()
     # what the model's non-gradient state starts as, where zeros would not
     # do: batch_stats tree, rng -> batch_stats tree
     stats_init: Optional[Callable[[Any, jax.Array], Any]] = None
@@ -85,11 +113,13 @@ class ModelDef:
                 stats = self.stats_init(stats, jax.random.fold_in(rng, 1))
             return ModelVars(params=variables["params"], batch_stats=stats)
 
-        if self.form == FORM_TOKENS:
+        if self.form in TOKEN_FORMS:
             # parameter shapes do not depend on the row's length: a short
             # row, and one compiled call instead of an eager forward pass
-            dummy = jnp.zeros((1, min(self.input_shape[0], 8)), jnp.int32)
-            return jax.jit(init)(rng, dummy)
+            shape = ((1, self.streams, 2 * self.block_length)
+                     if self.form == FORM_MASKED_TOKENS
+                     else (1, min(self.input_shape[0], 8)))
+            return jax.jit(init)(rng, jnp.zeros(shape, jnp.int32))
         return init(rng, jnp.zeros((1,) + self.input_shape, jnp.float32))
 
     def apply(self, model_vars: ModelVars, x, train: bool,
@@ -124,6 +154,28 @@ class ModelDef:
             mutable=["batch_stats", "counters"])
         return (logits, updates.get("batch_stats", model_vars.batch_stats),
                 updates.get("counters", {}))
+
+    def run_batch(self, model_vars: ModelVars, x, y, mask,
+                  key: jax.Array | None, train: bool) -> BatchOut:
+        """What a step does with a batch, whatever the model's form: `x` and
+        `y` as the data layer hands them (after `stamp`), `mask` [B] the
+        valid rows, `key` the step's key (None in evaluation). Training:
+        the loss, the new `batch_stats`, the model's `counters` collection
+        and what the objective tallied. Both modes: the logits and labels
+        `ops/losses.py::batch_scores` takes its three sums of (a caller
+        that wants them calls it; evaluation's loss is None).
+
+        Without an `objective`, labels in and logits out: the image form and
+        the next-token form."""
+        if self.objective is not None:
+            return self.objective(self, model_vars, x, y, mask, key, train)
+        if not train:
+            logits, _ = self.apply(model_vars, x, train=False)
+            return BatchOut(None, logits, y, model_vars.batch_stats, {}, {})
+        logits, stats, counted = self.apply_counted(model_vars, x,
+                                                    dropout_rng=key)
+        return BatchOut(batch_loss(logits, y, mask), logits, y, stats,
+                        counted, {})
 
     def similarity_param(self, params) -> jax.Array:
         p = params
@@ -176,4 +228,18 @@ def build_model(params: cfg.Params) -> ModelDef:
                         has_dropout=False, form=FORM_TOKENS,
                         vocab_size=arch.vocab_size, streamed=True,
                         stats_init=seed_expert_bias)
+    if t == cfg.TYPE_SDAR:
+        arch = SdarConfig.from_dict(params["sdar"])
+        seq_len = int(params["seq_len"])
+        if seq_len % arch.block_length:
+            raise ValueError(f"sdar: block_length {arch.block_length} does "
+                             f"not divide seq_len {seq_len}")
+        return ModelDef(name="SdarMoe", module=SdarMoe(arch, dtype=dtype),
+                        input_shape=(seq_len,), num_classes=0,
+                        similarity_path=("head",), has_batch_stats=False,
+                        has_dropout=False, form=FORM_MASKED_TOKENS,
+                        vocab_size=arch.vocab_size, streamed=True,
+                        objective=block_diffusion(arch), streams=2,
+                        block_length=arch.block_length, reserved_ids=1,
+                        tallies=TALLIES)
     raise ValueError(f"unknown workload type {t!r}")

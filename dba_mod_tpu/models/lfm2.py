@@ -68,10 +68,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from dba_mod_tpu.models.decoder_parts import (  # noqa: F401 (re-exported)
+    INIT_STD, apply_rope, held_picks, rms_norm, rope_tables)
+from dba_mod_tpu.models.decoder_parts import normal_init as _normal
+
 CONV = "conv"
 ATTENTION = "full_attention"
 CAPACITY_FACTOR = 3.0  # a held expert's gather buffer over an even share
-INIT_STD = 0.02         # of every matrix and the convolution kernel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,31 +122,6 @@ class Lfm2Config:
     @property
     def num_expert_layers(self) -> int:
         return max(0, len(self.layer_types) - self.num_dense_layers)
-
-
-def _normal(std: float):
-    return nn.initializers.normal(stddev=std)
-
-
-def rms_norm(x, scale, eps: float):
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
-
-
-def rope_tables(seq_len: int, head_dim: int, theta: float):
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
-    ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)          # [T, hd]
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rope(x, cos, sin):
-    """x [B, T, H, hd]; rotate-half over the whole head."""
-    half = x.shape[-1] // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos[None, :, None, :] + rot * sin[None, :, None, :]
 
 
 def route(scores_logits, bias, k: int, norm_topk: bool, scaling: float):
@@ -286,10 +264,7 @@ class ExpertFfn(nn.Module):
                            c.routed_scaling_factor)
             # [N, held]: whether a token chose each held expert, and the
             # weight it gave it (0 where it did not)
-            picks = sel[:, :, None] == jnp.arange(lo, hi)
-            chosen = jnp.any(picks, axis=1)
-            wts = jnp.sum(jnp.where(picks, w[:, :, None], 0.0), axis=1)
-            counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)    # [E]
+            chosen, wts, counts = held_picks(sel, w, lo, hi)
         self.sow("counters", "expert_tokens", counts,
                  reduce_fn=lambda a, b: b, init_fn=lambda: counts * 0)
         share = n * c.num_experts_per_tok / c.num_experts

@@ -9,17 +9,30 @@ Reference semantics preserved:
   scored (the last of a row, padding, and in a backdoor test everything but
   the target continuation): `batch_loss` and `batch_scores` take both forms,
   a row of the image form counting as one prediction and a row of the token
-  form as its scored positions;
+  form as its scored positions; a block-diffusion model's labels are the
+  row's own tokens (no shift), -1 at padding and where a test does not score
+  (`block_noise` draws what its step masks; `BatchOut` is what any model's
+  objective makes of a batch: `models.ModelDef.run_batch`);
 - distance/global norms run over trainable parameters only — torch
   named_parameters excludes BN running stats but includes BN affine γ/β
   (helper.py:59-71, :110-123).
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+
+class BatchOut(NamedTuple):
+    """What a model's objective makes of one batch (`ModelDef.run_batch`)."""
+    loss: Any         # the training loss; None in evaluation
+    logits: Any       # what `batch_scores` scores, against
+    labels: Any       # these labels (the data layer's, or the objective's)
+    batch_stats: Any  # the model's non-gradient state after the batch
+    counted: Any      # the model's `counters` collection ({}: none)
+    tallies: Any      # {name: scalar} the objective counted ({}: none)
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,
@@ -57,6 +70,29 @@ def token_nll(logits: jax.Array, labels: jax.Array):
     nll = -jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None],
                                axis=-1)[..., 0]
     return jnp.where(scored, nll, 0.0), scored.astype(jnp.float32)
+
+
+def block_noise(key: jax.Array, rows: jax.Array, block_length: int,
+                low: float, high: float):
+    """The noise of one block-diffusion step, a function of the step's key
+    alone: rows [B, T] token ids (negative: padding), T a multiple of
+    `block_length` -> (t [B, T] float32, each position's masking rate;
+    masked [B, T] bool). In this order:
+
+        k_t, k_m = split(key)
+        t_b ~ uniform(k_t, [B, T / block_length]; low, high)   one a block
+        u_i ~ uniform(k_m, [B, T]; 0, 1);  masked_i = u_i < t_b(i)
+
+    Padding is never masked. chipbench/reference/masked_tokens.py repeats
+    this (it imports nothing of the program);
+    tests/test_block_diffusion.py holds the two together."""
+    bsz, seq_len = rows.shape
+    k_t, k_m = jax.random.split(key)
+    t = jax.random.uniform(k_t, (bsz, seq_len // block_length), jnp.float32,
+                           low, high)
+    t = jnp.repeat(t, block_length, axis=1)
+    masked = jax.random.uniform(k_m, (bsz, seq_len), jnp.float32) < t
+    return t, masked & (rows >= 0)
 
 
 def batch_loss(logits: jax.Array, labels: jax.Array, mask: jax.Array):

@@ -137,17 +137,41 @@ def poison_batch_features(rows: jax.Array, labels: jax.Array,
 
 
 # ------------------------------------------------------------------ token rows
-def build_phrase_bank(params: cfg.Params, seq_len: int):
+def build_phrase_bank(params: cfg.Params, seq_len: int,
+                      block_length: int = 0):
     """Token sequences: (values [n + 1, T] int32, masks [n + 1, T] bool,
     target_values [T] int32, target_mask [T] bool). The trigger phrase is the
     sub-spans `<i>_poison_pattern` (lists of token ids) one behind the other;
     it is written at every position of `trigger_positions`, the target
     continuation `poison_continuation` right behind it. Row i holds adversary
-    i's span alone, row n the whole phrase."""
+    i's span alone, row n the whole phrase.
+
+    `block_length` > 0 (a block-diffusion model): the continuation has to be
+    exactly one block, the unit such a model fills in at once from the clean
+    blocks before it, so every trigger position and the phrase's length are
+    multiples of `block_length` and the continuation holds `block_length`
+    tokens; anything else is refused here, by the key at fault."""
     n = int(params["trigger_num"])
     spans = [[int(t) for t in params.poison_pattern_for(i)] for i in range(n)]
     target = [int(t) for t in params["poison_continuation"]]
     phrase_len = sum(len(s) for s in spans)
+    if block_length:
+        why = None
+        off = [int(p) for p in params["trigger_positions"]
+               if int(p) % block_length]
+        if off:
+            why = f"trigger_positions: {off} are no multiples"
+        elif phrase_len % block_length:
+            why = (f"<i>_poison_pattern: the phrase's {phrase_len} tokens "
+                   "are no multiple")
+        elif len(target) != block_length:
+            why = (f"poison_continuation: its {len(target)} tokens are not "
+                   "one block")
+        if why:
+            raise ValueError(
+                f"{why} of block_length {block_length}: a block-diffusion "
+                "model's backdoor test scores the continuation as one whole "
+                "block behind a phrase of whole blocks")
     values = np.zeros((n + 1, seq_len), np.int32)
     masks = np.zeros((n + 1, seq_len), bool)
     target_values = np.zeros((seq_len,), np.int32)
@@ -183,16 +207,26 @@ def next_token_labels(rows: jax.Array, only=None) -> jax.Array:
     return jnp.where(nxt >= 0, nxt, -1)
 
 
+def own_token_labels(rows: jax.Array, only=None) -> jax.Array:
+    """rows [..., T] token ids (negative: padding) -> labels [..., T] of a
+    model that predicts a position's own token (block diffusion): the row
+    itself, -1 (not scored) at padding; with `only` ([T] bool) also wherever
+    the position is outside it."""
+    own = jnp.where(rows >= 0, rows, -1)
+    return own if only is None else jnp.where(only, own, -1)
+
+
 def poison_batch_tokens(rows: jax.Array, values: jax.Array, masks: jax.Array,
                         target_values: jax.Array, target_mask: jax.Array,
-                        adv_index, poisoning_per_batch, poison_all=False):
+                        adv_index, poisoning_per_batch, poison_all=False,
+                        labels_of=next_token_labels):
     """Token counterpart of :func:`poison_batch`: the first
     `poisoning_per_batch` rows (all if `poison_all`) get trigger `adv_index`
     and the target continuation written over their tokens; padding is never
-    written over. Labels are the next tokens of the rows as stamped: a
-    training row scores every position (the adversary trains on the whole
-    poisoned sequence), a test row (`poison_all`, a Python bool) only the
-    continuation.
+    written over. Labels are `labels_of` the rows as stamped (their next
+    tokens; a block-diffusion model's: their own): a training row scores
+    every position (the adversary trains on the whole poisoned sequence), a
+    test row (`poison_all`, a Python bool) only the continuation.
     Returns (rows, labels, per-row poisoned mask)."""
     batch = rows.shape[0]
     sel = jnp.where(poison_all, jnp.ones((batch,), bool),
@@ -201,5 +235,5 @@ def poison_batch_tokens(rows: jax.Array, values: jax.Array, masks: jax.Array,
     stamped = jnp.where(masks[k], values[k], rows)
     stamped = jnp.where(target_mask, target_values, stamped)
     new_rows = jnp.where(sel[:, None] & (rows >= 0), stamped, rows)
-    labels = next_token_labels(new_rows, target_mask if poison_all else None)
+    labels = labels_of(new_rows, target_mask if poison_all else None)
     return new_rows, labels, sel

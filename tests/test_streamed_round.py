@@ -8,7 +8,10 @@
   main-task and backdoor rows, and puts its counts on the spans;
 - the benchmark's family for it offers the whole interface, and its check
   rounds at a toy size are inside toy limits against the plain reference,
-  with the bfloat16 control over one.
+  with the bfloat16 control over one;
+- the same two for an `sdar_moe` experiment (block diffusion: the objective
+  is the model's own, the reference draws the program's noise from the
+  feed's key).
 """
 import dataclasses
 import gc
@@ -24,6 +27,7 @@ from dba_mod_tpu.config import Params
 from dba_mod_tpu.fl.experiment import Experiment
 from dba_mod_tpu.fl.rounds import RoundEngine
 from dba_mod_tpu.utils import telemetry
+from tests import sdar_cases
 from tests.lfm2_cases import ARCH, params, small_buffers  # noqa: F401
 
 pytestmark = pytest.mark.usefixtures("small_buffers")
@@ -116,8 +120,50 @@ def test_an_lfm2_experiment_trains_poisons_and_records(tmp_path):
     assert np.isfinite(exp.run_round(4)["global_acc"])
 
 
+def test_an_sdar_experiment_trains_poisons_and_records(tmp_path):
+    """Block diffusion through `main.py`'s path: the streamed round calls the
+    model's own objective; a poisoned round records its backdoor rows; the
+    plan's and the record's spans carry the counts the readers read."""
+    exp = Experiment(sdar_cases.params(run_dir=str(tmp_path), telemetry=False),
+                     save_results=True)
+    assert exp.engine.streamed and exp.model_def.objective is not None
+    # the streams draw every id but MASK, the vocabulary's last
+    assert exp.token_data.vocab_size == 127
+    assert int(exp.token_data.train_tokens.max()) <= 126
+    mark = len(telemetry.spans())
+    results = [exp.run_round(e) for e in (1, 2, 3)]
+    assert all(np.isfinite(r["global_acc"]) for r in results)
+    assert results[1]["backdoor_acc"] is not None
+    rows = program.recorded_rows(exp)
+    assert [r["epoch"] for r in rows] == [1, 2, 3]
+    assert rows[1]["adversaries"] == ["0", "1", "2", "3"]
+    poison_rows = (exp.folder / "posiontest_result.csv").read_text().splitlines()
+    assert sum(line.split(",")[1] == "2" for line in poison_rows) == 1 + 2 * 4
+    spans = telemetry.spans()[mark:]
+    plan = [s.counts for s in spans if s.name == "round/plan"]
+    record = [s.counts for s in spans if s.name == "round/record"]
+    # 4 clients x 2 rows of their own, a row a step; a poisoned round: every
+    # client an adversary's 3 epochs
+    assert [c["client_steps"] for c in plan] == [8, 24, 8]
+    assert all(c["tokens_step"] == 2 * 32 and c["block_length"] == 4
+               for c in plan)
+    # packed rows hold no padding: every position is scored, and the noise
+    # masks between its bounds of them
+    assert [c["positions_scored"] for c in record] == [8 * 32, 24 * 32, 8 * 32]
+    assert all(0.45 < c["positions_masked"] / c["positions_scored"] < 0.95
+               for c in record)
+    # layer 0 routes both streams (64 positions), the last the noisy one (32):
+    # 96 positions x 2 choices, half the experts held
+    assert all(0.5 < c["expert_tokens_held"] / (p["client_steps"] * 96) < 1.5
+               for c, p in zip(record, plan))
+    assert all(c["expert_tokens_max"] >= c["expert_tokens_mean"] for c in record)
+
+
 CONFIG = {"name": "lfm2_toy", "population_seed": 1,
           "model": {"family": "lfm2_moe", "seq_len": 32, "arch": ARCH}}
+SDAR_CONFIG = {"name": "sdar_toy", "population_seed": 1,
+               "model": {"family": "sdar_moe", "seq_len": 32,
+                         "arch": sdar_cases.ARCH}}
 TRAFFIC = {"is_poison": True, "period_rounds": 10,
            "poison_window_rounds": [3, 5, 7, 9], "periods_max": 1,
            "num_devices": 0}
@@ -129,10 +175,15 @@ class Events:
 
 
 @pytest.mark.parametrize("dtype,inside", [("float32", True), ("bfloat16", False)])
-def test_the_familys_check_rounds_against_the_reference(tmp_path, dtype, inside):
-    family = families.of(CONFIG)
+@pytest.mark.parametrize("toy,toy_params", [(CONFIG, params),
+                                            (SDAR_CONFIG, sdar_cases.params)],
+                         ids=["lfm2_moe", "sdar_moe"])
+def test_the_familys_check_rounds_against_the_reference(tmp_path, toy,
+                                                        toy_params, dtype,
+                                                        inside):
+    family = families.of(toy)
     assert all(callable(getattr(family, n)) for n in families.INTERFACE)
-    config = {**CONFIG, "params": dict(params().raw)}
+    config = {**toy, "params": dict(toy_params().raw)}
     first = harness.FIRST_WINDOW_EPOCH
     p, raw = program.make_params(config, TRAFFIC, tmp_path, first,
                                  overrides={"compute_dtype": dtype})
