@@ -420,6 +420,9 @@ class Experiment:
                                                   False))
         self.last_backdoor_acc: Optional[float] = None
         self._apply_resume_aux()
+        # host counters and the slow-round history, from here on: the first
+        # round's counts are those since the build ended
+        self._boundary = telemetry.RoundBoundary()
 
     def _apply_resume_aux(self):
         """Restore the full-state sidecar loaded during resume: FoolsGold
@@ -640,9 +643,11 @@ class Experiment:
 
     def dispatch_round(self, epoch: int) -> RoundInFlight:
         """Telemetry/timing shell around :meth:`_dispatch`: the whole host
-        planning + enqueue runs under the ``round/dispatch`` span (children
-        ``round/plan``, ``round/stage``, ``round/enqueue``) inside a
-        profiler step annotation, so a device trace's step line carries the
+        planning + enqueue runs under the ``round/dispatch`` span (leaves
+        ``round/plan``, ``round/stage``, ``round/enqueue``; a LOAN poison
+        round's ``round/poison_probe`` inside the plan, a robust round's
+        ``round/compute`` and ``round/screen_sync`` inside the enqueue) inside
+        a profiler step annotation, so a device trace's step line carries the
         round; its perf_counter duration lands in ``round_result.csv`` as
         ``dispatch_time``."""
         t0 = time.perf_counter()
@@ -1187,19 +1192,30 @@ class Experiment:
                 "rng_key": np.asarray(jax.random.key_data(self.rng_key))}
 
     def finalize_round(self, fl: RoundInFlight) -> Dict[str, Any]:
-        """The round's one blocking transfer (``round/fetch``) and its
-        recording (``round/record``), both under ``round/finalize``."""
+        """The round's wait for the device (``round/wait``), its one
+        transfer (``round/fetch``) and its recording (``round/record``), the
+        three leaves of ``round/finalize``, whose record also carries the
+        host counters since the previous round's finalize ended
+        (telemetry.py::RoundBoundary). Then, outside the span: the round's
+        account, the ``slow round`` line if it stands out, and the
+        exporters' flush."""
         self.telemetry.set_epoch(fl.epoch)
-        with telemetry.span("round/finalize", round=fl.epoch):
-            return self._finalize(fl)
+        with telemetry.span("round/finalize", round=fl.epoch) as fin_span:
+            result = self._finalize(fl)
+            fin_span.count(**self._boundary.counts())
+        self.telemetry.flush_round(fl.epoch, self._boundary.close(fl.epoch))
+        return result
 
     def _finalize(self, fl: RoundInFlight) -> Dict[str, Any]:
         t_fin = time.perf_counter()
-        # the sync point where a wedged runtime stalls, hence the watchdog
-        # zone (run_guard.py)
-        with self.guard.watch("round/finalize"), \
-                telemetry.span("round/fetch", round=fl.epoch):
-            payload = jax.device_get(fl.payload)
+        with self.guard.watch("round/finalize"):
+            # the sync point where a wedged runtime stalls, hence the
+            # watchdog zone (run_guard.py); `device_get` would wait as well:
+            # apart, a late device and a slow transfer are two numbers
+            with telemetry.span("round/wait", round=fl.epoch):
+                jax.block_until_ready(fl.payload)
+            with telemetry.span("round/fetch", round=fl.epoch):
+                payload = jax.device_get(fl.payload)
             (locals_, globals_, metrics, delta_norms, wv, alpha,
              batches, is_updated, seg_locals, rstats, fstats) = payload[:11]
             # a streamed round appends what its model counted of its own work
@@ -1256,28 +1272,32 @@ class Experiment:
                 # what the model's objective tallied over the real steps
                 record_span.count(**{name: int(v) for name, v
                                      in counts.tallies.items()})
+            written = (self.recorder.files_written,
+                       self.recorder.bytes_written)
             self._record(fl.epoch, fl.seg_epochs, fl.agent_names,
                          fl.adv_names, fl.tasks_list, metrics, locals_,
                          globals_, delta_norms, wv, alpha, times, batches,
                          fl.mask_list, seg_locals, robust)
+            # the recorder rewrites every file whole each round
+            record_span.count(
+                files=self.recorder.files_written - written[0],
+                bytes=self.recorder.bytes_written - written[1])
             if self.forensics_writer is not None and fstats is not None:
                 self._record_forensics(fl, locals_, delta_norms, wv, alpha,
                                        fstats, robust)
-            # flushes the histogram window: this round's own
-            # round/record and round/finalize end after it and land in the
-            # next line of telemetry.jsonl
-            self._flush_round_telemetry(fl, robust, delta_norms, times)
+            self._update_round_registry(fl, robust, delta_norms, times)
         return {"epoch": fl.epoch, "agents": fl.agent_names,
                 "global_acc": float(globals_.clean.acc),
                 "backdoor_acc": (float(globals_.poison.acc)
                                  if self.is_poison_run else None),
                 **times, **robust}
 
-    def _flush_round_telemetry(self, fl: RoundInFlight, robust: Dict[str,
+    def _update_round_registry(self, fl: RoundInFlight, robust: Dict[str,
                                Any], delta_norms, times) -> None:
-        """Per-round metrics-registry update + flush: one telemetry.jsonl
-        line carrying the round's counters/gauges and the span-duration and
-        delta-norm histogram windows (mirrored to TB when wired)."""
+        """Per-round metrics-registry update: the round's counters and its
+        delta-norm and round-time observations. `finalize_round` flushes
+        them, with the span-duration windows, once the round's last span has
+        ended: one telemetry.jsonl line (mirrored to TB when wired)."""
         t = self.telemetry
         if not t.enabled:
             return
@@ -1293,7 +1313,6 @@ class Experiment:
         for n in np.asarray(delta_norms).reshape(-1):
             t.histogram("delta_norm").observe(float(n))
         t.histogram("round_seconds").observe(times["round_time"])
-        t.flush_round(fl.epoch)
 
     def _record_forensics(self, fl: RoundInFlight, locals_, delta_norms,
                           wv, alpha, fstats, robust) -> None:

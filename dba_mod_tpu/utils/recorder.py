@@ -115,6 +115,10 @@ class Recorder:
         self.batch_distance_result: List[list] = []
         self.round_result: List[list] = []
         self._jsonl_rows: List[dict] = []
+        # what `_atomic_write` has put on disk (the `round/record` span's
+        # `files` and `bytes` are a round's share)
+        self.files_written = 0
+        self.bytes_written = 0
 
     def _scalar(self, tag: str, value: float, step: int):
         if self._tb is not None:
@@ -286,7 +290,10 @@ class Recorder:
         try:
             with open(tmp, "w", newline="") as f:
                 emit(f)
+                written = f.tell()  # of a write-only stream: its bytes
             os.replace(tmp, path)
+            self.files_written += 1
+            self.bytes_written += written
         finally:
             if tmp.exists():
                 tmp.unlink()

@@ -15,8 +15,9 @@ all — three failure shapes, two tools:
   host-blocking sync points (``jax.device_get`` at finalize, the robust
   screen sync, the async-checkpoint wait). A stall past ``watchdog_soft_s``
   logs a loud diagnostic (zone label, epoch, elapsed, the telemetry span
-  stack captured at zone entry); past ``watchdog_hard_s`` the process is
-  aborted with :data:`EXIT_WATCHDOG` — a wedged run dies *checkpointed*
+  stack captured at zone entry, the round's account so far); past
+  ``watchdog_hard_s`` the process is aborted with :data:`EXIT_WATCHDOG` — a
+  wedged run dies *checkpointed*
   (the previous round's verified checkpoint is on disk) instead of burning
   quota silently.
 
@@ -28,6 +29,7 @@ attribute check. :class:`RunGuard` bundles them behind the config knobs
 from __future__ import annotations
 
 import contextlib
+import json
 import logging
 import os
 import signal
@@ -156,12 +158,23 @@ class GracefulShutdown:
             "force immediate exit", name, EXIT_INTERRUPTED)
 
 
+def _account_so_far(round_id: Optional[int]) -> str:
+    """The stalled round's row of `telemetry.round_accounts` from the spans
+    it has finished (its dispatch's leaves; the open ones are the stack at
+    entry), as JSON; "-" where it has finished none."""
+    recent = max(0, len(telemetry.spans()) - 256)
+    rows = [a for a in telemetry.round_accounts(recent)
+            if a["round"] == round_id]
+    return json.dumps(rows[-1]) if rows else "-"
+
+
 class _Zone:
     __slots__ = ("label", "t0", "soft_at", "hard_at", "soft_fired",
-                 "epoch", "spans")
+                 "epoch", "spans", "round")
 
     def __init__(self, label: str, t0: float, soft_at: float, hard_at: float,
-                 epoch: Optional[int], spans: List[str]):
+                 epoch: Optional[int], spans: List[str],
+                 round_id: Optional[int]):
         self.label = label
         self.t0 = t0
         self.soft_at = soft_at
@@ -169,6 +182,7 @@ class _Zone:
         self.soft_fired = False
         self.epoch = epoch
         self.spans = spans
+        self.round = round_id  # of the innermost open span: knob on or off
 
 
 class Watchdog:
@@ -178,8 +192,9 @@ class Watchdog:
     block disarms it. One daemon thread (started lazily on the first armed
     zone, never when disabled) watches the active zone: at
     ``soft_s`` it logs a stall diagnostic once — the zone label, current
-    epoch, elapsed seconds, and the telemetry span stack captured at zone
-    entry (captured *in the arming thread*; the span stack is
+    epoch, elapsed seconds, the stalled round's account from the spans it
+    has finished (`telemetry.round_accounts`) and the telemetry span stack
+    captured at zone entry (captured *in the arming thread*; the span stack is
     thread-local, and the arming thread is the one that is about to be
     wedged inside the zone) — at ``hard_s`` it aborts the process via
     `on_hard` (default: flush logging, ``os._exit(EXIT_WATCHDOG)``).
@@ -220,7 +235,7 @@ class Watchdog:
         z = _Zone(label, t0,
                   t0 + self.soft_s if self.soft_s > 0 else float("inf"),
                   t0 + self.hard_s if self.hard_s > 0 else float("inf"),
-                  t.current_epoch, t.span_stack())
+                  t.current_epoch, t.span_stack(), telemetry.open_round())
         with self._cv:
             self._zone = z
             self._cv.notify()
@@ -267,9 +282,10 @@ class Watchdog:
                 telemetry.count("watchdog/soft_stalls")
                 logger.error(
                     "watchdog: %s has stalled for %.1fs (soft limit %.1fs) "
-                    "— epoch=%s span stack at entry=%s; hard abort %s",
+                    "— epoch=%s open spans at entry=%s, the round's account "
+                    "so far=%s; hard abort %s",
                     z.label, elapsed, self.soft_s, z.epoch,
-                    z.spans or ["-"],
+                    z.spans or ["-"], _account_so_far(z.round),
                     (f"at {self.hard_s:.1f}s" if self.hard_s > 0
                      else "disabled"))
             if now >= z.hard_at:

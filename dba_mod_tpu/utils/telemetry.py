@@ -6,16 +6,30 @@ metrics registry, and XLA compile/memory instrumentation for the round path.
   a ``jax.profiler.TraceAnnotation`` (so under any ``jax.profiler`` trace —
   ``profile_dir``, or a benchmark harness — it lies in the same
   ``.xplane.pb`` as the device operations) and appends one
-  :class:`SpanRecord` (name, start/end in ``time.time_ns()``, which is the
-  clock the profiler's ``TraceMe`` reads; the enclosing span; the round; the
+  :class:`SpanRecord` (name, start and end; the enclosing span; the round; the
   counts the block gave it with ``.count(...)``) to a bounded process-wide
-  list, read with :func:`spans`. A span times HOST work:
+  list, read with :func:`spans`. **Two clocks**: a record's start is
+  ``time.time_ns()``, the clock the profiler's ``TraceMe`` reads, so the
+  record lies on a device trace's timeline; its length is a
+  ``time.perf_counter_ns()`` difference (``end_ns`` is the start plus it), so
+  a step of the wall clock can neither make nor hide a stall. A span times
+  HOST work:
   it never syncs the device, so the program that is traced is the program
   that is timed. Device time per phase comes from the ``jax.named_scope``
   names inside the round program (``phase/train``, ``phase/aggregate``,
   ``phase/local_battery``, ``phase/global_battery``; fl/rounds.py) under a
-  profiler trace. With nothing exporting a span costs two clock reads, one
+  profiler trace. With nothing exporting a span costs three clock reads, one
   list append and an inactive ``TraceMe``.
+- **Round accounts** — :func:`round_accounts` reduces the records to one row
+  a round: its extent, the milliseconds in each leaf span (``round/wait`` is
+  the wait for the device, ``round/fetch`` the transfer alone), each
+  parent's self time, the time in no span, ``host_ms`` (the extent less the
+  wait and the time in no span) and the host counters
+  :class:`RoundBoundary` samples where a round's finalize ends (CPU time,
+  run-queue wait, context switches, faults, block I/O, the collector's
+  pauses, compiles). :meth:`RoundBoundary.close` logs one ``slow round``
+  warning, knob on or off, when a round's row stands out from the rows
+  before it (the two rules are the ``SLOW_*`` constants below).
 - **Compile stages** — a ``jax.monitoring`` listener, installed once per
   process and counting whether or not the knob is on, sums seconds per stage
   and per jitted function: ``xla/trace_secs``, ``xla/lower_secs``,
@@ -37,12 +51,16 @@ additive observability, not part of the reference-parity CSV set (PARITY.md).
 """
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
 import re
+import resource
+import statistics
 import threading
 import time
+from collections import deque
 from pathlib import Path
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
@@ -71,8 +89,10 @@ _LOCK = threading.Lock()
 
 # ------------------------------------------------------------------- spans
 class SpanRecord(NamedTuple):
-    """One finished span. Times are ``time.time_ns()``: the clock of the
-    profiler's annotations (an xplane's ``profile_start_time`` is its zero)."""
+    """One finished span. ``start_ns`` is ``time.time_ns()``: the clock of the
+    profiler's annotations (an xplane's ``profile_start_time`` is its zero);
+    ``end_ns`` is the start plus the span's ``time.perf_counter_ns()``
+    length."""
     name: str
     start_ns: int
     end_ns: int
@@ -97,7 +117,7 @@ def _stack() -> list:
 
 class _Span:
     __slots__ = ("name", "ids", "counts", "_annotation", "_parent", "_round",
-                 "_t0")
+                 "_t0", "_p0")
 
     def __init__(self, name: str, ids: Dict[str, Any]):
         self.name = name
@@ -118,11 +138,12 @@ class _Span:
         self._annotation = TraceAnnotation(self.name, **self.ids)
         self._annotation.__enter__()
         self._t0 = time.time_ns()
+        self._p0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         global _dropped
-        end = time.time_ns()
+        end = self._t0 + (time.perf_counter_ns() - self._p0)
         self._annotation.__exit__(*exc)
         _stack().pop()
         record = SpanRecord(self.name, self._t0, end, self._parent,
@@ -148,10 +169,6 @@ def spans(since: int = 0) -> List[SpanRecord]:
     return _records[since:]
 
 
-def spans_dropped() -> int:
-    return _dropped
-
-
 def phase() -> str:
     """The calling thread's innermost open span, "-" outside any."""
     stack = getattr(_local, "stack", None)
@@ -165,12 +182,247 @@ def span_stack() -> List[str]:
     return [name for name, _ in getattr(_local, "stack", None) or ()]
 
 
+def open_round() -> Optional[int]:
+    """The round of the calling thread's innermost open span."""
+    stack = getattr(_local, "stack", None)
+    return stack[-1][1] if stack else None
+
+
 class _SpanAccess:
     """`t.span(...)`, `t.phase()`, `t.span_stack()` on either telemetry
     object: the module's, whatever instance is current."""
     span = staticmethod(span)
     phase = staticmethod(phase)
     span_stack = staticmethod(span_stack)
+
+
+# ---------------------------------------------------------- round accounts
+ROUND_WAIT = "round/wait"          # the leaf that waits for the device
+ROUND_FINALIZE = "round/finalize"  # carries the boundary's host counters
+ROUND_RECORD = "round/record"      # carries what the recorder wrote
+ROUND_CHECKPOINT = "round/checkpoint"  # ends after its round's finalize
+RECORD_COUNTS = ("files", "bytes")
+# The slow-round rules (RoundBoundary.close); constants, not parameters.
+# Rule "host_ms": over SLOW_HOST_MS and over SLOW_HOST_RATIO times the median
+# `host_ms` of the rounds before it that compiled nothing, once there are
+# SLOW_HOST_MIN_ROUNDS of them (`host_ms` is steady from the first round, and
+# the stalls met so far fell in a window's first rounds). Rule "extent_ms":
+# the extent over SLOW_EXTENT_RATIO times the longest extent of any round
+# before it, once there are SLOW_EXTENT_MIN_ROUNDS: a poisoned round is half
+# again a clean one, so this rule needs a period of the schedule behind it
+# and cannot see a late device in a run's first rounds.
+SLOW_HOST_MS = 50.0
+SLOW_HOST_RATIO = 3.0
+SLOW_HOST_MIN_ROUNDS = 2
+SLOW_EXTENT_RATIO = 1.25
+SLOW_EXTENT_MIN_ROUNDS = 8
+SLOW_HISTORY = 64  # rows the running medians are taken over
+
+# process-wide tallies the boundary samples: the collector's (one
+# `gc.callbacks` entry) and the compiler's (`_on_event_duration`); both are
+# installed by `install_xla_listeners`
+_gc_tally = {"gc_collections": 0, "gc_pause_ns": 0, "gc_gen2": 0}
+_gc_started_ns = 0
+_compile_tally = {"compiles": 0, "compile_ns": 0}
+# None once the kernel has refused it: a sample then costs no failed open
+_schedstat: Optional[str] = "/proc/thread-self/schedstat"
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    global _gc_started_ns
+    if phase == "start":
+        _gc_started_ns = time.perf_counter_ns()
+        return
+    _gc_tally["gc_collections"] += 1
+    _gc_tally["gc_pause_ns"] += time.perf_counter_ns() - _gc_started_ns
+    if info.get("generation") == 2:
+        _gc_tally["gc_gen2"] += 1
+
+
+def _sample_host() -> Dict[str, int]:
+    """Cumulative host counters of the calling thread and its process.
+    `runq_wait_ns` (time runnable but not running: the second field of the
+    thread's schedstat) is absent where the kernel does not give the file."""
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    sample = {"wall_ns": time.perf_counter_ns(),
+              "cpu_ns": time.thread_time_ns(),
+              "proc_cpu_ns": time.process_time_ns(),
+              "nvcsw": usage.ru_nvcsw, "nivcsw": usage.ru_nivcsw,
+              "majflt": usage.ru_majflt, "inblock": usage.ru_inblock,
+              "oublock": usage.ru_oublock, **_gc_tally, **_compile_tally}
+    global _schedstat
+    if _schedstat is not None:
+        try:
+            with open(_schedstat) as f:
+                sample["runq_wait_ns"] = int(f.read().split()[1])
+        except (OSError, IndexError, ValueError):
+            _schedstat = None
+    return sample
+
+
+def _account(records: List[SpanRecord]) -> Dict[str, Any]:
+    """One round's records -> its row. Spans of one thread nest, so in order
+    of their starts the enclosing record of each is the nearest one before it
+    that bears its `parent`'s name (a length comes from another clock than a
+    start, so intervals are not compared): a record that encloses none is a
+    leaf, one that does gives its self time (its length less its children's),
+    and what no outermost record covers is `between`. Leaves, self times and
+    `between` sum to the extent."""
+    start = min(r.start_ns for r in records)
+    extent = max(r.end_ns for r in records) - start
+    ordered = sorted(records, key=lambda r: (r.tid, r.start_ns, -r.end_ns))
+    covered = [0] * len(ordered)   # by its children
+    parents = set()
+    between = extent
+    stack: List[int] = []
+    for i, r in enumerate(ordered):
+        while stack and not (ordered[stack[-1]].tid == r.tid
+                             and ordered[stack[-1]].name == r.parent):
+            stack.pop()
+        if stack:
+            covered[stack[-1]] += r.end_ns - r.start_ns
+            parents.add(stack[-1])
+        else:
+            between -= r.end_ns - r.start_ns
+        stack.append(i)
+    leaves: Dict[str, int] = {}
+    selfs: Dict[str, int] = {}
+    counts: Dict[str, Any] = {}
+    for i, r in enumerate(ordered):
+        into = selfs if i in parents else leaves
+        into[r.name] = (into.get(r.name, 0)
+                        + r.end_ns - r.start_ns - covered[i])
+        if r.name == ROUND_FINALIZE and r.counts:
+            counts.update(r.counts)
+        elif r.name == ROUND_RECORD and r.counts:
+            counts.update({k: r.counts[k] for k in RECORD_COUNTS
+                           if k in r.counts})
+    wait = leaves.get(ROUND_WAIT, 0)
+    ms = lambda ns: ns / 1e6
+    return {"round": records[0].round, "start_ns": start,
+            "extent_ms": ms(extent),
+            "leaves": {k: ms(v) for k, v in leaves.items()},
+            "self": {k: ms(v) for k, v in selfs.items()},
+            "between_ms": ms(between), "wait_ms": ms(wait),
+            "host_ms": ms(extent - wait - between), "counts": counts}
+
+
+def round_accounts(since: int = 0,
+                   records: Optional[List[SpanRecord]] = None,
+                   ) -> List[Dict[str, Any]]:
+    """One row a round, in order of their starts: a pure reduction of the
+    span records that carry a round (the process's from index `since`, or
+    `records`). A row holds `round`, `start_ns`, `extent_ms` (first start to
+    last end of the round's records), `leaves` (milliseconds in each leaf
+    span, by name), `self` (each parent's self time), `between_ms` (inside
+    the extent, in no span of the round: a harness's own device wait between
+    `dispatch_round` and `finalize_round`, the next round's dispatch under
+    `pipeline_rounds`; 0 in `run_round`), `wait_ms` (the `round/wait` leaf),
+    `host_ms` (the extent less `wait_ms` and `between_ms`) and `counts` (what
+    :class:`RoundBoundary` put on `round/finalize`, and the recorder's
+    `files` and `bytes` from `round/record`). A round id that comes again
+    after its `round/finalize` (a second experiment in the process) opens a
+    row of its own; `round/checkpoint` ends after the finalize and stays in
+    its round's row. Records the full list turned away have no row: their
+    number is `spans_dropped` in the last row's counts."""
+    groups: List[List[SpanRecord]] = []
+    open_group: Dict[Any, List[SpanRecord]] = {}
+    finalized = set()
+    for r in (_records[since:] if records is None else records):
+        if r.round is None:
+            continue
+        if r.round in finalized and r.name != ROUND_CHECKPOINT:
+            finalized.discard(r.round)
+            del open_group[r.round]
+        if r.round not in open_group:
+            open_group[r.round] = []
+            groups.append(open_group[r.round])
+        open_group[r.round].append(r)
+        if r.name == ROUND_FINALIZE:
+            finalized.add(r.round)
+    rows = sorted((_account(g) for g in groups), key=lambda a: a["start_ns"])
+    if rows and _dropped and records is None:
+        rows[-1]["counts"]["spans_dropped"] = _dropped
+    return rows
+
+
+def _medians(rows) -> Dict[str, Any]:
+    """The median of every number of the rows, key by key."""
+    def med(values):
+        values = [v for v in values if v is not None]
+        return statistics.median(values) if values else None
+    out: Dict[str, Any] = {k: med(r[k] for r in rows)
+                           for k in ("extent_ms", "between_ms", "wait_ms",
+                                     "host_ms")}
+    for group in ("leaves", "self", "counts"):
+        keys = {k for r in rows for k in r[group]}
+        out[group] = {k: med(r[group].get(k) for r in rows)
+                      for k in sorted(keys)}
+    return out
+
+
+class RoundBoundary:
+    """What an experiment keeps from one round to the next: the last sample
+    of the host counters, where its closed rounds' records end, and the
+    bounded history the slow-round rules read."""
+
+    def __init__(self):
+        self._last = _sample_host()
+        self._mark = len(_records)
+        self._quiet: deque = deque(maxlen=SLOW_HISTORY)  # compiled nothing
+        self._rounds = 0
+        self._longest_ms = 0.0
+
+    def counts(self) -> Dict[str, int]:
+        """The host counters' deltas since the previous call (the first:
+        since this object was built), for the `round/finalize` span that is
+        about to end: the rows tile the process's time, so a stall between
+        two rounds is in the second one's counts. `cpu_ns` is the calling
+        thread's: the thread that built the experiment runs its rounds."""
+        now = _sample_host()
+        last, self._last = self._last, now
+        return {k: v - last[k] for k, v in now.items() if k in last}
+
+    def close(self, round_id: int) -> Optional[Dict[str, Any]]:
+        """The account of the round whose finalize just ended, and the
+        slow-round line if one of the two rules holds. One pass over the
+        records made since the previous round closed."""
+        rows = [a for a in round_accounts(self._mark)
+                if a["round"] == round_id]
+        self._mark = next(
+            (i for i in range(self._mark, len(_records))
+             if _records[i].round is not None
+             and _records[i].round > round_id), len(_records))
+        if not rows:
+            return None   # the record list is full
+        row = rows[-1]
+        hosts = [q["host_ms"] for q in self._quiet]
+        rule = None
+        if (len(hosts) >= SLOW_HOST_MIN_ROUNDS
+                and row["host_ms"] > SLOW_HOST_MS
+                and row["host_ms"] > SLOW_HOST_RATIO
+                * statistics.median(hosts)):
+            rule = "host_ms"
+        elif (self._rounds >= SLOW_EXTENT_MIN_ROUNDS
+                and row["extent_ms"] > SLOW_EXTENT_RATIO * self._longest_ms):
+            rule = "extent_ms"
+        if rule is not None:
+            usual = _medians(self._quiet)
+            # the part furthest over its usual length; a stall of the host
+            # is not looked for in the wait for the device
+            over = {name: value - (usual[group].get(name) or 0.0)
+                    for group in ("leaves", "self")
+                    for name, value in row[group].items()
+                    if rule == "extent_ms" or name != ROUND_WAIT}
+            logger.warning("slow round %s", json.dumps(
+                {"round": round_id, "rule": rule,
+                 "leaf": max(over, key=over.get), "account": row,
+                 "median_of": len(self._quiet), "median": usual}))
+        self._rounds += 1
+        self._longest_ms = max(self._longest_ms, row["extent_ms"])
+        if not row["counts"].get("compiles"):
+            self._quiet.append(row)
+        return row
 
 
 def _percentile(sorted_vals: List[float], q: float) -> float:
@@ -278,7 +530,8 @@ class _NullTelemetry(_SpanAccess):
     def record_memory(self) -> None:
         pass
 
-    def flush_round(self, epoch: int) -> None:
+    def flush_round(self, epoch: int,
+                    account: Optional[Dict[str, Any]] = None) -> None:
         pass
 
     def write_trace(self) -> None:
@@ -398,10 +651,13 @@ class Telemetry(_SpanAccess):
         self.peak_memory_bytes = max(self.peak_memory_bytes, int(peak))
 
     # ----------------------------------------------------------- round flush
-    def flush_round(self, epoch: int) -> None:
+    def flush_round(self, epoch: int,
+                    account: Optional[Dict[str, Any]] = None) -> None:
         """One JSON line per round: cumulative counters, last-value gauges,
-        and the histogram window since the previous flush (span durations,
-        delta norms). Mirrored to TensorBoard when a sink is wired."""
+        the histogram window since the previous flush (span durations,
+        delta norms) and, as `account`, the round's row of
+        :func:`round_accounts`. Mirrored to TensorBoard when a sink is
+        wired."""
         self.record_memory()
         with _LOCK:
             counters = {k: c.value for k, c in self._counters.items()}
@@ -415,6 +671,8 @@ class Telemetry(_SpanAccess):
                 hists[k] = {m: round(v, 6) for m, v in snap.items()}
         row = {"epoch": int(epoch), "time": time.time(),
                "counters": counters, "gauges": gauges, "histograms": hists}
+        if account is not None:
+            row["account"] = account
         if self.folder is not None:
             with open(self.folder / "telemetry.jsonl", "a") as f:
                 f.write(json.dumps(row) + "\n")
@@ -461,8 +719,8 @@ class Telemetry(_SpanAccess):
 
     # -------------------------------------------------------------- summary
     def summary_table(self) -> str:
-        """End-of-run phase summary: p50/p95 per span, recompile count, peak
-        device memory."""
+        """End-of-run phase summary: p50/p95 per span, the three rounds with
+        the largest `host_ms`, recompile count, peak device memory."""
         by_name: Dict[str, List[float]] = {}
         for r in self.own_spans():
             by_name.setdefault(r.name, []).append(
@@ -476,6 +734,15 @@ class Telemetry(_SpanAccess):
                 f"{name:<32} {len(vals):>6} {sum(vals):>9.3f} "
                 f"{_percentile(vals, 0.50) * 1e3:>9.2f} "
                 f"{_percentile(vals, 0.95) * 1e3:>9.2f}")
+        accounts = round_accounts(self._first_span)
+        for a in sorted(accounts, key=lambda a: -a["host_ms"])[:3]:
+            named = {**a["self"], **a["leaves"]}
+            named.pop(ROUND_WAIT, None)
+            top = max(named, key=named.get)
+            lines.append(
+                f"round {a['round']}: host_ms {a['host_ms']:.2f} of "
+                f"{a['extent_ms']:.2f} (wait {a['wait_ms']:.2f}, between "
+                f"{a['between_ms']:.2f}); most in {top} {named[top]:.2f}")
         compiles = self.counter("xla/compiles").value
         recompiles = self.counter("xla/recompiles_after_warmup").value
         mem = (f"{self.peak_memory_bytes / 2**20:.1f} MiB"
@@ -530,16 +797,6 @@ def count(name: str, n: int = 1) -> None:
         _current.counter(name).inc(n)
 
 
-def observe(name: str, v: float) -> None:
-    if _current.enabled:
-        _current.histogram(name).observe(v)
-
-
-def set_gauge(name: str, v: float) -> None:
-    if _current.enabled:
-        _current.gauge(name).set(v)
-
-
 def set_epoch(epoch: Optional[int]) -> None:
     _current.set_epoch(epoch)
 
@@ -591,6 +848,9 @@ def _on_event_duration(event: str, duration: float, **kwargs) -> None:
     with _LOCK:
         stages = _compile_stages.setdefault(fun, {})
         stages[stage] = stages.get(stage, 0.0) + float(duration)
+        if event == BACKEND_COMPILE_EVENT:  # a cache retrieval lies inside
+            _compile_tally["compiles"] += 1
+            _compile_tally["compile_ns"] += int(duration * 1e9)
     t = _current
     if not t.enabled:
         return
@@ -611,15 +871,17 @@ def _on_event(event: str, **kwargs) -> None:
 
 
 def install_xla_listeners() -> None:
-    """Register the jax.monitoring listeners once per process. The listeners
-    forward to whatever instance is current, so they are safe to leave
-    installed when telemetry is later disabled."""
+    """Register the jax.monitoring listeners, and the collector's callback
+    the round boundary reads, once per process. The listeners forward to
+    whatever instance is current, so they are safe to leave installed when
+    telemetry is later disabled."""
     global _listeners_installed
     if _listeners_installed:
         return
     import jax
     jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
     jax.monitoring.register_event_listener(_on_event)
+    gc.callbacks.append(_on_gc)
     _listeners_installed = True
 
 

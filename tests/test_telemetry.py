@@ -9,11 +9,15 @@ jitted function with the knob off), no-op mode adding no files, idempotent
 logging setup, the recorder's atomic save (a failure mid-write leaves the
 previous file intact), the names the device trace needs (the round program's
 four `phase/` scopes, the fused update's kernel name), the benchmark's
-reduction of them (chipbench/phases.py), and the end-to-end Experiment wiring
+reduction of them (chipbench/phases.py), the end-to-end Experiment wiring
 — `telemetry: true` runs the same fused program as off, to the bit, and only
-adds the exporters' files.
+adds the exporters' files — and a round's account of its own wall clock: the
+`round/wait` leaf, the host counters on `round/finalize`, the reduction to one
+row a round, the slow-round line and the benchmark's readers of the rows
+(chipbench/accounts.py).
 """
 import csv
+import gc
 import json
 import logging
 import time
@@ -152,8 +156,6 @@ def test_noop_mode_adds_no_files_and_no_state(tmp_path):
     with tel.span("x"):
         pass
     tel.count("c")
-    tel.observe("h", 1.0)
-    tel.set_gauge("g", 2.0)
     tel.sync(jnp.ones((2,)))
     t.flush_round(1)
     t.write_trace()
@@ -238,7 +240,8 @@ def test_round_header_carries_split_times():
 # ------------------------------------------------------------- end-to-end
 ROUND_SPANS = {"round/dispatch": ("round/plan", "round/stage",
                                   "round/enqueue"),
-               "round/finalize": ("round/fetch", "round/record")}
+               "round/finalize": ("round/wait", "round/fetch",
+                                  "round/record")}
 
 
 def _state_and_rows(e):
@@ -326,8 +329,9 @@ def test_experiment_telemetry_end_to_end(tmp_path, off_run):
         names = {ev["name"] for ev in doc["traceEvents"]
                  if ev.get("ph") == "X"}
         assert {"round/dispatch", "round/plan", "round/stage",
-                "round/enqueue", "round/finalize", "round/fetch",
-                "round/record", "engine/build", "setup/data"} <= names
+                "round/enqueue", "round/finalize", "round/wait",
+                "round/fetch", "round/record", "engine/build",
+                "setup/data"} <= names
         assert not {"round/train", "round/aggregate", "eval/local",
                     "eval/global"} & names
         assert e.engine.round_fn._cache_size() == 1
@@ -336,13 +340,20 @@ def test_experiment_telemetry_end_to_end(tmp_path, off_run):
                  (folder / "telemetry.jsonl").read_text().splitlines()]
         assert [ln["epoch"] for ln in lines] == [1, 2]
         last = lines[-1]
-        # per-round span durations; a round's finalize and record end after
-        # its flush, so round 1's are in round 2's line
+        # per-round span durations: the flush comes after the round's last
+        # span has ended, so a line holds its own round's, once each
         for span in ("span/round/dispatch", "span/round/plan",
-                     "span/round/fetch", "span/round/finalize",
-                     "span/round/record"):
-            assert last["histograms"][span]["count"] >= 1
+                     "span/round/wait", "span/round/fetch",
+                     "span/round/finalize", "span/round/record"):
+            assert last["histograms"][span]["count"] == 1
         assert last["counters"]["rounds"] == 2
+        # the round's own account, as the reduction of the records gives it
+        assert [ln["account"]["round"] for ln in lines] == [1, 2]
+        assert last["account"] == tel.round_accounts(
+            records=[r for r in e.telemetry.own_spans()
+                     if r.round == 2])[0]
+        assert last["account"]["counts"]["compiles"] == 0
+        assert lines[0]["account"]["counts"]["compiles"] >= 1
         # no retraces once the first full round has compiled everything
         assert last["counters"]["xla/recompiles_after_warmup"] == 0
         # the recorder carries the honest split times
@@ -354,6 +365,7 @@ def test_experiment_telemetry_end_to_end(tmp_path, off_run):
         assert float(times["finalize_time"]) > 0
         summary = e.telemetry.summary_table()
         assert "round/dispatch" in summary and "xla compiles" in summary
+        assert "round 1: host_ms" in summary  # it compiled: the largest
         leaves_on, rows_on = _state_and_rows(e)
     finally:
         tel.configure(enabled=False)
@@ -540,3 +552,324 @@ def test_steps_selfcheck_reads_the_recorded_sample():
     hand, and BENCHMARK.json's entries for them."""
     from chipbench import selfcheck_steps
     assert selfcheck_steps.main() == 0
+
+
+# ------------------------------- a round's account of its own wall clock
+BOUNDARY_COUNTS = ("wall_ns", "cpu_ns", "proc_cpu_ns", "nvcsw", "nivcsw",
+                   "majflt", "inblock", "oublock", "gc_collections",
+                   "gc_pause_ns", "gc_gen2", "compiles", "compile_ns")
+
+
+class _SlowLines(logging.Handler):
+    """The `slow round` warnings of the program's logger, parsed."""
+
+    def __init__(self):
+        super().__init__(level=logging.WARNING)
+        self.lines = []
+
+    def emit(self, record):
+        if record.getMessage().startswith("slow round "):
+            self.lines.append(json.loads(record.args[0]))
+
+    def __enter__(self):
+        logging.getLogger("dba_mod_tpu").addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        logging.getLogger("dba_mod_tpu").removeHandler(self)
+
+
+def _run_rounds(tmp, rounds, in_save=None):
+    """`rounds` rounds of the smoke experiment with the knob off;
+    `in_save(epoch)` runs inside every `Recorder.save`, so inside the
+    round's `round/record`. The collector is off meanwhile (a full
+    collection of a test process's heap is a stall of its own, and the
+    slow-round line says so), and the `host_ms` rule's floor is 150 ms, not
+    50: beside five busy workers the round thread loses its core for tens
+    of milliseconds. -> (records, rows, slow lines)."""
+    e = Experiment(Params.from_dict(dict(
+        SMOKE, epochs=rounds, run_dir=str(tmp / "runs"))))
+    gc.collect()
+    # the rounds' counts start here, after that collection
+    e._boundary = tel.RoundBoundary()
+    save = e.recorder.save
+
+    def patched(is_poison):
+        if in_save is not None:
+            in_save(tel.open_round())
+        return save(is_poison)
+
+    e.recorder.save = patched
+    n0 = len(tel.spans())
+    floor, tel.SLOW_HOST_MS = tel.SLOW_HOST_MS, 150.0
+    gc.disable()
+    try:
+        with _SlowLines() as slow:
+            for epoch in range(1, rounds + 1):
+                e.run_round(epoch)
+    finally:
+        gc.enable()
+        tel.SLOW_HOST_MS = floor
+    return tel.spans(n0), tel.round_accounts(n0), slow.lines
+
+
+@pytest.fixture(scope="module")
+def account_run(tmp_path_factory):
+    """Eight quiet rounds, shared read-only."""
+    return _run_rounds(tmp_path_factory.mktemp("tel_accounts"), 8)
+
+
+def test_wait_and_fetch_are_siblings_and_the_wait_comes_first(account_run):
+    records, _, _ = account_run
+    for rnd in range(1, 9):
+        of = {r.name: r for r in records if r.round == rnd}
+        wait, fetch = of["round/wait"], of["round/fetch"]
+        assert wait.parent == fetch.parent == "round/finalize"
+        assert wait.end_ns <= fetch.start_ns
+        # on this backend the round's compute is inside the wait, and the
+        # transfer alone is short
+        assert (wait.end_ns - wait.start_ns) > (fetch.end_ns - fetch.start_ns)
+
+
+def test_accounts_tile_the_process_time(account_run):
+    records, rows, _ = account_run
+    assert [a["round"] for a in rows] == list(range(1, 9))
+    ends = []
+    for a in rows:
+        parts = (sum(a["leaves"].values()) + sum(a["self"].values())
+                 + a["between_ms"])
+        assert parts == pytest.approx(a["extent_ms"], abs=1e-3)
+        assert set(a["self"]) == {"round/dispatch", "round/finalize"}
+        assert set(a["leaves"]) == {"round/plan", "round/stage",
+                                    "round/enqueue", "round/wait",
+                                    "round/fetch", "round/record"}
+        assert a["wait_ms"] == a["leaves"]["round/wait"]
+        assert a["host_ms"] == pytest.approx(
+            a["extent_ms"] - a["wait_ms"] - a["between_ms"], abs=1e-6)
+        assert 0 <= a["between_ms"] < 1.0  # run_round: nothing in between
+        ends.append(a["start_ns"] + a["extent_ms"] * 1e6)
+    for a, b, end in zip(rows, rows[1:], ends):
+        assert end <= b["start_ns"]  # sequential rounds do not overlap
+        # the counts' tile runs from one finalize's end to the next one's
+        assert b["counts"]["wall_ns"] / 1e6 == pytest.approx(
+            (b["start_ns"] - end) / 1e6 + b["extent_ms"], abs=1.0)
+
+
+def test_boundary_counts_ride_the_finalize_and_record_spans(account_run):
+    records, rows, _ = account_run
+    for rnd in range(1, 9):
+        of = {r.name: r for r in records if r.round == rnd}
+        counts = of["round/finalize"].counts
+        assert set(BOUNDARY_COUNTS) <= set(counts)
+        assert set(counts) <= set(BOUNDARY_COUNTS) | {"runq_wait_ns"}
+        assert all(isinstance(v, int) and v >= 0 for v in counts.values())
+        assert 0 < counts["cpu_ns"] <= counts["wall_ns"]
+        written = of["round/record"].counts
+        assert written["files"] >= 3 and written["bytes"] > 0
+    # the first round compiled the round program, the later ones nothing
+    assert rows[0]["counts"]["compiles"] >= 1
+    assert rows[0]["counts"]["compile_ns"] > 0
+    assert all(a["counts"]["compiles"] == 0 for a in rows[2:])
+    # the recorder rewrites its files whole: a round writes more than the last
+    sizes = [a["counts"]["bytes"] for a in rows]
+    assert sizes == sorted(sizes) and sizes[0] < sizes[-1]
+
+
+def test_quiet_rounds_print_no_slow_round_line(account_run):
+    _, rows, slow = account_run
+    assert len(rows) == 8 and slow == []
+
+
+def test_forced_collection_shows_in_its_round_only(tmp_path):
+    _, rows, _ = _run_rounds(  # no collection but the forced one
+        tmp_path, 4, lambda epoch: gc.collect() if epoch == 3 else None)
+    for a in rows:
+        c = a["counts"]
+        if a["round"] == 3:
+            assert c["gc_collections"] == 1 and c["gc_gen2"] == 1
+            assert 0 < c["gc_pause_ns"] < c["wall_ns"]
+            assert c["gc_pause_ns"] / 1e6 <= a["leaves"]["round/record"]
+        else:
+            assert (c["gc_collections"], c["gc_pause_ns"],
+                    c["gc_gen2"]) == (0, 0, 0)
+
+
+def test_a_stall_in_the_recorder_gives_one_slow_round_line(tmp_path):
+    _, rows, slow = _run_rounds(
+        tmp_path, 12, lambda epoch: time.sleep(0.4) if epoch == 9 else None)
+    assert len(slow) == 1
+    line, = slow
+    assert line["round"] == 9 and line["rule"] == "host_ms"
+    assert line["leaf"] == "round/record"
+    assert line["account"] == rows[8]
+    assert line["account"]["leaves"]["round/record"] >= 400
+    assert line["median"]["leaves"]["round/record"] < 100
+    assert line["median_of"] >= tel.SLOW_HOST_MIN_ROUNDS
+    # every count, beside its running median
+    assert set(BOUNDARY_COUNTS) | {"files", "bytes"} <= (
+        set(line["account"]["counts"]) & set(line["median"]["counts"]))
+    # the thread slept: the stall is wall time, not CPU time
+    c = line["account"]["counts"]
+    assert c["wall_ns"] - c["cpu_ns"] >= 400e6 - 5e6
+
+
+def _spans_of_a_round(boundary, rnd, wait_s=0.0, record_s=0.0):
+    with tel.span("round/dispatch", round=rnd):
+        with tel.span("round/plan", round=rnd):
+            pass
+    with tel.span("round/finalize", round=rnd) as fin:
+        with tel.span("round/wait", round=rnd):
+            time.sleep(wait_s)
+        with tel.span("round/record", round=rnd):
+            time.sleep(record_s)
+        fin.count(**boundary.counts())
+    return boundary.close(rnd)
+
+
+@pytest.mark.parametrize("case", ["late_device", "late_device_too_early",
+                                  "host_after_two", "host_under_50_ms"])
+def test_slow_round_rules(case):
+    """The two rules on hand-made rounds: a late device needs eight rounds
+    behind it (the extent rule), a stall of the host two."""
+    tel.install_xla_listeners()
+    boundary = tel.RoundBoundary()
+    before = {"late_device": 8, "late_device_too_early": 7,
+              "host_after_two": 2, "host_under_50_ms": 2}[case]
+    with _SlowLines() as slow:
+        for rnd in range(1, before + 1):
+            _spans_of_a_round(boundary, rnd, wait_s=0.02)
+        assert slow.lines == []
+        if case.startswith("late_device"):
+            row = _spans_of_a_round(boundary, before + 1, wait_s=0.06)
+        else:
+            row = _spans_of_a_round(
+                boundary, before + 1, wait_s=0.02,
+                record_s=0.08 if case == "host_after_two" else 0.01)
+    if case == "late_device":
+        line, = slow.lines
+        assert (line["rule"], line["leaf"]) == ("extent_ms", "round/wait")
+        assert line["account"] == row and row["host_ms"] < 5
+    elif case == "host_after_two":
+        line, = slow.lines
+        assert (line["rule"], line["leaf"]) == ("host_ms", "round/record")
+        assert line["median_of"] == 2
+    else:
+        assert slow.lines == []
+
+
+def test_span_length_survives_a_step_of_the_wall_clock(monkeypatch):
+    real = time.time_ns
+    n0 = len(tel.spans())
+    with tel.span("clock/stepped"):
+        # the wall clock goes back 10 s while the span is open
+        monkeypatch.setattr(time, "time_ns", lambda: real() - 10 ** 10)
+        time.sleep(0.02)
+    monkeypatch.undo()
+    record, = tel.spans(n0)
+    assert 0.02e9 <= record.end_ns - record.start_ns < 1e9
+    assert abs(record.start_ns - real()) < 5e9  # the start: the wall clock's
+
+
+@pytest.mark.parametrize("case", ["nested", "between", "round_comes_again",
+                                  "checkpoint_stays", "no_round"])
+def test_round_accounts_arithmetic_on_synthetic_records(case):
+    from chipbench import selfcheck_accounts as sc
+    ms = 10 ** 6
+    if case == "nested":
+        # a parent with two children, one of them a parent itself
+        a, = tel.round_accounts(records=[
+            sc.Span("leaf/a", 10 * ms, 14 * ms, "inner", 1),
+            sc.Span("inner", 8 * ms, 16 * ms, "outer", 1),
+            sc.Span("leaf/b", 17 * ms, 19 * ms, "outer", 1),
+            sc.Span("outer", 5 * ms, 25 * ms, None, 1)])
+        assert a["leaves"] == {"leaf/a": 4.0, "leaf/b": 2.0}
+        assert a["self"] == {"inner": 4.0, "outer": 10.0}
+        assert (a["extent_ms"], a["between_ms"], a["host_ms"]) == (20, 0, 20)
+    elif case == "between":
+        a, = tel.round_accounts(records=sc.synthetic_records()[-8:])
+        assert a["between_ms"] == pytest.approx(sc.BETWEEN_MS)
+        assert a["wait_ms"] == pytest.approx(sc.WAIT_MS)
+        assert a["host_ms"] == pytest.approx(
+            a["extent_ms"] - sc.BETWEEN_MS - sc.WAIT_MS)
+    elif case == "round_comes_again":
+        one = sc.round_records(1, 0, host_ms=20)
+        again = sc.round_records(1, 10 ** 10, host_ms=30)
+        first, second = tel.round_accounts(records=one + again)
+        assert first["round"] == second["round"] == 1
+        assert second["start_ns"] - first["start_ns"] == 10 ** 10
+        assert second["host_ms"] - first["host_ms"] == pytest.approx(10)
+    elif case == "checkpoint_stays":
+        recs = sc.round_records(4, 0, host_ms=20)
+        end = max(r.end_ns for r in recs)
+        recs.append(sc.Span("round/checkpoint", end + ms, end + 6 * ms, None,
+                            4))
+        a, = tel.round_accounts(records=recs)
+        assert a["leaves"]["round/checkpoint"] == 5.0
+        assert a["between_ms"] == pytest.approx(sc.BETWEEN_MS + 1.0)
+    else:
+        assert tel.round_accounts(records=[
+            sc.Span("setup/data", 0, 5 * ms, None, None)]) == []
+
+
+ACCOUNT_READERS = ("round_host_ms", "host_stall_ms_max", "host_offcpu_ms",
+                   "gc_pause_ms", "record_kib", "idle_in_wait_ms")
+
+
+@pytest.mark.parametrize("reader", ACCOUNT_READERS)
+@pytest.mark.parametrize("records", ["counted", "bare", "none"])
+def test_round_account_readers(reader, records):
+    """chipbench/metrics/<reader>.py over the program's own reduction of
+    synthetic records: the warm round and a window of three, two of them
+    traced; nothing (not zero) from records without the span or the count a
+    reader needs. `idle_in_wait_ms` reads the trace alone."""
+    from chipbench import selfcheck_accounts as sc
+    _, mod = sc.readers()[reader]  # found by name, as the harness finds it
+    if records == "counted":
+        got = mod.read(sc.context(sc.synthetic_records()))
+        assert got == pytest.approx(sc.WANT[reader])
+        return
+    if records == "bare":   # a program before the wait and the counts
+        ctx = sc.context(sc.synthetic_records(wait=False, counts=False))
+    else:
+        ctx = sc.context(None)
+    if reader == "idle_in_wait_ms":
+        assert mod.read(ctx) == pytest.approx(sc.WANT[reader])
+        assert mod.read(dict(ctx, trace=None)) is None
+    else:
+        assert mod.read(ctx) is None
+        assert mod.read(dict(ctx, program_spans=[])) is None
+
+
+def test_accounts_selfcheck_reads_the_recorded_sample():
+    """chipbench/testdata/accounts_sample.json (the round records of a traced
+    run on a TPU v5e): the account arithmetic by hand, the six readers
+    against what that run printed, and BENCHMARK.json's entries for them."""
+    from chipbench import selfcheck_accounts
+    assert selfcheck_accounts.main() == 0
+
+
+def test_watchdog_soft_stall_prints_the_round_account(caplog):
+    from dba_mod_tpu.utils.run_guard import Watchdog
+    lg = logging.getLogger("dba_mod_tpu")
+    prev_propagate, lg.propagate = lg.propagate, True
+    wd = Watchdog(soft_s=0.05, hard_s=0.0)
+    try:
+        with tel.span("round/dispatch", round=77):
+            with tel.span("round/plan", round=77):
+                pass
+        with caplog.at_level("ERROR", logger="dba_mod_tpu"), \
+                tel.span("round/finalize", round=77), \
+                wd.zone("round/finalize"):
+            stalled = lambda: [r for r in caplog.records
+                               if "stalled" in r.getMessage()]
+            deadline = time.monotonic() + 5.0
+            while not stalled() and time.monotonic() < deadline:
+                time.sleep(0.01)  # the line comes after the count
+    finally:
+        lg.propagate = prev_propagate
+    stall, = stalled()
+    assert stall.args[4] == ["round/finalize"]  # the open spans at entry
+    account = json.loads(stall.args[5])
+    assert account["round"] == 77
+    assert set(account["leaves"]) == {"round/plan"}
+    assert set(account["self"]) == {"round/dispatch"}
