@@ -1268,6 +1268,9 @@ class Experiment:
                     expert_tokens_max=int(counts.max),
                     expert_tokens_mean=float(counts.held)
                     / int(counts.cells))
+            if counts is not None and int(counts.rows[1]):
+                record_span.count(expert_rows_run=int(counts.rows[0]),
+                                  expert_rows_all=int(counts.rows[1]))
             if counts is not None and counts.tallies:
                 # what the model's objective tallied over the real steps
                 record_span.count(**{name: int(v) for name, v

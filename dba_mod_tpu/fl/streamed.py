@@ -44,6 +44,7 @@ from dba_mod_tpu.fl.evaluation import (EvalResult, local_battery_jobs,
                                        make_eval_fn)
 from dba_mod_tpu.fl.rounds import LocalEvals
 from dba_mod_tpu.models import ModelDef, ModelVars
+from dba_mod_tpu.models.decoder_parts import ROWS_COUNTER
 from dba_mod_tpu.ops import aggregation as agg
 from dba_mod_tpu.ops.fused_update import make_fused_step_update
 from dba_mod_tpu.ops.losses import batch_scores
@@ -57,14 +58,36 @@ class Workspace(NamedTuple):
 
 class ModelCounts(NamedTuple):
     """What the model counted of its own work over a round's real steps
-    (models/lfm2.py, models/sdar.py: tokens given to the held experts). All
-    zero for a model that counts nothing."""
+    (models/lfm2.py, models/sdar.py: tokens given to the held experts;
+    models/sdar.py: the rows its expert layers multiplied). All zero for a
+    model that counts nothing."""
     held: jax.Array   # sum over steps, layers and held experts
     max: jax.Array    # the most one held expert was given in one step
     cells: jax.Array  # (step, layer, held expert) cells counted
+    # [2] sums over steps and layers of the model's `ROWS_COUNTER` entries:
+    # the (position, expert) rows multiplied, the rows of every held expert
+    # over every position; no part of the three above
+    rows: jax.Array
     # {name: sum over the real steps} of what the model's objective tallies
     # (`ModelDef.tallies`; models/sdar.py: positions masked and scored)
     tallies: Any = ()
+
+
+def fold_counts(counts: ModelCounts, counted, valid) -> ModelCounts:
+    """`counts` with what the model counted in one step (its `counters`
+    collection) added where the step was real (`valid`): a `ROWS_COUNTER`
+    entry into `rows`, every other leaf (tokens given to each held expert,
+    a layer an entry) into `held`, `max` and `cells`."""
+    for path, n in jax.tree_util.tree_leaves_with_path(counted):
+        n = n * valid
+        if any(getattr(key, "key", None) == ROWS_COUNTER for key in path):
+            counts = counts._replace(rows=counts.rows + n)
+        else:
+            counts = counts._replace(
+                held=counts.held + jnp.sum(n),
+                max=jnp.maximum(counts.max, jnp.max(n)),
+                cells=counts.cells + n.size * valid)
+    return counts
 
 
 def make_workspace(global_vars: ModelVars) -> Workspace:
@@ -168,12 +191,7 @@ def make_streamed_round(model_def: ModelDef, data, hyper, plans, local_plans,
                 count=m.count.at[e].add(vf * seen),
                 poison_count=m.poison_count.at[e].add(
                     vf * jnp.sum(sel * rows)))
-            for n in jax.tree_util.tree_leaves(out.counted):
-                n = n * valid
-                counts = counts._replace(
-                    held=counts.held + jnp.sum(n),
-                    max=jnp.maximum(counts.max, jnp.max(n)),
-                    cells=counts.cells + n.size * valid)
+            counts = fold_counts(counts, out.counted, valid)
             if out.tallies:
                 counts = counts._replace(tallies={
                     name: counts.tallies[name]
@@ -260,6 +278,7 @@ def make_streamed_round(model_def: ModelDef, data, hyper, plans, local_plans,
             return acc, end, mom, counts, rows
 
         counts0 = ModelCounts(jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                              jnp.zeros((2,), jnp.int32),
                               {name: jnp.int32(0)
                                for name in model_def.tallies} or ())
         acc, live, mom, counts, rows = jax.lax.fori_loop(

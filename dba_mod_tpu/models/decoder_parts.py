@@ -10,9 +10,10 @@ other chips of the layer add it; nothing here stands in for them). The
 router is the model's own (a sigmoid with a bias buffer, a softmax): it hands
 `held_picks` its selection and weights. How the held experts' products are
 laid out is the model's too: models/lfm2.py gathers a held expert's tokens
-into a buffer where they fit one, models/sdar.py runs every held expert over
-every position (a diffusion step's masked positions are one token and route
-alike: no buffer holds them).
+into a buffer where they fit one; models/sdar.py, whose masked positions are
+one token and route alike (no buffer holds them), multiplies one list of the
+routed rows ordered by expert on a TPU (ops/grouped_experts.py) and runs
+every held expert over every position elsewhere.
 """
 from __future__ import annotations
 
@@ -21,6 +22,11 @@ import jax
 import jax.numpy as jnp
 
 INIT_STD = 0.02         # of every matrix and the convolution kernel
+# a model's entry in the `counters` collection that is not tokens given to
+# held experts (every other leaf is): int32 [2], the (position, expert) rows
+# its expert layer multiplied in the call and the rows every held expert over
+# every position would be (fl/streamed.py::ModelCounts.rows)
+ROWS_COUNTER = "expert_rows"
 
 
 def normal_init(std: float = INIT_STD):

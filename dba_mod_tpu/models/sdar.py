@@ -65,21 +65,33 @@ computes its own, drops no token); `mask_token_id` is the slice's last row
 it. A negative token id is padding: embedded as id 0, never masked and never
 scored.
 
-**Every held expert runs over every position** (`experts_over_all`), weighted
-by what the router gave it there: no buffer of gathered positions as
-models/lfm2.py's. A diffusion step's masked positions, seven in ten of the
-noisy stream and all of it in a test, are one token and route alike: a held
-expert that MASK chooses is given most of a step (on the chip the fullest
-held expert of a step read 16.5 times the mean at seeded weights, where
-LFM2's reads 1.5-1.8 and a buffer holds 3: PERF.md, PR 37), and whether one
-is held is the seed's weights' to say, so a buffered path would be taken or
-not, and the round's time set, by the seed. At these widths the every-position
-products take no longer than a buffer's sort, gather and scatter (PERF.md
-section 5).
+**The expert layer multiplies the rows the router sent**, in one of two
+forms; which runs is read from the backend and the call's shapes
+(`ops/grouped_experts.py::runs_here`), not from a knob, and training steps,
+their recomputation, the backward pass and the batteries' forward passes all
+take the same call:
+
+- **on a TPU, for positions that are whole tiles and widths that are whole
+  lanes** (`grouped_experts`): the (position, held expert) pairs the router
+  chose as one list ordered by expert, multiplied a tile at a time by a
+  grouped product whose kernels fetch their own rows; dispatch and combine
+  are gathers forward and backward. One path whatever the routing: no buffer
+  an expert and no capacity, so a held expert that MASK chooses may be given
+  every position of a step (a diffusion step's masked positions, seven in
+  ten of the noisy stream and all of it in a test, are one token and route
+  alike: the fullest held expert of a step reads 15-21 times the mean at
+  seeded weights, PERF.md) and another none, and the time follows the rows
+  routed: about one a position, a sixteenth of what the other form
+  multiplies;
+- **everywhere else** (the CPU suite, toy rows; `experts_over_all`): every
+  held expert over every position, weighted by what the router gave it there
+  (0 where it was not chosen): the oracle tests/test_grouped_experts.py holds
+  the kernels to.
 
 Every layer is rematerialised in the backward pass (`nn.remat`). The model
-counts the positions each held expert was given in the `counters`
-collection (`ModelDef.apply_counted`).
+counts the positions each held expert was given, and the rows its expert
+layers multiplied beside the rows `experts_over_all` would have, in the
+`counters` collection (`ModelDef.apply_counted`).
 """
 from __future__ import annotations
 
@@ -92,7 +104,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from dba_mod_tpu.models.decoder_parts import (
-    INIT_STD, apply_rope, held_picks, normal_init, rms_norm, rope_tables)
+    INIT_STD, ROWS_COUNTER, apply_rope, held_picks, normal_init, rms_norm,
+    rope_tables)
+from dba_mod_tpu.ops import grouped_experts as grouped
 from dba_mod_tpu.ops.attention import blocked_attention, plan_of, runs_here
 from dba_mod_tpu.ops.losses import BatchOut, block_noise, token_nll
 
@@ -339,12 +353,21 @@ class SoftmaxExpertFfn(nn.Module):
             sel, w = route_softmax(logits, c.num_experts_per_tok,
                                    c.norm_topk_prob)
             _, wts, counts = held_picks(sel, w, lo, hi)
+        keep = dict(reduce_fn=lambda a, b: b)
         self.sow("counters", "expert_tokens", counts,
-                 reduce_fn=lambda a, b: b, init_fn=lambda: counts * 0)
+                 init_fn=lambda: counts * 0, **keep)
+        weights = [m.astype(self.dtype) for m in (w1, w3, w2)]
+        n = tokens.shape[0]
         with jax.named_scope("experts"):
-            out = experts_over_all(tokens, wts, w1.astype(self.dtype),
-                                   w3.astype(self.dtype),
-                                   w2.astype(self.dtype))
+            if grouped.runs_here(n, d, f):
+                out = grouped.grouped_experts(tokens, sel - lo, w, *weights)
+                run = grouped.rows_run(counts)
+            else:
+                out = experts_over_all(tokens, wts, *weights)
+                run = jnp.int32(e * n)
+        self.sow("counters", ROWS_COUNTER,
+                 jnp.stack([run, jnp.int32(e * n)]),
+                 init_fn=lambda: jnp.zeros((2,), jnp.int32), **keep)
         return out.reshape(x.shape)
 
 

@@ -164,13 +164,31 @@ def test_the_models_tile_counts_are_its_calls_plans(monkeypatch):
     assert 0 < run < every
 
 
-def test_on_the_cpu_the_round_program_lowers_as_on_the_parent():
-    """`b3e6…`: the first 16 hex digits of sha256 of the block-diffusion
-    round's lowered text at tests/sdar_cases.py's sizes, recorded by
-    `tests/test_block_diffusion.py::lowered_sha` on the parent of the PR that
-    brought the kernel (commit 4b32d61, jax 0.9.0): off the TPU the model
-    keeps XLA's form, text for text, and the plan says so."""
+def test_on_the_cpu_the_round_program_holds_no_kernel():
+    """Off the TPU the model keeps XLA's forms, the attention's and the
+    expert layer's (every held expert over every position): the plan and the
+    model's own row counts say so, and the lowered round program calls no
+    kernel. `d5c5…`: the first 16 hex digits of sha256 of the
+    block-diffusion round's lowered text at tests/sdar_cases.py's sizes
+    (`tests/test_block_diffusion.py::lowered_sha`, jax 0.9.0), recorded on
+    the PR that brought the expert layer's row counts into the round's carry
+    (b3e6… before it, from commit 4b32d61 on)."""
     exp = Experiment(sdar_cases.params(), save_results=False)
     assert exp.model_def.attention_tiles == (0, 0)
-    assert build_model(sdar_cases.params()).attention_tiles == (0, 0)
-    assert lowered_sha(exp, 2) == "b3e65248d499de14"
+    model = build_model(sdar_cases.params())
+    assert model.attention_tiles == (0, 0)
+    streams = jnp.zeros((1, 2, 32), jnp.int32)
+    _, _, counted = model.apply_counted(
+        model.init_vars(jax.random.key(0)), streams)
+    lo, hi = sdar_cases.ARCH["experts_held"]
+    rows = [layer["moe"]["expert_rows"] for layer in counted.values()]
+    assert [r.tolist() for r in rows] == [
+        [(hi - lo) * 64] * 2, [(hi - lo) * 32] * 2]   # the last: one stream
+    tasks, idx, mask, ns, lane = exp.build_static_round_inputs(2)
+    k1, k2 = jax.random.split(jax.random.key(0))
+    text = exp.engine.round_fn.lower(
+        exp.global_vars, exp.fg_state,
+        exp.engine.round_workspace(exp.global_vars), tasks, idx, mask, lane,
+        ns, k1, k2, exp.device_data.train_source).as_text()
+    assert "custom_call" not in text or "tpu_custom_call" not in text
+    assert lowered_sha(exp, 2) == "d5c541e12e5f184a"

@@ -1,5 +1,5 @@
 """The per-layer readers of the `sdar_moe` cell (chipbench/sdar_layers.py,
-chipbench/lfm2_layers.py and the eight files under chipbench/metrics/ that
+chipbench/lfm2_layers.py and the nine files under chipbench/metrics/ that
 call them) on hand-made records: from a program without the scopes and counts (the
 parent of the PR that brought them) every reader gives nothing and raises
 nothing; from a traced run's records each gives the number its docstring
@@ -18,7 +18,8 @@ METRICS = Path(sdar_layers.__file__).parent / "metrics"
 READERS = ("sdar_mixer_device_ms", "sdar_experts_device_ms",
            "sdar_noise_device_ms", "sdar_expert_load_max_over_mean",
            "sdar_masked_positions_pct", "sdar_client_step_mfu_pct",
-           "sdar_attention_device_ms", "sdar_attention_tiles_run_pct")
+           "sdar_attention_device_ms", "sdar_attention_tiles_run_pct",
+           "sdar_expert_rows_run_pct")
 TRAIN = "jit(round_fn)/phase/train/while/body/"
 MS = 1e6   # the trace's clock is in nanoseconds
 KERNEL = "%blocked_attention = f32[4,8,2048,128]{3,2,1,0} custom-call(%q)"
@@ -61,10 +62,12 @@ def traced_ctx():
              for c in (8, 8, 24)]
     records = [span("round/record", expert_tokens_held=h, expert_tokens_max=m,
                     expert_tokens_mean=mean, positions_masked=masked,
-                    positions_scored=scored)
-               for h, m, mean, masked, scored in (
-                   (500, 30, 15.0, 150, 256), (512, 24, 16.0, 180, 256),
-                   (1600, 40, 16.0, 520, 768))]
+                    positions_scored=scored, expert_rows_run=run,
+                    expert_rows_all=every)
+               for h, m, mean, masked, scored, run, every in (
+                   (500, 30, 15.0, 150, 256, 1024, 8192),
+                   (512, 24, 16.0, 180, 256, 768, 8192),
+                   (1600, 40, 16.0, 520, 768, 3072, 24576))]
     return {"spans": {"dispatch": [0.01, 0.01, 0.01]},
             "program_spans": plans + records,
             "traced": {"rounds": 2, "window_rounds": [2, 3]},
@@ -102,6 +105,7 @@ def test_a_program_without_the_scopes_and_counts_reads_as_nothing(name):
     ("sdar_masked_positions_pct", 100 * (150 + 180 + 520) / (256 + 256 + 768)),
     ("sdar_attention_device_ms", 1.5 / 2),
     ("sdar_attention_tiles_run_pct", 100 * 1376 / 2816),
+    ("sdar_expert_rows_run_pct", 100 * (1024 + 768 + 3072) / (2 * 8192 + 24576)),
 ])
 def test_the_readers_read_what_their_docstrings_say(name, want):
     assert reader(name).read(traced_ctx()) == pytest.approx(want)
@@ -117,6 +121,60 @@ def test_where_xlas_form_runs_the_attention_readers_read_nothing():
     assert reader("sdar_attention_device_ms").read(ctx) is None
     assert reader("sdar_attention_tiles_run_pct").read(ctx) is None
     assert reader("sdar_mixer_device_ms").read(ctx) == pytest.approx(4.0 / 2)
+
+
+EXPERT_READERS = ("sdar_expert_load_max_over_mean", "sdar_client_step_mfu_pct",
+                  "sdar_experts_device_ms", "sdar_masked_positions_pct")
+
+
+@pytest.mark.parametrize("rows", ["absent", "none_counted", "every_row"])
+def test_the_row_counts_read_as_their_share_or_as_nothing(rows):
+    """`expert_rows_run` over `expert_rows_all`: nothing from records without
+    them (the parent's program) or with no row counted, 100 where every held
+    expert ran over every position; and the readers of the expert layer that
+    were there read what they read whatever the row counts say."""
+    ctx, before = traced_ctx(), {}
+    for name in EXPERT_READERS:
+        before[name] = reader(name).read(ctx)
+    for record in ctx["program_spans"][3:]:
+        if rows == "absent":
+            del record.counts["expert_rows_run"], record.counts["expert_rows_all"]
+        elif rows == "none_counted":
+            record.counts.update(expert_rows_run=0, expert_rows_all=0)
+        else:
+            record.counts["expert_rows_run"] = record.counts["expert_rows_all"]
+    got = reader("sdar_expert_rows_run_pct").read(ctx)
+    assert got == (100.0 if rows == "every_row" else None)
+    for name in EXPERT_READERS:
+        assert reader(name).read(ctx) == before[name]
+
+
+@pytest.mark.parametrize("valid", [True, False])
+def test_the_rounds_expert_counts_leave_the_row_counts_out(valid):
+    """`fl/streamed.py::fold_counts`: the model's `expert_rows` entries go to
+    `rows` alone; `held`, `max` and `cells` fold the tokens given to the held
+    experts, as before the row counts were there; a step that was not real
+    adds nothing."""
+    import jax.numpy as jnp
+    from dba_mod_tpu.fl.streamed import ModelCounts, fold_counts
+    zero = ModelCounts(jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                       jnp.zeros((2,), jnp.int32), {"positions_scored": 7})
+    tokens = [jnp.asarray([5, 0, 9, 2], jnp.int32),
+              jnp.asarray([1, 1, 1, 1], jnp.int32)]
+    rows = [jnp.asarray([512, 4096], jnp.int32),
+            jnp.asarray([256, 2048], jnp.int32)]
+    with_rows = {f"layer_{i}": {"moe": {"expert_tokens": t, "expert_rows": r}}
+                 for i, (t, r) in enumerate(zip(tokens, rows))}
+    without = {f"layer_{i}": {"moe": {"expert_tokens": t}}
+               for i, t in enumerate(tokens)}
+    got = fold_counts(zero, with_rows, jnp.asarray(valid))
+    old = fold_counts(zero, without, jnp.asarray(valid))
+    assert (int(got.held), int(got.max), int(got.cells)) == (
+        (20, 9, 8) if valid else (0, 0, 0))
+    assert (int(old.held), int(old.max), int(old.cells)) == (
+        int(got.held), int(got.max), int(got.cells))
+    assert got.rows.tolist() == ([768, 6144] if valid else [0, 0])
+    assert old.rows.tolist() == [0, 0] and got.tallies == zero.tallies
 
 
 def test_the_steps_share_of_the_peak_counts_the_experts_from_the_counter():

@@ -157,6 +157,14 @@ def test_an_sdar_experiment_trains_poisons_and_records(tmp_path):
     assert all(0.5 < c["expert_tokens_held"] / (p["client_steps"] * 96) < 1.5
                for c, p in zip(record, plan))
     assert all(c["expert_tokens_max"] >= c["expert_tokens_mean"] for c in record)
+    # the mean is over (step, layer, held expert) cells: 2 layers x 4 held; the
+    # row counts are no part of it, and on this CPU every held expert runs
+    # over every position: 4 x (64 + 32) rows a step, all of them run
+    assert all(c["expert_tokens_mean"] == pytest.approx(
+        c["expert_tokens_held"] / (p["client_steps"] * 2 * 4))
+        for c, p in zip(record, plan))
+    assert all(c["expert_rows_run"] == c["expert_rows_all"]
+               == p["client_steps"] * 4 * 96 for c, p in zip(record, plan))
 
 
 CONFIG = {"name": "lfm2_toy", "population_seed": 1,

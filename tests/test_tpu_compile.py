@@ -5,7 +5,8 @@ described (`on-chip-measurement` guide §2): it refuses what the chip's
 compiler would refuse (misaligned Pallas slices, VMEM overflow, programs that
 do not fit HBM), which interpret-mode tests cannot see. Covered: the fused
 Pallas per-step update at the CIFAR and Tiny-ImageNet model shapes, the
-blocked attention kernel at the SDAR cell's shapes and the file's tiles, and the
+blocked attention kernel and the grouped expert product at the SDAR cell's
+shapes and the files' tiles, and the
 CIFAR round program's donated twin — the default program of an unsharded TPU
 run, which the CPU suite otherwise never builds.
 
@@ -106,6 +107,34 @@ def test_blocked_attention_compiles_for_v5e(one_chip, no_persistent_cache,
     text = jax.jit(fn).lower(q, k, v).compile().as_text()
     assert text.count("tpu_custom_call") >= (1 if last else 2) * (
         2 if backward else 1)
+
+
+@pytest.mark.parametrize("positions", [4096, 2048])
+@pytest.mark.parametrize("backward", [False, True])
+def test_grouped_experts_compile_for_v5e(one_chip, no_persistent_cache,
+                                         backward, positions):
+    """`ops/grouped_experts.py` at configs/sdar_params.yaml's shapes (both
+    streams of a row of 2,048 and the last layer's one, hidden 2,048, 16 held
+    experts 768 wide, top-8) and the file's tile, forward (the list's kernel
+    and the combine) and with its two backward kernels: a row travels as
+    whole tiles, an expert's matrices, their bfloat16 copies and their
+    gradients fit fast memory."""
+    from dba_mod_tpu.ops import grouped_experts as ge
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    args = (shape(positions, 2048), shape(positions, 8, dt=jnp.int32),
+            shape(positions, 8), shape(16, 2048, 768), shape(16, 2048, 768),
+            shape(16, 768, 2048))
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(ge.grouped_experts(*a) ** 2),
+                      (0, 2, 3, 4, 5))
+    else:
+        fn = ge.grouped_experts
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (5 if backward
+                                                           else 2)
+    # the list is sized for the most a call can route: 8 pairs a position
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 @pytest.fixture(scope="module")
