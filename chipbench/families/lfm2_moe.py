@@ -31,16 +31,11 @@ program's state. Before the reference runs, the program's round workspace
 (three copies of the model, made anew by the next round) is released:
 `population_of` hands the reference's caller that one call.
 
-A check round ends by collecting and freezing the interpreter's heap
-(`gc.freeze`): tracing the round program leaves some 230,000 long-lived
-objects, a full collection over them takes 0.12-0.14 s, and one fell into one
-window of six (one round of 2.507 s where every other run's took 2.385 s: my
-chip runs, PR 35). The window is to time the rounds, not the collector;
-frozen objects are skipped by later collections and freed at exit as ever.
+The interpreter's heap is the harness's to freeze (`run.run_cell`, after the
+warm round, for every family), not a check round's.
 """
 from __future__ import annotations
 
-import gc
 import json
 import time
 from typing import Any, Dict
@@ -157,8 +152,6 @@ def check_round(exp, epoch: int, real_steps: int) -> Dict[str, Any]:
     seconds = time.perf_counter() - t0
     exp.global_vars, exp.fg_state = new_vars, new_fg
     locals_, globals_, metrics, delta_norms = jax.device_get(payload[:4])
-    gc.collect()
-    gc.freeze()
     return {"seconds": seconds, "epoch": epoch, "real_steps": real_steps,
             "idx": flat[:, :real_steps], "mask": mask[:, :real_steps],
             "tokens_scored": scored,

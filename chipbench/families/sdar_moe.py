@@ -25,7 +25,7 @@ The check rounds' feed is the `lfm2_moe` family's (a client's own rows laid
 over its first `real_steps` steps; rows scored over their first
 `lfm2_moe.CHECK_TOKENS` positions, the rest padding: the block mask is closed under
 prefixes of whole blocks, so the cut is exact; the workspace released before
-the reference runs; the interpreter's heap frozen after a check round), with
+the reference runs), with
 the round's training key, the lanes and the plan's steps an epoch beside it:
 the reference draws the program's noise from them.
 """

@@ -8,8 +8,9 @@ A PR that renames one of these has to keep the benchmark running:
 - `dba_mod_tpu.config.Params.from_dict`
 - `dba_mod_tpu.fl.experiment.Experiment(params, save_results=True)` and its
   attributes `select_rng`, `plan_rng`, `rng_key`, `global_vars`, `fg_state`
-  (assigned from `--seed` after the build; `select_rng` again from the
-  population's seed at the start of every period of the window), `engine`,
+  (assigned after the build: from `--seed` for the check rounds, from the
+  population's seed for the warm round and the window; `select_rng` again
+  from the population's seed at the start of every period), `engine`,
   `folder`, `mesh`, `steps_per_epoch`, `epochs_max`, `_use_donated_round`,
   `last_global_loss`
 - `Experiment.run_round`, `dispatch_round`, `finalize_round`, `save_model`
@@ -127,15 +128,25 @@ def seed_selection(exp, seed: int) -> None:
     exp.select_rng = random.Random(int(seed))
 
 
-def seed_state(exp, seed: int, state: Dict[str, Any], to_tree) -> None:
-    """`state` goes into the program through the family's `to_tree(shapes,
-    state)`. `--seed` drives what cannot move a round's time, on top of the fixed
-    population: initial weights, batch order, device RNG; and the client
-    selection of set-up's rounds (the two check rounds, the warm round). The
-    window's selection is not `--seed`'s: `run.run_window` sets it from the
-    population's seed at the start of every period (`seed_selection`)."""
+def seed_state(exp, seed: int, state, to_tree) -> None:
+    """Everything of the program that a seed sets, from `seed`: the client
+    selection (`select_rng`), the batch order (`plan_rng`), the device RNG
+    (`rng_key`: for a model with an objective of its own, its noise) and the
+    weights, `state`, which go in through the family's `to_tree(shapes,
+    state)`. `state` is a dict of host arrays, or a call that makes one: it is
+    called once the old state has left the device, so weights that are made
+    on the device never lie there beside the ones they replace.
+
+    The harness calls it with two seeds (`run.py`): `--seed` before each
+    check round, and the configuration's `population_seed` before the warm
+    round and the window (`run.seed_window`). Weights, noise and batch order
+    can all move a round's time (a sparse expert product runs the rows the
+    router sent), so the window's are the population's; `run.run_window` sets
+    the selection from the same seed again at the start of every period."""
     shapes = tree_shapes(exp.global_vars)
     exp.global_vars = None  # free the old state first: no second copy at the peak
+    if callable(state):
+        state = state()
     seed_selection(exp, seed)
     exp.plan_rng = np.random.RandomState(int(seed) % (2 ** 32))
     exp.rng_key = jax.random.key(int(seed) % (2 ** 31 - 1))

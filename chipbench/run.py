@@ -4,19 +4,29 @@
 
 Fails at once without a TPU or with fewer devices than the cell's `chips`;
 finds the configuration's model family by the name its file gives
-(`chipbench/families/`); builds the configuration's `Experiment`; seeds the
-weights, the batch order and the check rounds' selection from `--seed`;
-compiles and warms the cell's one round program (the two check rounds and one
-whole round); then measures the program's own sequential loop over **whole
-periods of the traffic's schedule** (`period_rounds` in the traffic file):
-periods are started until `--seconds` have passed and the one in flight is
-finished, and at the start of every period the selection RNG is set from the
-configuration's `population_seed`, so every period of every run at every seed
-selects the same clients and times the same job. With `--trace 1` the window
-rounds the traffic names (`trace_window_rounds`) are traced and the window
-ends with the last of them. After the window the check rounds are compared
-with the plain reference; findings go out as JSON lines and, last, the result
-object of the benchmark's contract.
+(`chipbench/families/`); builds the configuration's `Experiment`; compiles and
+warms the cell's one round program (the two check rounds and one whole round);
+then measures the program's own sequential loop over **whole periods of the
+traffic's schedule** (`period_rounds` in the traffic file): periods are
+started until `--seconds` have passed and the one in flight is finished.
+
+Two seeds, each for one thing. **`--seed` is the check's**: the two check
+rounds run on weights, a selection, a batch order and a device RNG drawn from
+it, and after the window they are compared with the plain reference on those
+weights, so every run holds the program to the reference on inputs it has not
+seen. **The configuration's `population_seed` is the window's**
+(`seed_window`): the warm round and the window start from weights, a batch
+order and a device RNG (a model's own noise) drawn from it, and at the start
+of every period the selection RNG is set from it again, so every period of
+every run at every `--seed` selects the same clients and times the same job.
+How much work a round holds follows all of these (the steps a lane needs, the
+rows a router sends to the held experts), and a run's time is to follow the
+program alone; the `window` line names the seed and a fingerprint of the state
+the window started from. With `--trace 1` the window rounds the traffic names
+(`trace_window_rounds`) are traced and the window ends with the last of them.
+After the window the check rounds are compared with the plain reference;
+findings go out as JSON lines and, last, the result object of the benchmark's
+contract.
 
 `--rehearse` walks the same control flow on whatever backend is there at tiny
 sizes (Pallas interpreted): it prints no metric and is never `correct`.
@@ -28,6 +38,7 @@ import time
 T_PROCESS = time.perf_counter()  # before anything heavy is imported
 
 import argparse
+import gc
 import importlib.util
 import json
 import math
@@ -115,16 +126,17 @@ def device_memory(devices) -> list:
 
 def seeded_check_rounds(exp, family, config, traffic, seed, first_window_epoch,
                         events):
-    """Weights and traffic from `seed`, then the two check rounds through the
-    window's own round program; leaves `exp` seeded afresh for the window."""
+    """Weights from `seed`, then the two check rounds through the window's
+    own round program, each on those weights and on a selection, a batch order
+    and a device RNG seeded from `seed` afresh."""
     import jax
     from chipbench import program
     # one jitted call on the device; kept on the host from here on, so that
     # the device's peak stays the program's
     state0 = jax.device_get(family.init_weights(seed, config["model"]))
-    program.seed_state(exp, seed, state0, family.to_program)
     checks = []
     for i, steps in enumerate(CHECK_STEPS):
+        program.seed_state(exp, seed, state0, family.to_program)
         # the first check round is at the epoch the traffic poisons first
         rounds = traffic.get("poison_window_rounds") or []
         epoch = (first_window_epoch - 1 + rounds[0]
@@ -134,8 +146,41 @@ def seeded_check_rounds(exp, family, config, traffic, seed, first_window_epoch,
         checks.append(got)
         emit(phase="check_round", index=i, epoch=epoch, real_steps=steps,
              seconds=got["seconds"], compile=events.snapshot())
-        program.seed_state(exp, seed, state0, family.to_program)
     return state0, checks
+
+
+def fingerprint(state) -> dict:
+    """The three largest leaves of a state (ties by name), each by name with
+    the float64 sum of its values: enough to see in two runs' logs that they
+    started from one state."""
+    import numpy as np
+    largest = sorted(state, key=lambda n: (-np.size(state[n]), n))[:3]
+    return {n: float(np.sum(np.asarray(state[n]), dtype=np.float64))
+            for n in largest}
+
+
+def seed_window(exp, family, config, population) -> dict:
+    """The job the warm round and the window time, which is the
+    configuration's and not `--seed`'s: weights from `population_seed` as a
+    trained model would carry them (the family's rule: running statistics of
+    the population's own), and the program's RNGs (selection, batch order,
+    device noise) from the same seed. Returns what the `window` line says of
+    it. The weights are made once the check rounds' state has left the device
+    and are not kept on the host."""
+    import jax
+    from chipbench import program
+    seed, model = int(config["population_seed"]), config["model"]
+    said = {"window_seed": seed}
+
+    def window_state():
+        state = jax.device_get(family.window_state(
+            family.init_weights(seed, model), population, model))
+        said["window_fingerprint"] = fingerprint(state)
+        return state
+
+    program.seed_state(exp, seed, window_state, family.to_program)
+    jax.block_until_ready(exp.global_vars)
+    return said
 
 
 def judge(family, raw, model, state0, population, checks, lim,
@@ -307,23 +352,28 @@ def run_cell(args, sabotage=None) -> dict:
     if sabotage is not None:
         sabotage(exp)
 
-    # ---- set-up: traffic from --seed; the check rounds compile and warm the
-    # window's one round program
+    # ---- set-up: the check rounds, all of them --seed's; they compile and
+    # warm the window's one round program
+    marks = {"build": time.perf_counter() - T_PROCESS}  # where set-up's time goes
     state0, checks = seeded_check_rounds(exp, family, config, traffic,
                                          args.seed, first_window_epoch, events)
-    # the window starts from the same weights as a trained model would carry
-    # them (the family's rule: running statistics of the population's own)
+    marks["check_rounds"] = time.perf_counter() - T_PROCESS
+    # the warm round and the window: the population's job, at every --seed
     population = family.population_of(exp)
-    warm_state = jax.device_get(
-        family.window_state(state0, population, config["model"]))
-    program.seed_state(exp, args.seed, warm_state, family.to_program)
-    del warm_state
+    window_job = seed_window(exp, family, config, population)
+    marks["seed_window"] = time.perf_counter() - T_PROCESS
     spans["first_round"] = [checks[0]["seconds"]]
     spans["steady_round"] = [c["seconds"] for c in checks[1:]]
     for epoch in range(len(CHECK_STEPS) + 1, first_window_epoch):
         exp.run_round(epoch)
         exp.save_model(epoch)
     jax.block_until_ready(exp.global_vars)
+    marks["warm_round"] = time.perf_counter() - T_PROCESS
+    # the window times the rounds, not the collector: tracing a round program
+    # leaves some 230,000 long-lived objects, and a full collection over them
+    # (0.12-0.14 s) fell into one window of six before they were frozen
+    gc.collect()
+    gc.freeze()  # until the window has closed
     compiles_before = events.total()
     setup_s = time.perf_counter() - T_PROCESS
 
@@ -335,6 +385,7 @@ def run_cell(args, sabotage=None) -> dict:
                      int(traffic["periods_max"]), int(config["population_seed"]),
                      trace_span_of(traffic) if args.trace else None,
                      trace_dir, spans)
+    gc.unfreeze()
     rounds_s, results, failed = won["rounds_s"], won["results"], won["failed"]
     window_s, traced = won["window_s"], won["traced"]
     compiles_in_window = events.total() - compiles_before
@@ -347,7 +398,8 @@ def run_cell(args, sabotage=None) -> dict:
     engine = program.engine_report(exp, dev.platform == "tpu",
                                    family.engine_conditions(exp))
     agents = [[str(a) for a in r["agents"]] for r in results]
-    emit(phase="window", window_s=window_s, rounds_s=rounds_s,
+    emit(phase="window", **window_job, setup_marks_s=marks, window_s=window_s,
+         rounds_s=rounds_s,
          global_acc=[r["global_acc"] for r in results],
          global_loss=[r["global_loss"] for r in results],
          backdoor_acc=[r["backdoor_acc"] for r in results],
