@@ -164,7 +164,7 @@ def main(argv=None) -> int:
     emit(reading="rows", run=int(ge.rows_run(counts, main_tile)),
          all=held * n)
     ys = timed("parts.forward_kernel", lambda *a: ge._forward(
-        main_tile, args.rehearse, *a), plan, x, w1, w3, w2)
+        main_tile, args.rehearse, "silu", *a), plan, x, w1, w3, w2)
     for tile in (64, 128, 256):
         timed(f"parts.combine.{tile}", lambda ys, plan, tile=tile: ge.combine(
             ys, plan, min(tile, n), args.rehearse), ys, plan)
@@ -174,7 +174,7 @@ def main(argv=None) -> int:
     timed("parts.cumsum", lambda c: jnp.cumsum(c, axis=0, dtype=jnp.int32),
           (sel[:, :, None] == jnp.arange(held)).any(1))
     timed("parts.backward_kernels", lambda *a: ge._backward(
-        main_tile, args.rehearse, *a), plan, x, w1, w3, w2, cot)
+        main_tile, args.rehearse, "silu", *a), plan, x, w1, w3, w2, cot)
 
     # candidate (a): XLA's gather of a worst-case list and one ragged product
     sizes = plan.starts[1:] - plan.starts[:-1]

@@ -33,8 +33,13 @@ TYPE_LFM2 = "lfm2_moe"
 # (models/sdar.py; its architecture is the nested `sdar` key)
 TYPE_SDAR = "sdar_moe"
 
+# a decoder whose layers mix global attention without positions and a sliding
+# window with RoPE, routed before attention, ReGLU experts
+# (models/smallthinker.py; its architecture is the nested `smallthinker` key)
+TYPE_SMALLTHINKER = "smallthinker"
+
 IMAGE_TYPES = (TYPE_CIFAR, TYPE_MNIST, TYPE_TINYIMAGENET)
-TOKEN_TYPES = (TYPE_LFM2, TYPE_SDAR)
+TOKEN_TYPES = (TYPE_LFM2, TYPE_SDAR, TYPE_SMALLTHINKER)
 
 # Aggregation method names (reference config.py:4-6).
 AGGR_MEAN = "mean"
@@ -99,8 +104,9 @@ _DEFAULTS: Dict[str, Any] = {
     "random_seed": 1,
     # framework-specific knobs (not in the reference schema)
     # token workloads (data/tokens.py, ops/triggers.py::build_phrase_bank);
-    # the model's architecture is the nested `lfm2` key (models/lfm2.py) or
-    # the nested `sdar` key (models/sdar.py)
+    # the model's architecture is the nested `lfm2` key (models/lfm2.py),
+    # the nested `sdar` key (models/sdar.py) or the nested `smallthinker` key
+    # (models/smallthinker.py)
     "seq_len": 2048,               # tokens a packed row
     "sequences_per_client": 4,     # rows a participant holds
     "test_sequences": 8,           # held-out rows (the global battery)

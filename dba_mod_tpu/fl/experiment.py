@@ -741,11 +741,17 @@ class Experiment:
                                     * int(params["batch_size"])
                                     * self.model_def.streams),
                     client_steps=steps,
-                    **({"block_length": self.model_def.block_length,
-                        **dict(zip(
-                            ("attention_tiles_run", "attention_tiles_all"),
-                            self.model_def.attention_tiles))}
-                       if self.model_def.block_length else {}))
+                    **({"block_length": self.model_def.block_length}
+                       if self.model_def.block_length else {}),
+                    # a model with a blocked attention kernel: the tiles one
+                    # forward pass over a row visits, and what else its
+                    # ModelDef says of them
+                    **(dict(zip(
+                        ("attention_tiles_run", "attention_tiles_all"),
+                        self.model_def.attention_tiles))
+                       if (self.model_def.block_length
+                           or self.model_def.attention_counts) else {}),
+                    **dict(self.model_def.attention_counts))
             plan_span.count(
                 **plan_step_counts(
                     mask_list, STEP_CHUNK,

@@ -32,6 +32,9 @@ from dba_mod_tpu.models.mnist import MnistNet
 from dba_mod_tpu.models.resnet import cifar_resnet18, tiny_resnet18
 from dba_mod_tpu.models.sdar import (TALLIES, SdarConfig, SdarMoe,
                                      attention_tiles, block_diffusion)
+from dba_mod_tpu.models.smallthinker import (SmallThinker,
+                                             SmallThinkerConfig,
+                                             attention_counts)
 from dba_mod_tpu.ops.losses import BatchOut, batch_loss
 
 
@@ -103,6 +106,10 @@ class ModelDef:
     # (visited, of a full mask): tiles of a blocked attention kernel in one
     # forward pass over a row; zeros where XLA's form runs
     attention_tiles: Tuple[int, int] = (0, 0)
+    # ((name, count), ...): what else a round's plan says of the attention
+    # of one forward pass over a row, static (a model whose layers are of
+    # two kinds: each kind's tiles, and the pairs its mask allows)
+    attention_counts: Tuple[Tuple[str, int], ...] = ()
     tallies: Tuple[str, ...] = ()
     # what the model's non-gradient state starts as, where zeros would not
     # do: batch_stats tree, rng -> batch_stats tree
@@ -246,4 +253,18 @@ def build_model(params: cfg.Params) -> ModelDef:
                         block_length=arch.block_length, reserved_ids=1,
                         attention_tiles=attention_tiles(arch, seq_len),
                         tallies=TALLIES)
+    if t == cfg.TYPE_SMALLTHINKER:
+        arch = SmallThinkerConfig.from_dict(params["smallthinker"])
+        seq_len = int(params["seq_len"])
+        counts = attention_counts(arch, seq_len)
+        tiles = (counts.pop("attention_tiles_run"),
+                 counts.pop("attention_tiles_all"))
+        return ModelDef(name="SmallThinker",
+                        module=SmallThinker(arch, dtype=dtype),
+                        input_shape=(seq_len,), num_classes=0,
+                        similarity_path=("head",), has_batch_stats=False,
+                        has_dropout=False, form=FORM_TOKENS,
+                        vocab_size=arch.vocab_size, streamed=True,
+                        attention_tiles=tiles,
+                        attention_counts=tuple(sorted(counts.items())))
     raise ValueError(f"unknown workload type {t!r}")

@@ -318,14 +318,15 @@ def route_softmax(logits, k: int, norm_topk: bool):
     return sel, w
 
 
-def experts_over_all(x, wts, w1, w3, w2):
+def experts_over_all(x, wts, w1, w3, w2, act=nn.silu):
     """Every held expert over every position: x [N, D], wts [N, E] (0 where
     a position did not choose the expert) -> [N, D]. The weight goes onto the
     expert's hidden row, so the down-projections and the sum over the experts
-    are one product over E x F and no [E, N, D] is written."""
+    are one product over E x F and no [E, N, D] is written. `act`: the gate's
+    activation (models/smallthinker.py's experts are ReGLUs)."""
     gate = jnp.einsum("nd,edf->enf", x, w1)
     up = jnp.einsum("nd,edf->enf", x, w3)
-    hidden = nn.silu(gate) * up * wts.T[..., None].astype(gate.dtype)
+    hidden = act(gate) * up * wts.T[..., None].astype(gate.dtype)
     return jnp.einsum("enf,efd->nd", hidden, w2)
 
 

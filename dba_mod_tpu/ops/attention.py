@@ -12,10 +12,12 @@ host, once a mask, the tiles to visit: the grid's second axis walks that list
 (query tile by query tile, a tile's keys in order), the scalar-prefetched
 index arrays tell each step which blocks to fetch, and a tile the mask cuts
 through reads its `[BLOCK_Q, BLOCK_K]` piece of the mask from a table of the
-distinct pieces (a block-diagonal mask has a few). A tile of the kernel is
-the `g` query heads of one key-value head over `BLOCK_Q` positions, as
-`g x BLOCK_Q` rows against `BLOCK_K` keys: the heads share one read of their
-keys and values.
+distinct pieces (a block-diagonal mask has a few; a causal mask two, a causal
+mask with a window four). A tile of the kernel is the `g` query heads of one
+key-value head over `BLOCK_Q` positions, as `g x BLOCK_Q` rows against
+`BLOCK_K` keys: the heads share one read of their keys and values. `g` is the
+model's, any whole number (models/sdar.py: 8, tiles of 2,048 rows;
+models/smallthinker.py: 7, tiles of 1,792).
 
 **Arithmetic**: that of float32 state at the TPU's default matmul precision,
 which is what a softmax written out in XLA does there: every product takes
@@ -40,7 +42,9 @@ room, the tile's time is the vector unit's.
 log-sum-exp; one kernel walks the allowed pairs once, recomputes a tile's
 scores from q and k, accumulates dq over a query tile's keys in scratch and
 dk, dv into outputs that stay in fast memory for a whole key-value head
-(`[Tk, hd]` float32 each: 2 MiB at 4,096 keys). Called as the primal (an
+(`[Tk, hd]` float32 each: 2 MiB at the 4,096 keys of both streams of
+models/sdar.py's rows, 4 MiB at the 8,192 of models/smallthinker.py's, of
+`VMEM_LIMIT`'s 64; a row of 32k keys would need them tiled). Called as the primal (an
 evaluation; the first pass of `nn.remat`) the forward writes no log-sum-exp.
 
 Every row of the mask must allow a key: a masked score is a large negative

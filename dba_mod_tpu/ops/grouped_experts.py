@@ -1,10 +1,11 @@
-"""The held experts' SwiGLU over the rows the router sent them, and no others:
-a grouped product (Pallas, TPU) over one list of the (position, held expert)
-pairs ordered by expert.
+"""The held experts' gated feed-forward (`act(x W1) * (x W3)) W2`, the gate's
+activation `act` a static argument: `silu`, a SwiGLU, or `relu`, a ReGLU) over
+the rows the router sent them, and no others: a grouped product (Pallas, TPU)
+over one list of the (position, held expert) pairs ordered by expert.
 
     grouped_experts(x [N, D], held [N, k] int32, w [N, k],
-                    w1 [E, D, F], w3 [E, D, F], w2 [E, F, D])
-        -> sum_{j: 0 <= held[n, j] < E} w[n, j] SwiGLU_{held[n, j]}(x[n])  [N, D]
+                    w1 [E, D, F], w3 [E, D, F], w2 [E, F, D], act="silu")
+        -> sum_{j: 0 <= held[n, j] < E} w[n, j] GLU_{held[n, j]}(x[n])  [N, D]
 
 `held[n, j]` is the j-th expert position n chose, counted from the first
 expert this chip holds (outside `[0, E)`: another chip's, nothing is added
@@ -39,7 +40,16 @@ from `x` where it lies (a DMA a pair, `rows[p]` its source): no `[N x k, D]`
 copy of the gathered rows exists, forward or backward. The visits past the
 last one repeat its blocks (no transfer) and compute nothing.
 
-- forward: gate and up products, `silu(gate) * up * w`, the down product:
+**A row's layout.** A row of D floats travels as `[C, LANES]`, C = D / 128
+sublanes, and a copy moves whole 8-sublane tiles. Where C is a multiple of 8
+(D 2048: 16) a row is its own tiles. Where it is not (D 2560: 20) every row
+is padded to `_chunks(D)` sublanes, the next multiple of 8 (24): `_in_rows`
+pads the inputs with zeros (the bytes a tiled `[N, 20, 128]` array takes in
+memory anyway), the buffers and the per-pair outputs hold the padded rows,
+`_fetched` and `_put_rows` read and write a row's first C sublanes, and the
+pad of an output is never written and never read.
+
+- forward: gate and up products, `act(gate) * up * w`, the down product:
   `ys [N x k, D]`, a row a pair;
 - backward over the rows: recomputes gate and up from the fetched rows of x,
   fetches the rows of the incoming gradient, and gives the pairs' gradient
@@ -206,12 +216,19 @@ def _fetch(rows, base, count, sources, buffers, sems):
     jax.lax.fori_loop(0, count, wait, 0)
 
 
-def _fetched(buf, size: int):
-    """The `[size, D]` rows of a buffer `_fetch` filled: lane chunk c of
-    every row is the sublanes c, c + C, c + 2 C, ..."""
+def _chunks(d: int) -> int:
+    """Sublanes a row of `d` floats travels as: D / LANES up to whole
+    8-sublane tiles (the module's docstring: a row's layout)."""
+    return -(-(d // LANES) // 8) * 8
+
+
+def _fetched(buf, size: int, d: int):
+    """The `[size, d]` rows of a buffer `_fetch` filled: lane chunk c of
+    every row is the sublanes c, c + C, c + 2 C, ..., C the sublanes a row
+    travels as; a row's pad past `d` is left where it is."""
     chunks = buf.shape[0] // size
     return jnp.concatenate([buf[pl.ds(c, size, stride=chunks), :]
-                            for c in range(chunks)], axis=1)
+                            for c in range(d // LANES)], axis=1)
 
 
 def _visit(group, tile, flags, starts, size: int):
@@ -238,10 +255,11 @@ def _put(ref, value, mine, first):
 def _put_rows(ref, value, mine, first):
     """`_put` into a block that holds row r as its sublanes `[r C, (r + 1)
     C)` (what `_fetch` copies a row of): lane chunk c of every row to the
-    sublanes c, c + C, ..."""
+    sublanes c, c + C, ...; a row's pad (C over the value's width) is not
+    written."""
     size = value.shape[0]
     chunks = ref.shape[0] // size
-    for c in range(chunks):
+    for c in range(value.shape[1] // LANES):
         at = pl.ds(c, size, stride=chunks)
         kept = jnp.where(first, 0.0, ref[at, :])
         ref[at, :] = jnp.where(mine, value[:, c * LANES:(c + 1) * LANES], kept)
@@ -272,8 +290,9 @@ def _gate_up(x, w1b, w3b):
 
 
 def _forward_kernel(group, tile, flags, starts, rows, x_any, wrow_ref, w1_ref,
-                    w3_ref, w2_ref, ys_ref, xbuf, w1b, w3b, w2b, sem):
-    size = wrow_ref.shape[0]
+                    w3_ref, w2_ref, ys_ref, xbuf, w1b, w3b, w2b, sem, *,
+                    act: str):
+    size, d = wrow_ref.shape[0], w1_ref.shape[0]
     flag, mine, base, count = _visit(group, tile, flags, starts, size)
     first = flag & NEW_TILE != 0
 
@@ -285,9 +304,10 @@ def _forward_kernel(group, tile, flags, starts, rows, x_any, wrow_ref, w1_ref,
 
     @pl.when(flag & ACTIVE != 0)
     def _():
-        gate, up = _gate_up(_fetched(xbuf, size), w1b, w3b)
-        hidden = (gate * jax.nn.sigmoid(gate) * up
-                  * _across(wrow_ref[...], gate.shape[1]))
+        gate, up = _gate_up(_fetched(xbuf, size, d), w1b, w3b)
+        gated = (gate * jax.nn.sigmoid(gate) if act == "silu"
+                 else jnp.maximum(gate, 0.0))
+        hidden = gated * up * _across(wrow_ref[...], gate.shape[1])
         # a row of another expert, or past the list's end (never fetched:
         # whatever the buffer held), multiplies as zeros
         hidden = jnp.where(mine, hidden, 0).astype(jnp.bfloat16)
@@ -299,8 +319,8 @@ def _forward_kernel(group, tile, flags, starts, rows, x_any, wrow_ref, w1_ref,
 def _backward_rows_kernel(group, tile, flags, starts, rows, x_any, d_any,
                           wrow_ref, w1_ref, w3_ref, w2_ref, dxs_ref,
                           dwrow_ref, hidden_ref, dgate_ref, dup_ref, xbuf,
-                          dbuf, w1b, w3b, w2b, sem):
-    size = wrow_ref.shape[0]
+                          dbuf, w1b, w3b, w2b, sem, *, act: str):
+    size, d = wrow_ref.shape[0], w1_ref.shape[0]
     flag, mine, base, count = _visit(group, tile, flags, starts, size)
     first = flag & NEW_TILE != 0
 
@@ -313,19 +333,25 @@ def _backward_rows_kernel(group, tile, flags, starts, rows, x_any, d_any,
 
     @pl.when(flag & ACTIVE != 0)
     def _():
-        gate, up = _gate_up(_fetched(xbuf, size), w1b, w3b)
-        sig = jax.nn.sigmoid(gate)
-        act = gate * sig
+        gate, up = _gate_up(_fetched(xbuf, size, d), w1b, w3b)
+        if act == "silu":
+            sig = jax.nn.sigmoid(gate)
+            gated = gate * sig
+        else:
+            gated = jnp.maximum(gate, 0.0)
         weight = _across(wrow_ref[...], gate.shape[1])
         dhidden = jax.lax.dot_general(
-            _fetched(dbuf, size).astype(jnp.bfloat16), w2b[...], NT,
+            _fetched(dbuf, size, d).astype(jnp.bfloat16), w2b[...], NT,
             preferred_element_type=jnp.float32)               # [tile, F]
-        plain = act * up
+        plain = gated * up
         dwrow = jnp.sum(dhidden * plain, axis=1, keepdims=True)
         dplain = dhidden * weight
-        dgate = jnp.where(mine, dplain * up * sig * (1 + gate * (1 - sig)),
-                          0).astype(jnp.bfloat16)
-        dup = jnp.where(mine, dplain * act, 0).astype(jnp.bfloat16)
+        if act == "silu":
+            dgate = dplain * up * sig * (1 + gate * (1 - sig))
+        else:               # relu's slope at 0 is 0, as jax.nn.relu's
+            dgate = jnp.where(gate > 0, dplain * up, 0.0)
+        dgate = jnp.where(mine, dgate, 0).astype(jnp.bfloat16)
+        dup = jnp.where(mine, dplain * gated, 0).astype(jnp.bfloat16)
         dxs = (jax.lax.dot_general(dgate, w1b[...], NT,
                                    preferred_element_type=jnp.float32)
                + jax.lax.dot_general(dup, w3b[...], NT,
@@ -340,7 +366,7 @@ def _backward_rows_kernel(group, tile, flags, starts, rows, x_any, d_any,
 def _backward_weights_kernel(group, tile, flags, starts, rows, x_any, d_any,
                              hidden_ref, dgate_ref, dup_ref, dw1_ref, dw3_ref,
                              dw2_ref, xbuf, dbuf, sem):
-    size = hidden_ref.shape[0]
+    size, d = hidden_ref.shape[0], dw1_ref.shape[0]
     flag, mine, base, count = _visit(group, tile, flags, starts, size)
 
     @pl.when(flag & NEW_TILE != 0)
@@ -357,8 +383,8 @@ def _backward_weights_kernel(group, tile, flags, starts, rows, x_any, d_any,
     def _():
         # the expert's rows alone: another's, and the rows past the list's
         # end (never fetched), multiply as zeros
-        xb = jnp.where(mine, _fetched(xbuf, size), 0).astype(jnp.bfloat16)
-        db = jnp.where(mine, _fetched(dbuf, size), 0).astype(jnp.bfloat16)
+        xb = jnp.where(mine, _fetched(xbuf, size, d), 0).astype(jnp.bfloat16)
+        db = jnp.where(mine, _fetched(dbuf, size, d), 0).astype(jnp.bfloat16)
         grads = [
             (dw1_ref, jax.lax.dot_general(
                 xb, dgate_ref[...], TN, preferred_element_type=jnp.float32)),
@@ -389,9 +415,9 @@ def _specs(tile: int):
 
 def _pair_rows(pairs: int, d: int, tile: int):
     """(shape, block spec) of a `[N x k, D]` output a later kernel fetches
-    rows of: written as `[N x k x D / LANES, LANES]`, a row its own
+    rows of: written as `[N x k x C, LANES]`, a row its own C = `_chunks(D)`
     sublanes (`_put_rows`)."""
-    chunks = d // LANES
+    chunks = _chunks(d)
     return (jax.ShapeDtypeStruct((pairs * chunks, LANES), jnp.float32),
             pl.BlockSpec((tile * chunks, LANES),
                          lambda v, group, which, *rest: (which[v], 0)))
@@ -415,7 +441,7 @@ def _call(kernel, tile, interpret, name, plan: RoutePlan, every_group,
 
 def _row_buffers(d: int, tile: int, fetched: int):
     """What `_fetch` fills: a buffer a fetched array, a semaphore each."""
-    return ([pltpu.VMEM((tile * d // LANES, LANES), jnp.float32)] * fetched,
+    return ([pltpu.VMEM((tile * _chunks(d), LANES), jnp.float32)] * fetched,
             [pltpu.SemaphoreType.DMA((fetched,))])
 
 
@@ -429,28 +455,33 @@ def _scratch(e_d_f, tile: int, fetched: int):
 
 
 def _in_rows(x):
-    """`[N, D]` as the `[N, D / LANES, LANES]` a row of which is whole
-    tiles, so that a copy can move one row."""
-    return x.reshape(x.shape[0], -1, LANES)
+    """`[N, D]` as the `[N, C, LANES]` a row of which is whole tiles, so that
+    a copy can move one row: C = `_chunks(D)`, zeros past D / LANES."""
+    rows = x.reshape(x.shape[0], -1, LANES)
+    pad = _chunks(x.shape[1]) - rows.shape[1]
+    return jnp.pad(rows, ((0, 0), (0, pad), (0, 0))) if pad else rows
 
 
-def _forward(tile: int, interpret: bool, plan: RoutePlan, x, w1, w3, w2):
-    """-> ys [N x k, D / LANES, LANES] float32: sorted pair p's weighted
-    expert output."""
+def _forward(tile: int, interpret: bool, act: str, plan: RoutePlan, x, w1,
+             w3, w2):
+    """-> ys [N x k, C, LANES] float32, C = `_chunks(D)`: sorted pair p's
+    weighted expert output."""
     _, d, f = w1.shape
     by_tile, by_group, in_place = _specs(tile)
     shape, spec = _pair_rows(plan.rows.shape[0], d, tile)
     return _call(
-        _forward_kernel, tile, interpret, "forward", plan, False,
+        functools.partial(_forward_kernel, act=act), tile, interpret,
+        "forward", plan, False,
         (_in_rows(x), plan.wrow, w1, w3, w2),
         [in_place, by_tile(LANES), by_group(d, f), by_group(d, f),
          by_group(f, d)],
-        shape, spec, _scratch(w1.shape, tile, 1)).reshape(-1, d // LANES, LANES)
+        shape, spec, _scratch(w1.shape, tile, 1)).reshape(-1, _chunks(d),
+                                                          LANES)
 
 
-def _backward(tile: int, interpret: bool, plan: RoutePlan, x, w1, w3, w2,
-              dout):
-    """-> (dxs [N x k, D / LANES, LANES], dwrow [N x k, LANES], dw1, dw3,
+def _backward(tile: int, interpret: bool, act: str, plan: RoutePlan, x, w1,
+              w3, w2, dout):
+    """-> (dxs [N x k, C, LANES], dwrow [N x k, LANES], dw1, dw3,
     dw2): the pairs' gradient rows and weights' gradients, the experts'
     matrices' gradients."""
     _, d, f = w1.shape
@@ -460,7 +491,8 @@ def _backward(tile: int, interpret: bool, plan: RoutePlan, x, w1, w3, w2,
     x, dout = _in_rows(x), _in_rows(dout)
     shape, spec = _pair_rows(pairs, d, tile)
     dxs, dwrow, hidden, dgate, dup = _call(
-        _backward_rows_kernel, tile, interpret, "backward_rows", plan, False,
+        functools.partial(_backward_rows_kernel, act=act), tile, interpret,
+        "backward_rows", plan, False,
         (x, dout, plan.wrow, w1, w3, w2),
         [in_place, in_place, by_tile(LANES), by_group(d, f), by_group(d, f),
          by_group(f, d)],
@@ -510,14 +542,15 @@ def _combine_kernel(packed, count, src_any, count_ref, out_ref, buf, sem):
     out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
     def add(r, carry):
-        out_ref[...] += jnp.where(count_ref[:, :1] > r,
-                                  _fetched(buf.at[r], size), 0)
+        out_ref[...] += jnp.where(
+            count_ref[:, :1] > r,
+            _fetched(buf.at[r], size, out_ref.shape[1]), 0)
         return carry
 
     jax.lax.fori_loop(0, most, add, 0)
 
 
-def _combine(tile: int, interpret: bool, per_pair, plan: RoutePlan):
+def _combine(tile: int, interpret: bool, per_pair, plan: RoutePlan, d: int):
     n, slots = plan.held.shape
     chunks = per_pair.shape[1]
     return pl.pallas_call(
@@ -526,12 +559,11 @@ def _combine(tile: int, interpret: bool, per_pair, plan: RoutePlan):
             num_scalar_prefetch=2, grid=(n // tile,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec((tile, LANES), lambda t, *rest: (t, 0))],
-            out_specs=pl.BlockSpec((tile, chunks * LANES),
-                                   lambda t, *rest: (t, 0)),
+            out_specs=pl.BlockSpec((tile, d), lambda t, *rest: (t, 0)),
             scratch_shapes=[
                 pltpu.VMEM((slots, tile * chunks, LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA((1,))]),
-        out_shape=jax.ShapeDtypeStruct((n, chunks * LANES), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret, name=KERNEL_NAME + "_combine",
@@ -540,46 +572,49 @@ def _combine(tile: int, interpret: bool, per_pair, plan: RoutePlan):
 
 
 def combine(per_pair, plan: RoutePlan, tile: int = COMBINE_TILE,
-            interpret: bool = False):
-    """A position's pairs' rows of `per_pair [N x k, D / LANES, LANES]`
-    added up: [N, D]. The kernel fetches the rows of the pairs there are, a
-    tile of positions at a time: nothing is read for a place of the k that
-    holds no pair of this chip."""
-    return _combine(min(tile, plan.held.shape[0]), interpret, per_pair, plan)
+            interpret: bool = False, d: int | None = None):
+    """A position's pairs' rows of `per_pair [N x k, C, LANES]` added up:
+    [N, D] (`d`; C x LANES where the rows carry no pad). The kernel fetches
+    the rows of the pairs there are, a tile of positions at a time: nothing
+    is read for a place of the k that holds no pair of this chip."""
+    return _combine(min(tile, plan.held.shape[0]), interpret, per_pair, plan,
+                    d or per_pair.shape[1] * LANES)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _apply(tile: int, interpret: bool, x, held, w, w1, w3, w2):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _apply(tile: int, interpret: bool, act: str, x, held, w, w1, w3, w2):
     """-> (the layer's output [N, D], the list it was computed by)."""
     plan = route_plan(held, w, w1.shape[0])
-    ys = _forward(tile, interpret, plan, x, w1, w3, w2)
-    return combine(ys, plan, interpret=interpret), plan
+    ys = _forward(tile, interpret, act, plan, x, w1, w3, w2)
+    return combine(ys, plan, interpret=interpret, d=x.shape[1]), plan
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _pull(tile: int, interpret: bool, plan: RoutePlan, x, w1, w3, w2, dout):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _pull(tile: int, interpret: bool, act: str, plan: RoutePlan, x, w1, w3,
+          w2, dout):
     """-> the gradients to (x, w, w1, w3, w2)."""
-    dxs, dwrow, dw1, dw3, dw2 = _backward(tile, interpret, plan, x, w1, w3,
-                                          w2, dout)
+    dxs, dwrow, dw1, dw3, dw2 = _backward(tile, interpret, act, plan, x, w1,
+                                          w3, w2, dout)
     # back to the order of [N, k]: the sort's own inverse, one more sort
     _, dw = jax.lax.sort((plan.place, dwrow[:, 0]), num_keys=1,
                          is_stable=False)
     dw = jnp.where(plan.held, dw.reshape(plan.held.shape), 0)
-    return combine(dxs, plan, interpret=interpret), dw, dw1, dw3, dw2
+    return (combine(dxs, plan, interpret=interpret, d=x.shape[1]), dw, dw1,
+            dw3, dw2)
 
 
 @functools.lru_cache(maxsize=8)
-def _experts_of(tile: int, interpret: bool):
+def _experts_of(tile: int, interpret: bool, act: str):
     @jax.custom_vjp
     def experts(x, held, w, w1, w3, w2):
-        return _apply(tile, interpret, x, held, w, w1, w3, w2)[0]
+        return _apply(tile, interpret, act, x, held, w, w1, w3, w2)[0]
 
     def experts_fwd(x, held, w, w1, w3, w2):
-        out, plan = _apply(tile, interpret, x, held, w, w1, w3, w2)
+        out, plan = _apply(tile, interpret, act, x, held, w, w1, w3, w2)
         return out, (plan, x, w1, w3, w2)
 
     def experts_bwd(saved, dout):
-        dx, dw, dw1, dw3, dw2 = _pull(tile, interpret, *saved, dout)
+        dx, dw, dw1, dw3, dw2 = _pull(tile, interpret, act, *saved, dout)
         return (dx, np.zeros(saved[0].held.shape, jax.dtypes.float0), dw,
                 dw1, dw3, dw2)
 
@@ -587,16 +622,21 @@ def _experts_of(tile: int, interpret: bool):
     return jax.jit(experts)     # a call site binds one cached trace
 
 
+ACTS = ("silu", "relu")   # the gate's activation: a SwiGLU, a ReGLU
+
+
 def grouped_experts(x, held, w, w1, w3, w2, tile: int = TILE,
-                    interpret: bool = False):
+                    interpret: bool = False, act: str = "silu"):
     """See the module's docstring. `tile`, `interpret`: a narrow tile in
     Pallas' interpreter, for a test without the chip."""
     n, d = x.shape
+    if act not in ACTS:
+        raise ValueError(f"grouped experts: act {act!r} is none of {ACTS}")
     if held.shape != w.shape or held.shape[0] != n or (n * held.shape[1]) % tile:
         raise ValueError(f"grouped experts: {held.shape} picks and {w.shape} "
                          f"weights for {n} positions in tiles of {tile}")
     # the kernels fetch float32 rows and round every operand themselves
     weights = [m.astype(jnp.float32) for m in (w1, w3, w2)]
-    out = _experts_of(tile, bool(interpret))(
+    out = _experts_of(tile, bool(interpret), act)(
         x.astype(jnp.float32), held, w.astype(jnp.float32), *weights)
     return out.astype(x.dtype)

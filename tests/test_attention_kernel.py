@@ -4,7 +4,11 @@ form, which is also what every CPU run of the model keeps): outputs and the
 gradients to q, k and v for a whole layer and for the last; its static masks
 against the reference's written-out matrix; its tile counts against a numpy
 count of the mask's non-empty tiles; and the CPU round program of the
-block-diffusion model, which must not hold the kernel."""
+block-diffusion model, which must not hold the kernel. For
+models/smallthinker.py: the kernel under a causal mask and under a causal
+mask with a window, a group of 7 query heads a key-value head, against that
+model's written-out form; and the model's static tile counts at the cell's
+rows of 8,192."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,8 +17,9 @@ import pytest
 from chipbench.reference import sdar as ref
 from dba_mod_tpu.fl.experiment import Experiment
 from dba_mod_tpu.models import build_model, sdar
+from dba_mod_tpu.models import smallthinker as st
 from dba_mod_tpu.ops import attention
-from tests import sdar_cases
+from tests import sdar_cases, smallthinker_cases
 from tests.test_block_diffusion import lowered_sha
 
 T, BLK, KV, G, HD = 256, 4, 2, 2, 128
@@ -192,3 +197,74 @@ def test_on_the_cpu_the_round_program_holds_no_kernel():
         ns, k1, k2, exp.device_data.train_source).as_text()
     assert "custom_call" not in text or "tpu_custom_call" not in text
     assert lowered_sha(exp, 2) == "d5c541e12e5f184a"
+
+
+LONG, WINDOW, GROUP = 512, 160, 7   # four tiles a row; the window cuts tiles
+
+
+@pytest.fixture(scope="module")
+def layout_forms(small_tiles):
+    """kind -> ((out, dq, dk, dv) of the kernel, the same of the scores
+    written out) under models/smallthinker.py's two masks, 7 query heads a
+    key-value head, on one draw of q, k, v and of the output's weights."""
+    keys = jax.random.split(jax.random.key(13), 4)
+    q = jax.random.normal(keys[0], (1, KV, GROUP, LONG, HD))
+    k = jax.random.normal(keys[1], (1, KV, LONG, HD))
+    v = jax.random.normal(keys[2], (1, KV, LONG, HD))
+    w = jax.random.normal(keys[3], q.shape)
+
+    def results(form):
+        out, pull = jax.vjp(form, q, k, v)
+        return (out,) + pull(w)
+
+    def of(kind):
+        mask = st.attention_mask(LONG, WINDOW if kind == st.WINDOW else None)
+        return (results(lambda *a: attention.blocked_attention(
+                    *a, mask, interpret=True)),
+                results(lambda *a: st.written_attention(*a, mask, jnp.float32)))
+
+    return {kind: of(kind) for kind in (st.FULL, st.WINDOW)}
+
+
+@pytest.mark.parametrize("kind", [st.FULL, st.WINDOW])
+@pytest.mark.parametrize("which", range(4), ids=["out", "dq", "dk", "dv"])
+def test_the_kernel_is_the_written_out_form_under_both_layout_masks(
+        layout_forms, which, kind):
+    """As `test_the_kernel_is_the_three_part_form`, to bfloat16's rounding;
+    a group of 7 (a tile's rows are 7 x 128 here, 7 x 256 on the chip) and a
+    window whose edge cuts through tiles and leaves whole tiles unvisited."""
+    got, want = (r[which] for r in layout_forms[kind])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.isfinite(got).all())
+    err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    assert err < 8e-3, err
+    np.testing.assert_allclose(got, want, atol=0.05 * float(jnp.abs(want).max()))
+
+
+def test_the_window_leaves_tiles_unvisited(small_tiles):
+    full = attention.plan_of(st.attention_mask(LONG, None))
+    window = attention.plan_of(st.attention_mask(LONG, WINDOW))
+    assert (full.tiles_run, full.tiles_all) == (10, 16)
+    # a query tile sees its own key tile and at most the two before it
+    assert window.tiles_run == 1 + 2 + 3 + 3 and window.tiles_all == 16
+
+
+def test_the_long_row_models_tile_counts_are_the_issues(monkeypatch):
+    """At the cell's shapes (rows of 8,192, a 4,096-key window, 256 x 512
+    tiles, 4 key-value heads, one period of layers): 272 of a key-value
+    head's 512 tile pairs in the global layer, 216 in each window layer."""
+    config = st.SmallThinkerConfig.from_dict(smallthinker_cases.arch(
+        head_dim=128, num_key_value_heads=4, sliding_window_size=4096,
+        layers_run=[0, 1, 2, 3]))
+    assert st.attention_counts(config, 8192)["attention_tiles_all"] == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attention, "BLOCK_Q", 256)   # the chip's, whatever
+    monkeypatch.setattr(attention, "BLOCK_K", 512)   # `small_tiles` has set
+    counts = st.attention_counts(config, 8192)
+    assert counts["attention_tiles_full"] == 4 * 272
+    assert counts["attention_tiles_window"] == 4 * 3 * 216
+    assert counts["attention_tiles_all"] == 4 * 4 * 512
+    assert counts["attention_tiles_run"] == 4 * (272 + 3 * 216)
+    assert counts["attention_pairs_full"] == 33_558_528
+    assert counts["attention_pairs_window"] == 25_167_872
+    assert st.attention_counts(config, 8192 + 64)["attention_tiles_all"] == 0

@@ -3,7 +3,10 @@ interpreter on the CPU, held to `models/sdar.py::experts_over_all` (every held
 expert over every position, which is also what every CPU run of the model
 keeps): the output and the gradients to x, the router's weights and the three
 matrices under the routings the one path must hold in; the list and the walk
-against numpy counts of the same routing."""
+against numpy counts of the same routing. The same with the gate's activation
+`relu` (models/smallthinker.py's ReGLU experts) at a hidden size of 20 x 128,
+whose rows travel padded to 24 sublanes."""
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -192,3 +195,77 @@ def test_which_form_runs_is_read_from_the_backend_and_the_shapes(monkeypatch):
     with pytest.raises(ValueError, match="tiles of"):
         ge.grouped_experts(jnp.zeros((6, 128)), jnp.zeros((6, 2), jnp.int32),
                            jnp.zeros((6, 2)), *(jnp.zeros((2, 128, 128)),) * 3)
+
+
+WIDE = 20 * ge.LANES      # D / 128 = 20: no whole number of 8-sublane tiles
+RELU_CASES = ("even", "one_given_every_position",
+              "groups_no_multiple_of_the_tile")
+
+
+@pytest.fixture(scope="module")
+def relu_forms():
+    """case -> the kernels' and the oracle's (out, dx, dw, dw1, dw3, dw2)
+    with `act="relu"` at a hidden size of 2560."""
+    keys = jax.random.split(jax.random.key(4), 6)
+    inputs = (jax.random.normal(keys[0], (N, WIDE)),
+              jax.nn.softmax(jax.random.normal(keys[1], (N, K)), axis=-1),
+              jax.random.normal(keys[2], (E, WIDE, F)) * 0.05,
+              jax.random.normal(keys[3], (E, WIDE, F)) * 0.05,
+              jax.random.normal(keys[4], (E, F, WIDE)) * 0.1)
+    cot = jax.random.normal(keys[5], (N, WIDE))
+
+    def of(case):
+        held = jnp.asarray(routing(case), jnp.int32)
+        picks = held[:, :, None] == jnp.arange(E)
+
+        def oracle(x, w, w1, w3, w2):
+            # a ReLU's slope jumps at 0: the oracle's gate takes the
+            # operands the kernel's takes, rounded to bfloat16 (exact
+            # float32 gates have another sign at one entry in a hundred,
+            # and the gradients through them differ by 6-8 %)
+            x, w1, w3 = (a.astype(jnp.bfloat16).astype(jnp.float32)
+                         for a in (x, w1, w3))
+            wts = jnp.sum(jnp.where(picks, w[:, :, None], 0.0), axis=1)
+            return experts_over_all(x, wts, w1, w3, w2, act=nn.relu)
+
+        def kernels(x, w, w1, w3, w2):
+            return ge.grouped_experts(x, held, w, w1, w3, w2, tile=TILE,
+                                      interpret=True, act="relu")
+
+        def results(form):
+            out, pull = jax.vjp(form, *inputs)
+            return (out,) + pull(cot)
+
+        return results(kernels), results(oracle)
+
+    return {case: of(case) for case in RELU_CASES}
+
+
+@pytest.mark.parametrize("part", PARTS)
+@pytest.mark.parametrize("case", RELU_CASES)
+def test_the_relu_gate_at_twenty_lane_chunks_a_row(relu_forms, case, part):
+    """As `test_the_grouped_product_is_every_expert_over_every_position`, to
+    bfloat16's rounding: a ReGLU, and rows of 20 x 128 floats that travel as
+    24 sublanes (a chunk read from the pad, or a row written over its
+    neighbour's, would be off by tenths)."""
+    got, want = (r[PARTS.index(part)] for r in relu_forms[case])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.isfinite(got).all())
+    scale = float(jnp.linalg.norm(want))
+    assert scale > 0
+    assert float(jnp.linalg.norm(got - want)) / scale < 8e-3
+    np.testing.assert_allclose(got, want,
+                               atol=0.05 * float(jnp.abs(want).max()))
+
+
+def test_a_rows_sublanes_are_whole_tiles_and_the_act_is_one_of_two(monkeypatch):
+    assert ge._chunks(2048) == 16 and ge._chunks(2560) == 24
+    assert ge._chunks(128) == 8
+    assert ge._in_rows(jnp.ones((4, 2560))).shape == (4, 24, ge.LANES)
+    assert not bool(jnp.any(ge._in_rows(jnp.ones((4, 2560)))[:, 20:]))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ge.runs_here(8192, 2560, 768)
+    with pytest.raises(ValueError, match="act"):
+        ge.grouped_experts(jnp.zeros((16, 128)), jnp.zeros((16, 1), jnp.int32),
+                           jnp.zeros((16, 1)), *(jnp.zeros((2, 128, 128)),) * 3,
+                           tile=16, act="gelu")

@@ -11,10 +11,12 @@
   with the bfloat16 control over one;
 - the same two for an `sdar_moe` experiment (block diffusion: the objective
   is the model's own, the reference draws the program's noise from the
-  feed's key).
+  feed's key);
+- and for a `smallthinker` experiment (two kinds of attention layer, a
+  router that reads the pre-attention norm, ReGLU experts), whose plan also
+  says what its attention is made of.
 """
 import dataclasses
-import gc
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +29,7 @@ from dba_mod_tpu.config import Params
 from dba_mod_tpu.fl.experiment import Experiment
 from dba_mod_tpu.fl.rounds import RoundEngine
 from dba_mod_tpu.utils import telemetry
-from tests import sdar_cases
+from tests import sdar_cases, smallthinker_cases
 from tests.lfm2_cases import ARCH, params, small_buffers  # noqa: F401
 
 pytestmark = pytest.mark.usefixtures("small_buffers")
@@ -167,11 +169,52 @@ def test_an_sdar_experiment_trains_poisons_and_records(tmp_path):
                == p["client_steps"] * 4 * 96 for c, p in zip(record, plan))
 
 
+def test_a_smallthinker_experiment_trains_poisons_and_records(tmp_path):
+    """Layers of two attention kinds through `main.py`'s path, next-token
+    form, `run_batch` without an objective of the model's own; the plan's and
+    the record's spans carry the counts the `st_*` readers read."""
+    exp = Experiment(smallthinker_cases.params(run_dir=str(tmp_path),
+                                               telemetry=False),
+                     save_results=True)
+    assert exp.engine.streamed and exp.model_def.objective is None
+    mark = len(telemetry.spans())
+    results = [exp.run_round(e) for e in (1, 2, 3)]
+    assert all(np.isfinite(r["global_acc"]) for r in results)
+    assert results[1]["backdoor_acc"] is not None
+    rows = program.recorded_rows(exp)
+    assert [r["epoch"] for r in rows] == [1, 2, 3]
+    assert rows[1]["adversaries"] == ["0", "1", "2", "3"]
+    spans = telemetry.spans()[mark:]
+    plan = [s.counts for s in spans if s.name == "round/plan"]
+    record = [s.counts for s in spans if s.name == "round/record"]
+    # 4 clients x 2 rows of their own, a row a step; a poisoned round: every
+    # client an adversary's 3 epochs
+    assert [c["client_steps"] for c in plan] == [8, 24, 8]
+    assert all(c["tokens_step"] == 32 and "block_length" not in c
+               for c in plan)
+    # rows of 32 under a window of 8: the pairs each kind's mask allows; on
+    # this CPU the scores are written out and no tile is counted
+    assert all(c["attention_pairs_full"] == 32 * 33 // 2
+               and c["attention_pairs_window"] == 8 * 9 // 2 + 24 * 8
+               for c in plan)
+    assert all(c["attention_tiles_run"] == c["attention_tiles_all"]
+               == c["attention_tiles_full"] == c["attention_tiles_window"] == 0
+               for c in plan)
+    # 2 layers x 32 positions x 3 choices of 16 experts, 4 of them held
+    assert all(0.5 < c["expert_tokens_held"] / (p["client_steps"] * 48) < 1.5
+               for c, p in zip(record, plan))
+    assert all(c["expert_rows_run"] == c["expert_rows_all"]
+               == p["client_steps"] * 2 * 4 * 32 for c, p in zip(record, plan))
+
+
 CONFIG = {"name": "lfm2_toy", "population_seed": 1,
           "model": {"family": "lfm2_moe", "seq_len": 32, "arch": ARCH}}
 SDAR_CONFIG = {"name": "sdar_toy", "population_seed": 1,
                "model": {"family": "sdar_moe", "seq_len": 32,
                          "arch": sdar_cases.ARCH}}
+ST_CONFIG = {"name": "smallthinker_toy", "population_seed": 1,
+             "model": {"family": "smallthinker", "seq_len": 32,
+                       "arch": smallthinker_cases.ARCH}}
 TRAFFIC = {"is_poison": True, "period_rounds": 10,
            "poison_window_rounds": [3, 5, 7, 9], "periods_max": 1,
            "num_devices": 0}
@@ -183,9 +226,10 @@ class Events:
 
 
 @pytest.mark.parametrize("dtype,inside", [("float32", True), ("bfloat16", False)])
-@pytest.mark.parametrize("toy,toy_params", [(CONFIG, params),
-                                            (SDAR_CONFIG, sdar_cases.params)],
-                         ids=["lfm2_moe", "sdar_moe"])
+@pytest.mark.parametrize("toy,toy_params", [
+    (CONFIG, params), (SDAR_CONFIG, sdar_cases.params),
+    (ST_CONFIG, smallthinker_cases.params)],
+    ids=["lfm2_moe", "sdar_moe", "smallthinker"])
 def test_the_familys_check_rounds_against_the_reference(tmp_path, toy,
                                                         toy_params, dtype,
                                                         inside):
@@ -209,7 +253,10 @@ def test_the_familys_check_rounds_against_the_reference(tmp_path, toy,
     assert all(row["ok"] for row in compared) == inside, compared
     assert exp.engine.workspace is None      # released for the reference
     report = program.engine_report(exp, False, family.engine_conditions(exp))
-    assert report["ok"] and report["streamed_round"]
+    assert report["streamed_round"]
+    # a family may ask for kernel forms that only a TPU runs (smallthinker:
+    # the CPU writes its scores out and runs every held expert everywhere)
+    kernels = {"attention_kernel", "grouped_experts"} & set(report)
+    assert report["ok"] == (not kernels) and not any(report[k] for k in kernels)
     flops = family.model_flops(config["model"])
     assert flops["train_step"] == 3 * flops["forward"] > 0
-    gc.unfreeze()   # the family's check round froze this worker's heap

@@ -6,7 +6,8 @@ compiler would refuse (misaligned Pallas slices, VMEM overflow, programs that
 do not fit HBM), which interpret-mode tests cannot see. Covered: the fused
 Pallas per-step update at the CIFAR and Tiny-ImageNet model shapes, the
 blocked attention kernel and the grouped expert product at the SDAR cell's
-shapes and the files' tiles, and the
+and the SmallThinker cell's shapes and the files' tiles, the SmallThinker
+cell's round program (rows of 8,192: its temporaries beside the state), and the
 CIFAR round program's donated twin — the default program of an unsharded TPU
 run, which the CPU suite otherwise never builds.
 
@@ -135,6 +136,97 @@ def test_grouped_experts_compile_for_v5e(one_chip, no_persistent_cache,
                                                            else 2)
     # the list is sized for the most a call can route: 8 pairs a position
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("backward", [False, True])
+def test_layout_attention_compiles_for_v5e(one_chip, no_persistent_cache,
+                                           backward, kind):
+    """`ops/attention.py::blocked_attention` at the SmallThinker cell's
+    shapes (a row of 8,192, 4 key-value heads of 7 query heads, head_dim 128)
+    under models/smallthinker.py's two masks, forward and with its backward
+    kernel: tiles of 7 x 256 rows against 512 keys, and dk and dv of 4 MiB a
+    key-value head each, fit fast memory."""
+    from dba_mod_tpu.models import smallthinker as st
+    from dba_mod_tpu.ops.attention import blocked_attention
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    q, k = shape(1, 4, 7, 8192, 128), shape(1, 4, 8192, 128)
+    mask = st.attention_mask(8192, 4096 if kind == "window" else None)
+    form = lambda q, k, v: blocked_attention(q, k, v, mask)
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(form(*a) ** 2), (0, 1, 2))
+    else:
+        fn = form
+    text = jax.jit(fn).lower(q, k, k).compile().as_text()
+    assert text.count("tpu_custom_call") >= (2 if backward else 1)
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_relu_grouped_experts_compile_for_v5e(one_chip, no_persistent_cache,
+                                              backward):
+    """`ops/grouped_experts.py` at the SmallThinker cell's shapes (a row of
+    8,192 positions, hidden 2,560: 20 lane chunks a row, padded to 24
+    sublanes; 8 held experts 768 wide, top-6, a ReLU gate): every copy moves
+    whole tiles, an expert's matrices, their bfloat16 copies and their
+    gradients fit fast memory; the list is sized for 49,152 pairs."""
+    from dba_mod_tpu.ops import grouped_experts as ge
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    args = (shape(8192, 2560), shape(8192, 6, dt=jnp.int32), shape(8192, 6),
+            shape(8, 2560, 768), shape(8, 2560, 768), shape(8, 768, 2560))
+    form = lambda *a: ge.grouped_experts(*a, act="relu")
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(form(*a) ** 2), (0, 2, 3, 4, 5))
+    else:
+        fn = form
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (5 if backward
+                                                           else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
+def test_smallthinker_round_compiles_for_v5e(one_chip, no_persistent_cache,
+                                             tmp_path):
+    """The cell `smallthinker_long_row_attack`'s round program (the
+    configuration's file under its traffic, as `chipbench.run` builds it) for
+    one described chip: both kernels are in it, and its temporaries fit
+    beside the arguments (the global model, the workspace's three copies, the
+    population): a step of 8,192 tokens over 18,992 logits beside 5.5 GiB of
+    arguments, in the chip's 15.75 GiB."""
+    import json
+    from pathlib import Path
+    from chipbench import program
+    from chipbench import run as harness
+    from dba_mod_tpu.fl.streamed import make_workspace
+    root = Path(__file__).resolve().parents[1] / "chipbench"
+    config = json.loads(
+        (root / "configs/smallthinker_21b_a3b_dba.json").read_text())
+    traffic = json.loads(
+        (root / "traffic/long_row_phrase_rounds.json").read_text())
+    params, _ = program.make_params(config, traffic, tmp_path,
+                                    harness.FIRST_WINDOW_EPOCH)
+    with pytest.MonkeyPatch.context() as mp:
+        # the model asks the backend which attention and which expert product
+        # it runs, when it is built and when it is traced
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        exp, _ = program.build_experiment(params)
+        assert exp.model_def.attention_tiles == (4 * (272 + 3 * 216), 8192)
+        tasks, idx, mask, ns, lane = exp.build_static_round_inputs(
+            harness.FIRST_WINDOW_EPOCH + 1)
+        k1, k2 = jax.random.split(jax.random.key(0))
+        exp.engine.release_workspace()      # three copies on this host
+        work = jax.eval_shape(make_workspace, exp.global_vars)
+        args = _abstract((exp.global_vars, exp.fg_state, work, tasks, idx,
+                          mask, lane, ns, k1, k2,
+                          exp.device_data.train_source), one_chip)
+        compiled = exp.engine.round_fn_donated.lower(*args).compile()
+    text = compiled.as_text()
+    assert "blocked_attention" in text and "grouped_experts" in text
+    mem = compiled.memory_analysis()
+    print("smallthinker round memory:", mem)
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < 15.75 * 2**30, mem
 
 
 @pytest.fixture(scope="module")
