@@ -1,19 +1,18 @@
 """What the token decoders of this package share (models/lfm2.py,
-models/sdar.py): the initialiser, RMSNorm, the rotary tables, and what a
-router's choice means for the experts one chip holds of an expert-parallel
-layer.
+models/sdar.py, models/smallthinker.py): the initialiser, RMSNorm, the
+rotary tables, and what a router's choice means for the experts one chip
+holds of an expert-parallel layer.
 
 **The held experts.** A decoder that is one client's share of a larger job
 routes over all `num_experts` and computes only the experts `[lo, hi)` this
 chip holds; what the absent experts would add to the sum is left out (the
 other chips of the layer add it; nothing here stands in for them). The
 router is the model's own (a sigmoid with a bias buffer, a softmax): it hands
-`held_picks` its selection and weights. How the held experts' products are
-laid out is the model's too: models/lfm2.py gathers a held expert's tokens
-into a buffer where they fit one; models/sdar.py, whose masked positions are
-one token and route alike (no buffer holds them), multiplies one list of the
-routed rows ordered by expert on a TPU (ops/grouped_experts.py) and runs
-every held expert over every position elsewhere.
+`held_picks` its selection and weights. The held experts' products are
+laid out one way in all three decoders: on a TPU one list of the routed rows
+ordered by expert (ops/grouped_experts.py), every held expert over every
+position elsewhere (models/sdar.py::experts_over_all), and the layer counts
+the rows it multiplied (`ROWS_COUNTER`).
 """
 from __future__ import annotations
 

@@ -41,23 +41,24 @@ Departures, each because this model is one client's share of a larger job:
 - A negative token id is padding: embedded as id 0 and never scored
   (ops/losses.py).
 
-No token is dropped. A held expert's tokens are gathered into a buffer of
-`capacity` rows (`CAPACITY_FACTOR` = three times an even share, a multiple of
-8), multiplied as one batched product over the held experts and scattered
-back; a step in which some held expert is chosen by more tokens than that
-runs every held expert over every token instead, weighted by the same
-routing weights (the `lax.cond` below): exact either way. Three and not two:
-identical tokens route alike, so a row's most frequent token alone sends a
-tenth of a step to its four experts; on the chip the fullest held expert of
-a step read 1.6-1.8 times the mean at seeded weights (PERF.md, PR 35), and
-a program whose path depends on the seed's routing has a round time that
-does. The buffer's cost is its rows: the sort, the gather and the scatter
-take most of the layer's time on a TPU v5e, not the products (PERF.md
-section 5).
+No token is dropped and no expert is capped. The held experts' products
+run in one of two forms, by where the process runs, as models/sdar.py's and
+models/smallthinker.py's do (nothing configures it:
+`ops/grouped_experts.py::runs_here`): **on a TPU**, where a call's positions
+are whole tiles of the list and the widths whole lanes, the grouped product
+of `ops/grouped_experts.py` over the rows the router sent (one sort of the
+routed pairs, kernels that fetch their own rows, an expert's width of 1,536
+walked in two blocks of 768; one path whatever the routing: an expert may be
+given every token, another none); **everywhere else** (the CPU suite, toy
+rows) every held expert over every token, weighted by the same routing
+weights (`models/sdar.py::experts_over_all`). The same mathematics on the
+same float32 state. The model counts the rows its expert layers multiplied
+beside the rows every held expert over every token would be, in the
+`counters` collection (`decoder_parts.ROWS_COUNTER`).
 
 Every layer is rematerialised in the backward pass (`nn.remat`): a step
 keeps one layer's activations. The model counts the tokens each held expert
-was given in the `counters` collection (`ModelDef.apply_counted`).
+was given in the `counters` collection too (`ModelDef.apply_counted`).
 """
 from __future__ import annotations
 
@@ -69,12 +70,13 @@ import jax
 import jax.numpy as jnp
 
 from dba_mod_tpu.models.decoder_parts import (  # noqa: F401 (re-exported)
-    INIT_STD, apply_rope, held_picks, rms_norm, rope_tables)
+    INIT_STD, ROWS_COUNTER, apply_rope, held_picks, rms_norm, rope_tables)
 from dba_mod_tpu.models.decoder_parts import normal_init as _normal
+from dba_mod_tpu.models.sdar import experts_over_all
+from dba_mod_tpu.ops import grouped_experts as grouped
 
 CONV = "conv"
 ATTENTION = "full_attention"
-CAPACITY_FACTOR = 3.0  # a held expert's gather buffer over an even share
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,31 +134,6 @@ def route(scores_logits, bias, k: int, norm_topk: bool, scaling: float):
     if norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
     return sel, w * scaling
-
-
-def experts_dense(x, wts, w1, w3, w2):
-    """Every held expert over every token, weighted: x [N, D], wts [N, E]."""
-    gate = jnp.einsum("nd,edf->enf", x, w1)
-    up = jnp.einsum("nd,edf->enf", x, w3)
-    y = jnp.einsum("enf,efd->end", nn.silu(gate) * up, w2)
-    return jnp.einsum("end,ne->nd", y, wts.astype(y.dtype))
-
-
-def experts_gathered(x, wts, chosen, w1, w3, w2, capacity: int):
-    """Each held expert over the tokens that chose it (at most `capacity`
-    each, which the caller has checked): gather, one batched product over the
-    experts, weighted scatter-add."""
-    # a token that chose the expert sorts first, in its order in the batch
-    order = jnp.argsort(~chosen.T, axis=1, stable=True)[:, :capacity]  # [E, cap]
-    took = jnp.take_along_axis(chosen.T, order, axis=1)                # [E, cap]
-    xe = x[order]                                                      # [E, cap, D]
-    gate = jnp.einsum("ecd,edf->ecf", xe, w1)
-    up = jnp.einsum("ecd,edf->ecf", xe, w3)
-    y = jnp.einsum("ecf,efd->ecd", nn.silu(gate) * up, w2)
-    we = jnp.take_along_axis(wts.T, order, axis=1) * took              # [E, cap]
-    y = y * we[..., None].astype(y.dtype)
-    return jnp.zeros_like(x).at[order.reshape(-1)].add(
-        y.reshape(-1, x.shape[-1]))
 
 
 class ShortConv(nn.Module):
@@ -262,24 +239,23 @@ class ExpertFfn(nn.Module):
             sel, w = route(logits, bias,
                            c.num_experts_per_tok, c.norm_topk_prob,
                            c.routed_scaling_factor)
-            # [N, held]: whether a token chose each held expert, and the
-            # weight it gave it (0 where it did not)
-            chosen, wts, counts = held_picks(sel, w, lo, hi)
+            # the weight a token gave each held expert [N, held], 0 where
+            # it did not choose it; the tokens each was given
+            _, wts, counts = held_picks(sel, w, lo, hi)
+        keep = dict(reduce_fn=lambda a, b: b)
         self.sow("counters", "expert_tokens", counts,
-                 reduce_fn=lambda a, b: b, init_fn=lambda: counts * 0)
-        share = n * c.num_experts_per_tok / c.num_experts
-        capacity = min(n, -(-int(CAPACITY_FACTOR * share) // 8) * 8)
-        args = (tokens, wts, w1.astype(self.dtype), w3.astype(self.dtype),
-                w2.astype(self.dtype))
+                 init_fn=lambda: counts * 0, **keep)
+        weights = [m.astype(self.dtype) for m in (w1, w3, w2)]
         with jax.named_scope("experts"):
-            if capacity >= n:
-                out = experts_dense(*args)
+            if grouped.runs_here(n, d, f):
+                out = grouped.grouped_experts(tokens, sel - lo, w, *weights)
+                run = grouped.rows_run(counts, d, f)
             else:
-                out = jax.lax.cond(
-                    jnp.max(counts) <= capacity,
-                    lambda a: experts_gathered(a[0], a[1], chosen, *a[2:],
-                                               capacity),
-                    lambda a: experts_dense(*a), args)
+                out = experts_over_all(tokens, wts, *weights)
+                run = jnp.int32(e * n)
+        self.sow("counters", ROWS_COUNTER,
+                 jnp.stack([run, jnp.int32(e * n)]),
+                 init_fn=lambda: jnp.zeros((2,), jnp.int32), **keep)
         return out.reshape(x.shape)
 
 
@@ -328,7 +304,7 @@ class Lfm2Moe(nn.Module):
 def seed_expert_bias(batch_stats, rng: jax.Array, std: float = 0.01):
     """`expert_bias` as a trained checkpoint carries it: small, seeded, and
     different for every expert (zeros would leave the selection to the
-    scores alone and never exercise the buffer)."""
+    scores alone)."""
     leaves, treedef = jax.tree_util.tree_flatten(batch_stats)
     keys = jax.random.split(rng, max(len(leaves), 1))
     return jax.tree_util.tree_unflatten(

@@ -362,7 +362,7 @@ class SoftmaxExpertFfn(nn.Module):
         with jax.named_scope("experts"):
             if grouped.runs_here(n, d, f):
                 out = grouped.grouped_experts(tokens, sel - lo, w, *weights)
-                run = grouped.rows_run(counts)
+                run = grouped.rows_run(counts, d, f)
             else:
                 out = experts_over_all(tokens, wts, *weights)
                 run = jnp.int32(e * n)

@@ -292,7 +292,7 @@ class PreRoutedExpertFfn(nn.Module):
             if grouped.runs_here(n, d, f):
                 out = grouped.grouped_experts(tokens, sel - lo, w, *weights,
                                               act="relu")
-                run = grouped.rows_run(counts)
+                run = grouped.rows_run(counts, d, f)
             else:
                 out = experts_over_all(tokens, wts, *weights, act=nn.relu)
                 run = jnp.int32(e * n)

@@ -12,11 +12,14 @@ expert this chip holds (outside `[0, E)`: another chip's, nothing is added
 for it); a position names an expert once. What `models/sdar.py::
 experts_over_all` computes over every (position, held expert) pair and
 multiplies by 0 where the router did not choose, this computes for the chosen
-pairs only. **One path**: no buffer an expert, no capacity, no dropped pair,
-no second form to fall back on. The shapes are static and sized for the most
-a call can route (N x k pairs); the work follows the pairs really routed: a
-tile of pairs past the last one is never visited, whichever expert holds how
-many (one may hold every position, another none).
+pairs only. Three expert layers call it: models/sdar.py's (D 2048, F 768,
+`silu`), models/smallthinker.py's (D 2560, F 768, `relu`) and
+models/lfm2.py's (D 2048, F 1,536, `silu`). **One path**: no buffer an
+expert, no capacity, no dropped pair, no second form to fall back on. The
+shapes are static and sized for the most a call can route (N x k pairs); the
+work follows the pairs really routed: a tile of pairs past the last one is
+never visited, whichever expert holds how many (one may hold every position,
+another none).
 
 **The list** (`route_plan`, XLA, a few small arrays): the pairs' keys
 (the expert, then the pair's place in `[N, k]`; a pair of another chip's
@@ -39,6 +42,25 @@ prefetched. **A tile's rows are fetched by the kernel itself**, row by row
 from `x` where it lies (a DMA a pair, `rows[p]` its source): no `[N x k, D]`
 copy of the gathered rows exists, forward or backward. The visits past the
 last one repeat its blocks (no transfer) and compute nothing.
+
+**The width block.** The kernels keep a group's three matrices whole in
+fast memory (the float32 blocks twice, the next group's arriving while this
+one's multiply, and a bfloat16 copy): at D 2048 x F 1,536 that alone is 94 MB
+of the 100 MiB `VMEM_LIMIT`. A gated feed-forward is a sum over blocks of
+its width, `sum_b (act(x W1[:, b]) * (x W3[:, b])) W2[b, :]`, so where an
+expert's matrices do not fit, **the list's groups are the experts' width
+blocks** (`block_plan`): block b of expert e, `width_block(D, F, tile)`
+wide, is group `e B + b`, a routed pair is in the list once a block (`[N, k x
+B]` places), and a group's matrices are blocks of the `[E, D, F]` and `[E, F,
+D]` arrays where they lie (`_specs`: block `g % B` of expert `g // B`; no
+copy). The walk, the kernels and the combine are the same: a position's
+output is the sum over its places, a block's partial down-product a place;
+the gradient to a pair's weight the sum over its blocks'. A pair's row is
+fetched once a block; a block's matrices once. The block is the most whole
+lanes that divide F and fit `VMEM_LIMIT` by `_fast_bytes`' count of what the
+largest kernel holds: F itself at D 2048 and 2560 x F 768 (B = 1: the list
+is `route_plan`'s, and nothing differs from a product without blocks), 768
+at 2048 x 1,536 (B = 2). From the shapes; nothing configures it.
 
 **A row's layout.** A row of D floats travels as `[C, LANES]`, C = D / 128
 sublanes, and a copy moves whole 8-sublane tiles. Where C is a multiple of 8
@@ -74,9 +96,10 @@ pair's down-projection is summed over F alone and a position's pairs are
 added in float32 afterwards, where the einsum contracts over E x F at once.
 
 The forward pass and the backward pass are each one jitted function of
-(tile, shapes): every layer of a model, its recomputation and its evaluation
-passes share one trace and one lowering of the list and the kernels. `TILE` was chosen on the chip (PERF.md, PR 41). No reference
-counterpart.
+(tile, blocks, shapes): every layer of a model, its recomputation and its
+evaluation passes share one trace and one lowering of the list and the
+kernels. `TILE` was chosen on the chip (PERF.md, PR 41), the form of the
+width block priced there (PR 46). No reference counterpart.
 """
 from __future__ import annotations
 
@@ -176,15 +199,49 @@ def runs_here(positions: int, hidden: int, width: int) -> bool:
             and hidden % LANES == 0 and width % LANES == 0)
 
 
-def rows_run(counts, tile: int = TILE):
-    """The rows the forward's visits multiply for a call whose experts were
-    given `counts` [E] pairs: tile padding, and the rows a shared tile is
-    multiplied again for, included (int32 scalar): `visit_plan`'s active
-    visits, counted without the walk."""
+def _fast_bytes(d: int, width: int, tile: int) -> int:
+    """What the rows' backward kernel, the largest, keeps in fast memory at
+    once for experts `width` wide: the three matrices' float32 blocks twice
+    (the next group's arrive while this one's multiply) and once in
+    bfloat16; the two row buffers; its blocks of the lists' arrays twice
+    (the pairs' rows, three `[tile, width]` operands, two lane-replicated
+    columns); and a visit's values, eight `[tile, width]` and two
+    `[tile, D]` float32 (what the chip's compiler refuses: 82 MiB at D 2560
+    x 768, 60 at 2048 x 768, where this says 83.0 and 65.8)."""
+    row = _chunks(d) * LANES * 4
+    return (3 * d * width * (2 * 4 + 2) + 2 * tile * row
+            + 2 * tile * (row + 3 * width * 2 + 2 * LANES * 4)
+            + tile * (8 * width * 4 + 2 * row))
+
+
+def width_block(d: int, f: int, tile: int = TILE) -> int:
+    """The width a visit multiplies: the most whole lanes that divide an
+    expert's width `f` and fit `VMEM_LIMIT` (the module's docstring: the
+    width block). From the shapes and nothing else."""
+    lanes = f // LANES
+    for parts in range(1, lanes + 1):
+        width = f // parts
+        if lanes % parts == 0 and _fast_bytes(d, width, tile) <= VMEM_LIMIT:
+            return width
+    raise ValueError(f"grouped experts: a tile of {tile} rows of {d} and one "
+                     f"lane block of an expert's width pass {VMEM_LIMIT} "
+                     f"bytes of fast memory")
+
+
+def rows_run(counts, d: int, f: int, tile: int = TILE):
+    """The rows the forward's visits multiply for a call whose experts, `f`
+    wide over rows of `d`, were given `counts` [E] pairs: tile padding, and
+    the rows a shared tile is multiplied again for, included (int32 scalar):
+    `visit_plan`'s active visits, counted without the walk. A visit of one
+    of an expert's B width blocks counts as a B-th of its rows."""
+    blocks = f // width_block(d, f, tile)
+    if blocks > 1:
+        counts = jnp.repeat(counts, blocks)
     ends = jnp.cumsum(counts, dtype=jnp.int32)
     visits = jnp.where(counts > 0,
                        (ends - 1) // tile - (ends - counts) // tile + 1, 0)
-    return tile * jnp.sum(visits, dtype=jnp.int32)
+    rows = tile * jnp.sum(visits, dtype=jnp.int32)
+    return rows if blocks == 1 else rows // blocks
 
 
 def _across(x, width: int):
@@ -402,15 +459,23 @@ def _backward_weights_kernel(group, tile, flags, starts, rows, x_any, d_any,
                 ref[...] += grad
 
 
-def _specs(tile: int):
+def _specs(tile: int, d: int, width: int, blocks: int):
     """Block specs: a `[N x k, width]` array a tile of the walk at a time;
-    an expert's matrix; an array left where it is (the kernel fetches its
-    rows). An index map reads the walk (expert, tile, ...)."""
-    by_tile = lambda width: pl.BlockSpec(
-        (tile, width), lambda v, group, which, *rest: (which[v], 0))
-    by_group = lambda *shape: pl.BlockSpec(
-        (None,) + shape, lambda v, group, *rest: (group[v], 0, 0))
-    return by_tile, by_group, pl.BlockSpec(memory_space=pl.ANY)
+    the width block of an expert's `[D, F]` matrix, and of its `[F, D]` one,
+    that the walk's group g names (block g % blocks of expert g // blocks);
+    an array left where it is (the kernel fetches its rows). An index map
+    reads the walk (group, tile, ...)."""
+    by_tile = lambda wide: pl.BlockSpec(
+        (tile, wide), lambda v, group, which, *rest: (which[v], 0))
+
+    def split(g):   # one block: the expert itself, as without blocks
+        return (g, 0) if blocks == 1 else (g // blocks, g % blocks)
+
+    across = pl.BlockSpec((None, d, width), lambda v, group, *rest: (
+        split(group[v])[0], 0, split(group[v])[1]))
+    down = pl.BlockSpec((None, width, d), lambda v, group, *rest: (
+        *split(group[v]), 0))
+    return by_tile, across, down, pl.BlockSpec(memory_space=pl.ANY)
 
 
 def _pair_rows(pairs: int, d: int, tile: int):
@@ -445,13 +510,12 @@ def _row_buffers(d: int, tile: int, fetched: int):
             [pltpu.SemaphoreType.DMA((fetched,))])
 
 
-def _scratch(e_d_f, tile: int, fetched: int):
-    """Row buffers, the expert's three matrices in bfloat16, semaphores."""
-    _, d, f = e_d_f
+def _scratch(d: int, width: int, tile: int, fetched: int):
+    """Row buffers, the group's three blocks in bfloat16, semaphores."""
     buffers, sems = _row_buffers(d, tile, fetched)
-    return buffers + [pltpu.VMEM((d, f), jnp.bfloat16),
-                      pltpu.VMEM((d, f), jnp.bfloat16),
-                      pltpu.VMEM((f, d), jnp.bfloat16)] + sems
+    return buffers + [pltpu.VMEM((d, width), jnp.bfloat16),
+                      pltpu.VMEM((d, width), jnp.bfloat16),
+                      pltpu.VMEM((width, d), jnp.bfloat16)] + sems
 
 
 def _in_rows(x):
@@ -462,50 +526,57 @@ def _in_rows(x):
     return jnp.pad(rows, ((0, 0), (0, pad), (0, 0))) if pad else rows
 
 
+def _widths(plan: RoutePlan, w1):
+    """(D, the width of a group's block, the blocks an expert's width is
+    walked in): the list's groups are the experts' width blocks."""
+    experts, d, f = w1.shape
+    blocks = (plan.starts.shape[0] - 1) // experts
+    return d, f // blocks, blocks
+
+
 def _forward(tile: int, interpret: bool, act: str, plan: RoutePlan, x, w1,
              w3, w2):
-    """-> ys [N x k, C, LANES] float32, C = `_chunks(D)`: sorted pair p's
-    weighted expert output."""
-    _, d, f = w1.shape
-    by_tile, by_group, in_place = _specs(tile)
+    """-> ys [P, C, LANES] float32, C = `_chunks(D)`: sorted pair p's
+    weighted expert output (its width block's part of it)."""
+    d, width, blocks = _widths(plan, w1)
+    by_tile, across, down, in_place = _specs(tile, d, width, blocks)
     shape, spec = _pair_rows(plan.rows.shape[0], d, tile)
     return _call(
         functools.partial(_forward_kernel, act=act), tile, interpret,
         "forward", plan, False,
         (_in_rows(x), plan.wrow, w1, w3, w2),
-        [in_place, by_tile(LANES), by_group(d, f), by_group(d, f),
-         by_group(f, d)],
-        shape, spec, _scratch(w1.shape, tile, 1)).reshape(-1, _chunks(d),
+        [in_place, by_tile(LANES), across, across, down],
+        shape, spec, _scratch(d, width, tile, 1)).reshape(-1, _chunks(d),
                                                           LANES)
 
 
 def _backward(tile: int, interpret: bool, act: str, plan: RoutePlan, x, w1,
               w3, w2, dout):
-    """-> (dxs [N x k, C, LANES], dwrow [N x k, LANES], dw1, dw3,
-    dw2): the pairs' gradient rows and weights' gradients, the experts'
-    matrices' gradients."""
-    _, d, f = w1.shape
+    """-> (dxs [P, C, LANES], dwrow [P, LANES], dw1, dw3, dw2): the pairs'
+    gradient rows and weights' gradients, the experts' matrices'
+    gradients."""
     pairs = plan.rows.shape[0]
-    by_tile, by_group, in_place = _specs(tile)
-    operand = jax.ShapeDtypeStruct((pairs, f), jnp.bfloat16)
+    d, width, blocks = _widths(plan, w1)
+    by_tile, across, down, in_place = _specs(tile, d, width, blocks)
+    operand = jax.ShapeDtypeStruct((pairs, width), jnp.bfloat16)
     x, dout = _in_rows(x), _in_rows(dout)
     shape, spec = _pair_rows(pairs, d, tile)
     dxs, dwrow, hidden, dgate, dup = _call(
         functools.partial(_backward_rows_kernel, act=act), tile, interpret,
         "backward_rows", plan, False,
         (x, dout, plan.wrow, w1, w3, w2),
-        [in_place, in_place, by_tile(LANES), by_group(d, f), by_group(d, f),
-         by_group(f, d)],
+        [in_place, in_place, by_tile(LANES), across, across, down],
         [shape, jax.ShapeDtypeStruct((pairs, LANES), jnp.float32), operand,
          operand, operand],
-        [spec, by_tile(LANES), by_tile(f), by_tile(f), by_tile(f)],
-        _scratch(w1.shape, tile, 2))
+        [spec, by_tile(LANES), by_tile(width), by_tile(width),
+         by_tile(width)],
+        _scratch(d, width, tile, 2))
     dw1, dw3, dw2 = _call(
         _backward_weights_kernel, tile, interpret, "backward_weights", plan,
         True, (x, dout, hidden, dgate, dup),
-        [in_place, in_place, by_tile(f), by_tile(f), by_tile(f)],
+        [in_place, in_place, by_tile(width), by_tile(width), by_tile(width)],
         [jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in (w1, w3, w2)],
-        [by_group(d, f), by_group(d, f), by_group(f, d)],
+        [across, across, down],
         sum(_row_buffers(d, tile, 2), []))
     return dxs.reshape(pairs, -1, LANES), dwrow, dw1, dw3, dw2
 
@@ -581,10 +652,25 @@ def combine(per_pair, plan: RoutePlan, tile: int = COMBINE_TILE,
                     d or per_pair.shape[1] * LANES)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _apply(tile: int, interpret: bool, act: str, x, held, w, w1, w3, w2):
+def block_plan(held, w, experts: int, blocks: int) -> RoutePlan:
+    """`route_plan` over the experts' width blocks: block b of expert e is
+    group `e blocks + b` of the list, and a pair is in the list once a
+    block, side by side in `[N, k x blocks]`. One block: `route_plan`."""
+    if blocks == 1:
+        return route_plan(held, w, experts)
+    n, k = held.shape
+    mine = (held >= 0) & (held < experts)
+    groups = (jnp.where(mine, held, experts)[:, :, None] * blocks
+              + jnp.arange(blocks, dtype=held.dtype))
+    return route_plan(groups.reshape(n, k * blocks),
+                      jnp.repeat(w, blocks, axis=1), experts * blocks)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _apply(tile: int, interpret: bool, act: str, blocks: int, x, held, w, w1,
+           w3, w2):
     """-> (the layer's output [N, D], the list it was computed by)."""
-    plan = route_plan(held, w, w1.shape[0])
+    plan = block_plan(held, w, w1.shape[0], blocks)
     ys = _forward(tile, interpret, act, plan, x, w1, w3, w2)
     return combine(ys, plan, interpret=interpret, d=x.shape[1]), plan
 
@@ -599,24 +685,27 @@ def _pull(tile: int, interpret: bool, act: str, plan: RoutePlan, x, w1, w3,
     _, dw = jax.lax.sort((plan.place, dwrow[:, 0]), num_keys=1,
                          is_stable=False)
     dw = jnp.where(plan.held, dw.reshape(plan.held.shape), 0)
+    blocks = _widths(plan, w1)[2]
+    if blocks > 1:      # a pair's weight multiplied each of its blocks
+        dw = jnp.sum(dw.reshape(dw.shape[0], -1, blocks), axis=-1)
     return (combine(dxs, plan, interpret=interpret, d=x.shape[1]), dw, dw1,
             dw3, dw2)
 
 
 @functools.lru_cache(maxsize=8)
-def _experts_of(tile: int, interpret: bool, act: str):
+def _experts_of(tile: int, interpret: bool, act: str, blocks: int):
     @jax.custom_vjp
     def experts(x, held, w, w1, w3, w2):
-        return _apply(tile, interpret, act, x, held, w, w1, w3, w2)[0]
+        return _apply(tile, interpret, act, blocks, x, held, w, w1, w3, w2)[0]
 
     def experts_fwd(x, held, w, w1, w3, w2):
-        out, plan = _apply(tile, interpret, act, x, held, w, w1, w3, w2)
+        out, plan = _apply(tile, interpret, act, blocks, x, held, w, w1, w3,
+                           w2)
         return out, (plan, x, w1, w3, w2)
 
     def experts_bwd(saved, dout):
         dx, dw, dw1, dw3, dw2 = _pull(tile, interpret, act, *saved, dout)
-        return (dx, np.zeros(saved[0].held.shape, jax.dtypes.float0), dw,
-                dw1, dw3, dw2)
+        return (dx, np.zeros(dw.shape, jax.dtypes.float0), dw, dw1, dw3, dw2)
 
     experts.defvjp(experts_fwd, experts_bwd)
     return jax.jit(experts)     # a call site binds one cached trace
@@ -637,6 +726,8 @@ def grouped_experts(x, held, w, w1, w3, w2, tile: int = TILE,
                          f"weights for {n} positions in tiles of {tile}")
     # the kernels fetch float32 rows and round every operand themselves
     weights = [m.astype(jnp.float32) for m in (w1, w3, w2)]
-    out = _experts_of(tile, bool(interpret), act)(
+    f = w1.shape[2]
+    out = _experts_of(tile, bool(interpret), act,
+                      f // width_block(d, f, tile))(
         x.astype(jnp.float32), held, w.astype(jnp.float32), *weights)
     return out.astype(x.dtype)

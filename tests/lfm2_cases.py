@@ -1,16 +1,5 @@
 """Tiny `lfm2_moe` architectures and parameters the token tests share."""
-import pytest
-
 from dba_mod_tpu import config as cfg
-from dba_mod_tpu.models import lfm2
-
-
-@pytest.fixture
-def small_buffers(monkeypatch):
-    """At 64 tokens the expert layer's buffer of four even shares is the
-    whole batch, so only the every-token path would run: two shares (32
-    rows an expert) put both paths under test."""
-    monkeypatch.setattr(lfm2, "CAPACITY_FACTOR", 2.0)
 
 ARCH = dict(hidden_size=64, intermediate_size=160, moe_intermediate_size=48,
             num_attention_heads=4, num_key_value_heads=2,

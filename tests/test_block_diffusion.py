@@ -183,7 +183,7 @@ TINY = dict(
        "0_poison_epochs": [2, 3, 4], "1_poison_epochs": [3]})
 
 
-@pytest.mark.parametrize("family,sha", [("lfm2_moe", "236fe94bd6fbf1c0"),
+@pytest.mark.parametrize("family,sha", [("lfm2_moe", "f731b9c20c6a578c"),
                                         ("tiny_resnet18", "0b021b160b6d36b9")])
 def test_the_accepted_cells_round_programs_lower_as_on_the_parent(
         narrow_resnets, family, sha):
@@ -195,7 +195,10 @@ def test_the_accepted_cells_round_programs_lower_as_on_the_parent(
     text compiles to an equal program: what `ModelDef.run_batch` moved it
     did not change. A PR that changes a round program on purpose, or a new
     JAX, records new ones here (PR 41: the streamed round carries the
-    model's row counts, `ModelCounts.rows`, two zeros for this decoder)."""
+    model's row counts, `ModelCounts.rows`, two zeros for this decoder;
+    PR 46: this decoder's expert layer is every held expert over every
+    token on this CPU, its two paths and their `lax.cond` gone, and it
+    counts its rows)."""
     params = (lfm2_cases.params() if family == "lfm2_moe"
               else Params.from_dict(TINY))
     assert lowered_sha(Experiment(params, save_results=False), 2) == sha

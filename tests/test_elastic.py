@@ -3,6 +3,7 @@ semantics, the watchdog's peer-lost verdict (exit 77 vs 76), the
 host-level fault lane's determinism and survivor-mask composition, and
 the strict no-op contract of every new knob."""
 import json
+import logging
 import time
 
 import numpy as np
@@ -241,8 +242,16 @@ def test_single_process_host_loss_without_num_hosts_disables_lane(caplog):
     crash at its final step."""
     p = Params.from_dict(dict(_BASE, fault_injection=True,
                               fault_host_loss_prob=0.5))
-    with caplog.at_level("WARNING", logger="dba_mod_tpu"):
-        fcfg = flt.FaultConfig.from_params(p)
+    # the handler on the logger itself: `setup_logging` turns propagation
+    # off once a result-saving Experiment has run in this worker, whichever
+    # file brought it (tests/test_checkpoint_guard.py::dba_log)
+    lg = logging.getLogger("dba_mod_tpu")
+    lg.addHandler(caplog.handler)
+    try:
+        with caplog.at_level("WARNING", logger="dba_mod_tpu"):
+            fcfg = flt.FaultConfig.from_params(p)
+    finally:
+        lg.removeHandler(caplog.handler)
     assert not fcfg.host_loss_enabled
     assert any("fault_num_hosts" in r.message for r in caplog.records)
     ok = Params.from_dict(dict(_BASE, fault_injection=True,

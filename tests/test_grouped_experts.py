@@ -5,7 +5,10 @@ keeps): the output and the gradients to x, the router's weights and the three
 matrices under the routings the one path must hold in; the list and the walk
 against numpy counts of the same routing. The same with the gate's activation
 `relu` (models/smallthinker.py's ReGLU experts) at a hidden size of 20 x 128,
-whose rows travel padded to 24 sublanes."""
+whose rows travel padded to 24 sublanes; and at a width the kernels take in
+two blocks (models/lfm2.py's experts), under a fast memory made small."""
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -64,34 +67,34 @@ def operands():
             jax.random.normal(keys[5], (N, D)))
 
 
+def forms(case, inputs, cot):
+    """((out, dx, dw, dw1, dw3, dw2) of the kernels, the same of every held
+    expert over every position) under `case`'s routing of `inputs` (x, w,
+    w1, w3, w2), pulled back from `cot`."""
+    held = jnp.asarray(routing(case), jnp.int32)
+    picks = held[:, :, None] == jnp.arange(E)
+
+    def oracle(x, w, w1, w3, w2):
+        wts = jnp.sum(jnp.where(picks, w[:, :, None], 0.0), axis=1)
+        return experts_over_all(x, wts, w1, w3, w2)
+
+    def kernels(x, w, w1, w3, w2):
+        return ge.grouped_experts(x, held, w, w1, w3, w2, tile=TILE,
+                                  interpret=True)
+
+    def results(form):
+        out, pull = jax.vjp(form, *inputs)
+        return (out,) + pull(cot)
+
+    return results(kernels), results(oracle)
+
+
 @pytest.fixture(scope="module")
 def both_forms(operands):
-    """case -> ((out, dx, dw, dw1, dw3, dw2) of the kernels, the same of
-    every held expert over every position), computed once a case."""
+    """case -> `forms` of it on the module's operands, computed once a
+    case."""
     *inputs, cot = operands
-    done = {}
-
-    def of(case):
-        if case not in done:
-            held = jnp.asarray(routing(case), jnp.int32)
-            picks = held[:, :, None] == jnp.arange(E)
-
-            def oracle(x, w, w1, w3, w2):
-                wts = jnp.sum(jnp.where(picks, w[:, :, None], 0.0), axis=1)
-                return experts_over_all(x, wts, w1, w3, w2)
-
-            def kernels(x, w, w1, w3, w2):
-                return ge.grouped_experts(x, held, w, w1, w3, w2, tile=TILE,
-                                          interpret=True)
-
-            def results(form):
-                out, pull = jax.vjp(form, *inputs)
-                return (out,) + pull(cot)
-
-            done[case] = results(kernels), results(oracle)
-        return done[case]
-
-    return of
+    return functools.cache(lambda case: forms(case, inputs, cot))
 
 
 @pytest.mark.parametrize("part", PARTS)
@@ -182,7 +185,7 @@ def test_the_walk_visits_each_experts_tiles_once(counts, every_group):
         assert new_group[step] == (group[step] not in seen_groups)
         seen_tiles.add(which[step])
         seen_groups.add(group[step])
-    assert int(ge.rows_run(jnp.asarray(counts, jnp.int32), TILE)) == (
+    assert int(ge.rows_run(jnp.asarray(counts, jnp.int32), D, F, TILE)) == (
         TILE * sum(1 for e, _ in want if counts[e]))
 
 
@@ -269,3 +272,96 @@ def test_a_rows_sublanes_are_whole_tiles_and_the_act_is_one_of_two(monkeypatch):
         ge.grouped_experts(jnp.zeros((16, 128)), jnp.zeros((16, 1), jnp.int32),
                            jnp.zeros((16, 1)), *(jnp.zeros((2, 128, 128)),) * 3,
                            tile=16, act="gelu")
+
+
+TWO_BLOCKS = 2 * F        # under `small_fast_memory`: two blocks of F
+BLOCK_CASES = ("even", "positions_that_chose_none",
+               "groups_no_multiple_of_the_tile")
+
+
+def small_fast_memory(patch):
+    """A fast memory that holds a tile of 16 rows of D beside experts F
+    wide and not beside experts 2 F wide: the limit is what the width block
+    is worked out against, so that a width of 256 is walked in two blocks
+    here as 1,536 is on the chip."""
+    patch.setattr(ge, "VMEM_LIMIT", (ge._fast_bytes(D, F, TILE)
+                                     + ge._fast_bytes(D, TWO_BLOCKS, TILE)) // 2)
+    assert ge.width_block(D, TWO_BLOCKS, TILE) == F == ge.width_block(D, F, TILE)
+
+
+@pytest.fixture(scope="module")
+def block_forms():
+    """case -> the kernels' and the oracle's (out, dx, dw, dw1, dw3, dw2) at
+    a width of two blocks."""
+    keys = jax.random.split(jax.random.key(6), 6)
+    inputs = (jax.random.normal(keys[0], (N, D)),
+              jax.nn.sigmoid(jax.random.normal(keys[1], (N, K))),
+              jax.random.normal(keys[2], (E, D, TWO_BLOCKS)) * 0.1,
+              jax.random.normal(keys[3], (E, D, TWO_BLOCKS)) * 0.1,
+              jax.random.normal(keys[4], (E, TWO_BLOCKS, D)) * 0.07)
+    cot = jax.random.normal(keys[5], (N, D))
+    with pytest.MonkeyPatch.context() as patch:
+        small_fast_memory(patch)
+        return {case: forms(case, inputs, cot) for case in BLOCK_CASES}
+
+
+@pytest.mark.parametrize("part", PARTS)
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_a_width_of_two_blocks_is_every_expert_over_every_position(
+        block_forms, case, part):
+    """As `test_the_grouped_product_is_every_expert_over_every_position`, to
+    bfloat16's rounding, with every expert's width walked in two blocks: a
+    block multiplied by another's columns of w1, a half of the down product
+    left out or added twice, or the router's weight given one block's
+    gradient alone would be off by tenths."""
+    got, want = (r[PARTS.index(part)] for r in block_forms[case])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.isfinite(got).all())
+    scale = float(jnp.linalg.norm(want))
+    assert scale > 0
+    assert float(jnp.linalg.norm(got - want)) / scale < 8e-3
+    np.testing.assert_allclose(got, want,
+                               atol=0.05 * float(jnp.abs(want).max()))
+
+
+def test_the_width_block_is_read_from_the_shapes():
+    """D 2048 x 768 (models/sdar.py) and D 2560 x 768
+    (models/smallthinker.py) are walked whole, as before there was a block;
+    models/lfm2.py's 2048 x 1,536 in two blocks of 768."""
+    assert ge.width_block(2048, 768) == 768 == ge.width_block(2560, 768)
+    assert ge.width_block(2048, 1536) == 768
+    assert ge._fast_bytes(2048, 1536, ge.TILE) > ge.VMEM_LIMIT > ge._fast_bytes(
+        2560, 768, ge.TILE)
+    assert ge.width_block(2048, 2304) == 1152      # whole lanes that divide it
+    with pytest.raises(ValueError, match="fast memory"):
+        ge.width_block(2 ** 16, 768)
+
+
+def test_the_list_of_two_blocks_holds_a_pair_once_a_block(monkeypatch):
+    """`block_plan`: block b of expert e is group 2 e + b with e's pairs;
+    one block is `route_plan` itself; the rows counted are a visit's at a
+    block's share of the width."""
+    small_fast_memory(monkeypatch)
+    held = jnp.asarray(routing("groups_no_multiple_of_the_tile"), jnp.int32)
+    w = jnp.asarray(np.random.default_rng(2).random((N, K)), jnp.float32)
+    one, two = ge.block_plan(held, w, E, 1), ge.block_plan(held, w, E, 2)
+    for a, b in zip(one, ge.route_plan(held, w, E)):
+        np.testing.assert_array_equal(a, b)
+    counts = np.diff(one.starts)
+    np.testing.assert_array_equal(np.diff(two.starts), np.repeat(counts, 2))
+    assert two.held.shape == (N, 2 * K) and two.rows.shape == (2 * N * K,)
+    np.testing.assert_array_equal(two.count, 2 * one.count)
+    for e in range(E):      # both blocks' lists are the expert's own
+        rows = one.rows[one.starts[e]:one.starts[e + 1]]
+        for b in range(2):
+            lo = two.starts[2 * e + b]
+            np.testing.assert_array_equal(two.rows[lo:lo + len(rows)], rows)
+    def visits(plan):
+        starts = np.asarray(plan.starts)
+        return sum((hi - 1) // TILE - lo // TILE + 1
+                   for lo, hi in zip(starts[:-1], starts[1:]))
+
+    counts = jnp.asarray(counts, jnp.int32)
+    assert (visits(one), visits(two)) == (6, 13)    # the second of half a width
+    assert int(ge.rows_run(counts, D, F, TILE)) == TILE * 6
+    assert int(ge.rows_run(counts, D, TWO_BLOCKS, TILE)) == TILE * 13 // 2

@@ -2,7 +2,11 @@
 on seeded weights at toy widths: every layer kind and the whole model,
 forward, loss and gradients; the share test (the parts that 8 shares of the
 experts give add up to the uncut reference's layer); no token dropped when
-every token chooses one expert; padding and causality of a packed row."""
+every token chooses one expert; the expert layer on the grouped product
+(Pallas' interpreter) against the every-expert form and the reference, and
+the rows it counts; padding and causality of a packed row."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,9 +20,10 @@ from dba_mod_tpu.models import ModelVars, build_model
 from dba_mod_tpu.models import lfm2
 from dba_mod_tpu.ops.losses import batch_loss
 from dba_mod_tpu.ops.triggers import next_token_labels
-from tests.lfm2_cases import arch, params, small_buffers  # noqa: F401
-
-pytestmark = pytest.mark.usefixtures("small_buffers")
+from dba_mod_tpu.fl.streamed import ModelCounts, fold_counts
+from dba_mod_tpu.models.decoder_parts import ROWS_COUNTER
+from dba_mod_tpu.ops import grouped_experts as ge
+from tests.lfm2_cases import arch, params
 
 CASES = {
     "conv_dense": arch(layer_types=["conv"], num_dense_layers=1),
@@ -73,14 +78,16 @@ def test_forward_loss_and_gradients_are_the_references(case):
             atol=2e-6 * float(jnp.abs(want_grads[name]).max()), err_msg=name)
 
 
+def layer_variables(state, pre="layers.0.moe."):
+    return {"params": {n: state[pre + n] for n in ("router", "w1", "w3", "w2")},
+            "batch_stats": {"expert_bias": state[pre + "expert_bias"]}}
+
+
 def expert_layer_of(architecture, state, x):
     """The program's expert layer alone on the reference's weights."""
     cfg = lfm2.Lfm2Config.from_dict(architecture)
-    pre = "layers.0.moe."
-    variables = {"params": {n: state[pre + n] for n in ("router", "w1", "w3", "w2")},
-                 "batch_stats": {"expert_bias": state[pre + "expert_bias"]}}
     out, sown = lfm2.ExpertFfn(cfg, jnp.float32).apply(
-        variables, x, mutable=["counters"])
+        layer_variables(state), x, mutable=["counters"])
     return out, sown["counters"]["expert_tokens"]
 
 
@@ -108,9 +115,9 @@ def test_eight_shares_add_up_to_the_uncut_layer():
 
 @pytest.mark.parametrize("forced", [True, False])
 def test_no_token_is_dropped(forced):
-    """A router forced to expert 1 gives it every token, more than the
-    gathered path's buffer holds: the layer then runs every held expert over
-    every token, and agrees with the reference either way."""
+    """A router forced to expert 1 gives it every token, sixteen times an
+    even share: one path, no buffer to overflow and no second form, and the
+    layer agrees with the reference either way."""
     one = arch(layer_types=["conv"], num_dense_layers=0)
     state = dict(ref.init_weights(11, one))
     pre = "layers.0.moe."
@@ -121,8 +128,105 @@ def test_no_token_is_dropped(forced):
         out, counts = expert_layer_of(one, state, x)
         want = ref.expert_layer(state, pre, x, one)
     np.testing.assert_allclose(out, want, atol=2e-6)
-    capacity = 2 * (64 * 2 // 8)         # twice an even share of 64 tokens
-    assert (int(counts[1]) == 64) if forced else (int(counts.max()) <= capacity)
+    assert (int(counts[1]) == 64) if forced else (int(counts.max()) < 64)
+
+
+WIDE = dict(layer_types=["conv"], num_dense_layers=0, hidden_size=128,
+            num_experts=16, num_experts_per_tok=4, experts_held=[4, 8])
+WIDTHS = (128, 256)     # one width block and two under `interpret_the_product`
+
+
+def interpret_the_product(patch):
+    """The layer's TPU form on this CPU: `runs_here` holds, the product runs
+    in Pallas' interpreter on tiles of 16, and a width block is 128 lanes,
+    so that a width of 256 is walked in two blocks (as 1,536 is on the chip;
+    tests/test_grouped_experts.py works the block out of a small memory)."""
+    patch.setattr(ge, "runs_here", lambda n, d, f: True)
+    patch.setattr(ge, "grouped_experts", functools.partial(
+        ge.grouped_experts, tile=16, interpret=True))
+    patch.setattr(ge, "width_block", lambda d, f, tile=ge.TILE: 128)
+
+
+def layer_and_gradients(cfg, variables, x, cot):
+    """(out, (d params, dx)) of the expert layer, and what it sowed."""
+    def layer(p, x):
+        out, sown = lfm2.ExpertFfn(cfg, jnp.float32).apply(
+            {**variables, "params": p}, x, mutable=["counters"])
+        return out, sown["counters"]
+
+    out, pull, counters = jax.vjp(layer, variables["params"], x, has_aux=True)
+    return out, pull(cot), counters
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_the_grouped_form_is_the_every_expert_form_and_the_reference(
+        monkeypatch, width):
+    """`ExpertFfn` with `expert_bias` set, a sigmoid's weights renormalised,
+    a top-4 of 16 of which this chip holds experts 4-7 (most positions give
+    some choice to another chip, some all four): on the grouped product the
+    output and every gradient (router, the three matrices, x) are the
+    every-expert form's to bfloat16's rounding (the kernels' operands; this
+    CPU's einsums are exact float32), the every-expert form is the
+    reference's layer, and a position that chose no held expert reads
+    zeros."""
+    architecture = arch(moe_intermediate_size=width, **WIDE)
+    cfg = lfm2.Lfm2Config.from_dict(architecture)
+    state = dict(ref.init_weights(13, architecture))
+    pre = "layers.0.moe."
+    state[pre + "expert_bias"] = 0.05 * jax.random.normal(jax.random.key(9), (16,))
+    variables = layer_variables(state)
+    x = jax.random.normal(jax.random.key(6), (2, 32, 128))
+    cot = jax.random.normal(jax.random.key(7), x.shape)
+    with jax.default_matmul_precision("highest"):
+        over_all = layer_and_gradients(cfg, variables, x, cot)
+        want = ref.expert_layer(state, pre, x, architecture)
+    np.testing.assert_allclose(over_all[0], want, atol=2e-6)
+    interpret_the_product(monkeypatch)
+    grouped = layer_and_gradients(cfg, variables, x, cot)
+    flat = lambda r: [r[0]] + jax.tree_util.tree_leaves(r[1])
+    for got, ours in zip(flat(grouped), flat(over_all)):
+        assert got.shape == ours.shape and bool(jnp.isfinite(got).all())
+        assert float(jnp.linalg.norm(got - ours)
+                     / jnp.linalg.norm(ours)) < 8e-3
+    logits = x.reshape(-1, 128) @ state[pre + "router"]
+    sel, _ = lfm2.route(logits, state[pre + "expert_bias"], 4, True, 1.0)
+    elsewhere = np.asarray(((sel < 4) | (sel >= 8)).all(axis=1))
+    assert 0 < elsewhere.sum() < 64
+    assert not bool(jnp.any(grouped[0].reshape(-1, 128)[elsewhere]))
+    np.testing.assert_array_equal(grouped[2]["expert_tokens"],
+                                  over_all[2]["expert_tokens"])
+
+
+@pytest.mark.parametrize("form", ["every_expert", "grouped"])
+def test_the_layer_counts_its_rows_and_the_round_carries_them(
+        monkeypatch, form):
+    """`ROWS_COUNTER` beside `expert_tokens`: (rows multiplied, held experts
+    x tokens): all of them where every held expert runs over every token,
+    the visited tiles' where the grouped product does (the file's tile of
+    256: a toy call's few pairs round up past the whole); and
+    `fl/streamed.py::fold_counts` adds a real step's to `ModelCounts.rows`,
+    apart from the tokens' three."""
+    if form == "grouped":
+        interpret_the_product(monkeypatch)
+    architecture = arch(moe_intermediate_size=256, **WIDE)
+    cfg = lfm2.Lfm2Config.from_dict(architecture)
+    variables = layer_variables(dict(ref.init_weights(13, architecture)))
+    x = jax.random.normal(jax.random.key(6), (2, 32, 128))
+    _, sown = lfm2.ExpertFfn(cfg, jnp.float32).apply(
+        variables, x, mutable=["counters"])
+    counted = sown["counters"]
+    tokens = counted["expert_tokens"]
+    run = (int(ge.rows_run(tokens, 128, 256)) if form == "grouped"
+           else 4 * 64)
+    assert counted[ROWS_COUNTER].tolist() == [run, 4 * 64]
+    assert run == (256 * 8 // 2 if form == "grouped" else 256)
+    zero = ModelCounts(*(jnp.int32(0),) * 3, jnp.zeros((2,), jnp.int32))
+    once = fold_counts(zero, {"layer_1": {"moe": counted}}, jnp.int32(1))
+    twice = fold_counts(once, {"layer_1": {"moe": counted}}, jnp.int32(1))
+    skipped = fold_counts(twice, {"layer_1": {"moe": counted}}, jnp.int32(0))
+    assert skipped.rows.tolist() == twice.rows.tolist() == [2 * run, 2 * 256]
+    assert (int(twice.held), int(twice.max), int(twice.cells)) == (
+        2 * int(tokens.sum()), int(tokens.max()), 2 * 4)
 
 
 def test_padding_and_causality_of_a_packed_row():

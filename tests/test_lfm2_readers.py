@@ -1,5 +1,5 @@
 """The per-layer readers of the `lfm2_moe` cell (chipbench/lfm2_layers.py and
-the five files under chipbench/metrics/ that call it) on hand-made records:
+the six files under chipbench/metrics/ that call it) on hand-made records:
 from a program without the scopes and counts (the parent of the PR that
 brought them) every reader gives nothing and raises nothing; from a traced
 run's records each gives the number its docstring says."""
@@ -15,7 +15,8 @@ from tests.lfm2_cases import ARCH
 
 METRICS = Path(lfm2_layers.__file__).parent / "metrics"
 READERS = ("mixer_device_ms", "experts_device_ms", "optimizer_device_ms",
-           "expert_load_max_over_mean", "client_step_mfu_pct")
+           "expert_load_max_over_mean", "client_step_mfu_pct",
+           "expert_rows_run_pct")
 TRAIN = "jit(round_fn)/phase/train/while/body/"
 MS = 1e6   # the trace's clock is in nanoseconds
 
@@ -50,9 +51,11 @@ def traced_ctx():
     plans = [span("round/plan", tokens_step=64, client_steps=c)
              for c in (8, 8, 24)]
     records = [span("round/record", expert_tokens_held=h, expert_tokens_max=m,
-                    expert_tokens_mean=mean)
-               for h, m, mean in ((500, 30, 15.0), (512, 24, 16.0),
-                                  (1600, 40, 16.0))]
+                    expert_tokens_mean=mean, expert_rows_run=run,
+                    expert_rows_all=every)
+               for h, m, mean, run, every in (
+                   (500, 30, 15.0, 768, 8192), (512, 24, 16.0, 1024, 8192),
+                   (1600, 40, 16.0, 3072, 24576))]
     return {"spans": {"dispatch": [0.01, 0.01, 0.01]},
             "program_spans": plans + records,
             "traced": {"rounds": 2, "window_rounds": [2, 3]},
@@ -86,6 +89,7 @@ def test_a_program_without_the_scopes_and_counts_reads_as_nothing(name):
     ("experts_device_ms", (1.0 + 2.0 + 1.0) / 2),
     ("optimizer_device_ms", 2.0 / 2),
     ("expert_load_max_over_mean", (30 / 15 + 24 / 16 + 40 / 16) / 3),
+    ("expert_rows_run_pct", 100 * (768 + 1024 + 3072) / (2 * 8192 + 24576)),
 ])
 def test_the_readers_read_what_their_docstrings_say(name, want):
     assert reader(name).read(traced_ctx()) == pytest.approx(want)
@@ -99,3 +103,35 @@ def test_the_steps_share_of_the_peak_counts_the_experts_from_the_counter():
     got = reader("client_step_mfu_pct").read(traced_ctx())
     assert got == pytest.approx(100 * 3 * forward / (0.010 * peak))
     assert per["experts"] == 0.0 and per["forward"] > per["mixer"] > 0
+
+
+@pytest.mark.parametrize("how,want", [("every_row_run", 100.0),
+                                      ("a_round_without_the_counts", None),
+                                      ("no_row_counted", None)])
+def test_the_row_share_reads_the_counts_or_nothing(how, want):
+    """`expert_rows_run` over `expert_rows_all`: 100 where every held expert
+    ran over every position (any CPU run), nothing where a round of the
+    window carries no counts (the parent) or none was counted."""
+    ctx = traced_ctx()
+    records = [r for r in ctx["program_spans"] if r.name == "round/record"]
+    for record in records[:1] if how == "a_round_without_the_counts" else records:
+        if how == "every_row_run":
+            record.counts["expert_rows_run"] = record.counts["expert_rows_all"]
+        elif how == "no_row_counted":
+            record.counts.update(expert_rows_run=0, expert_rows_all=0)
+        else:
+            del record.counts["expert_rows_run"], record.counts["expert_rows_all"]
+    assert reader("expert_rows_run_pct").read(ctx) == want
+
+
+def test_the_benchmark_lists_the_cell_for_the_row_share():
+    import json
+    bench = json.loads((METRICS.parents[1] / "BENCHMARK.json").read_text())
+    entry = [m for m in bench["per_layer"] if m["name"] == "expert_rows_run_pct"]
+    mod = reader("expert_rows_run_pct")
+    assert entry == [dict(name="expert_rows_run_pct", unit=mod.UNIT,
+                          better="lower", source="program_counter",
+                          layer=mod.LAYER, moves=mod.MOVES,
+                          workloads=["lfm2_split_phrase_attack"])]
+    assert (mod.LAYER, mod.UNIT, mod.MOVES) == (
+        "expert layer", "%", "client_updates_per_s")

@@ -182,7 +182,9 @@ def test_the_benchmark_lists_the_cell_for_each_reader():
     import json
     bench = json.loads((METRICS.parents[1] / "BENCHMARK.json").read_text())
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-12:]] == list(READERS)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(READERS[0])     # the twelve stand together, in order
+    assert names[first:first + len(READERS)] == list(READERS)
     for name in READERS:
         mod = reader(name)
         assert entries[name]["workloads"] == ["smallthinker_long_row_attack"]

@@ -30,9 +30,7 @@ from dba_mod_tpu.fl.experiment import Experiment
 from dba_mod_tpu.fl.rounds import RoundEngine
 from dba_mod_tpu.utils import telemetry
 from tests import sdar_cases, smallthinker_cases
-from tests.lfm2_cases import ARCH, params, small_buffers  # noqa: F401
-
-pytestmark = pytest.mark.usefixtures("small_buffers")
+from tests.lfm2_cases import ARCH, params
 
 SMOKE = "configs/smoke_params.yaml"
 LIMITS = {"loss_gap.k1": 1e-4, "update_rel_l2.k1": 1e-3,
@@ -116,6 +114,10 @@ def test_an_lfm2_experiment_trains_poisons_and_records(tmp_path):
     assert all(0.5 < h / (c["client_steps"] * 64 * 2) < 1.5
                for h, c in zip(held, plan))
     assert all(c["expert_tokens_max"] >= c["expert_tokens_mean"] for c in record)
+    # on this CPU every held expert runs over every token: 2 layers x 4 held
+    # x 64 rows a step, all of them run
+    assert all(c["expert_rows_run"] == c["expert_rows_all"]
+               == p["client_steps"] * 2 * 4 * 64 for c, p in zip(record, plan))
     # the workspace goes and comes back
     exp.engine.release_workspace()
     assert exp.engine.workspace is None

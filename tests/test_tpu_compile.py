@@ -5,8 +5,8 @@ described (`on-chip-measurement` guide §2): it refuses what the chip's
 compiler would refuse (misaligned Pallas slices, VMEM overflow, programs that
 do not fit HBM), which interpret-mode tests cannot see. Covered: the fused
 Pallas per-step update at the CIFAR and Tiny-ImageNet model shapes, the
-blocked attention kernel and the grouped expert product at the SDAR cell's
-and the SmallThinker cell's shapes and the files' tiles, the SmallThinker
+blocked attention kernel and the grouped expert product at the SDAR cell's,
+the SmallThinker cell's and the LFM2 cell's shapes and the files' tiles, the SmallThinker
 cell's round program (rows of 8,192: its temporaries beside the state), and the
 CIFAR round program's donated twin — the default program of an unsharded TPU
 run, which the CPU suite otherwise never builds.
@@ -135,6 +135,35 @@ def test_grouped_experts_compile_for_v5e(one_chip, no_persistent_cache,
     assert compiled.as_text().count("tpu_custom_call") >= (5 if backward
                                                            else 2)
     # the list is sized for the most a call can route: 8 pairs a position
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("positions", [4096, 2048])
+@pytest.mark.parametrize("backward", [False, True])
+def test_two_block_grouped_experts_compile_for_v5e(one_chip,
+                                                   no_persistent_cache,
+                                                   backward, positions):
+    """`ops/grouped_experts.py` at configs/lfm2_params.yaml's shapes (a step's
+    two rows of 2,048 and a battery's one, hidden 2,048, 8 held experts
+    1,536 wide, top-4): an expert's matrices whole would pass the kernels'
+    fast memory, so its width is walked in two blocks of 768, and a block's
+    matrices, their bfloat16 copies and their gradients fit; the list is
+    sized for 8 pairs a position, a pair a block."""
+    from dba_mod_tpu.ops import grouped_experts as ge
+    assert ge.width_block(2048, 1536) == 768
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+    args = (shape(positions, 2048), shape(positions, 4, dt=jnp.int32),
+            shape(positions, 4), shape(8, 2048, 1536), shape(8, 2048, 1536),
+            shape(8, 1536, 2048))
+    if backward:
+        fn = jax.grad(lambda *a: jnp.sum(ge.grouped_experts(*a) ** 2),
+                      (0, 2, 3, 4, 5))
+    else:
+        fn = ge.grouped_experts
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (5 if backward
+                                                           else 2)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
